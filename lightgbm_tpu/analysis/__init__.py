@@ -5,8 +5,8 @@ test exercises directly — they hold by construction until someone edits
 the wrong line, and then they regress silently:
 
   * the native `task=predict` fast path and the CLI arg-parse never
-    import jax (predict_fast.py docstring; BASELINE.md measured the
-    JAX startup tax at over half the 1M-row predict wall);
+    import jax (predict_fast.py docstring: the JAX startup cost is one
+    the reference binary never pays);
   * device code never host-syncs mid-trace and never touches float64
     (x64 is off during training; bit-parity with the reference is the
     whole point, PARITY.md);

@@ -191,8 +191,10 @@ PARITY_PREFIXES = ("ops/",)
 
 SERVING_PREFIX = "serving/"
 
-# Process-owning entry points may mutate global jax config (GL007).
-ENTRY_MODULES = {"cli.py", "__main__.py"}
+# Process-owning entry points may mutate global jax config (GL007) —
+# and utils/device.py, the one resolver every entry point calls to
+# turn device_type into a platform.
+ENTRY_MODULES = {"cli.py", "__main__.py", "utils/device.py"}
 
 # The logger's home (and the CLI's stderr error report) may write to
 # stdio directly (GL008).
@@ -219,7 +221,7 @@ _TRACE_TRANSFORMS = {
     "jax.lax.associative_scan", "lax.scan", "lax.while_loop",
     "lax.fori_loop", "lax.map", "lax.cond", "lax.switch",
     "jax.vmap", "vmap", "jax.grad", "jax.value_and_grad",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map",
     "pl.pallas_call", "pallas_call", "jax.checkpoint", "jax.remat",
 }
 _HOST_SYNC_CALLS = {

@@ -44,6 +44,7 @@ from .metrics import create_metrics
 from .models.gbdt import GBDT, create_boosting
 from .objectives import create_objective
 from .utils import log
+from .utils.device import resolve_device
 from .utils.mt19937 import Mt19937Random
 
 ArrayLike = Union[np.ndarray, "scipy.sparse.spmatrix", str]  # noqa: F821
@@ -409,6 +410,7 @@ class Booster:
                              " / model_str")
         if train_set is not None:
             self.config = _to_config(self.params)
+            resolve_device(self.config.device_type)
             self.train_set = train_set
             objective = create_objective(self.config)
             objective.init(train_set.inner.metadata, train_set.num_data())
@@ -430,6 +432,7 @@ class Booster:
             p.setdefault("boosting_type",
                          "dart" if first_line == "dart" else "gbdt")
             self.config = _to_config(p)
+            resolve_device(self.config.device_type)
             self.train_set = None
             self._gbdt = GBDT(self.config, None, None)
             self._gbdt.load_model_from_string(text)

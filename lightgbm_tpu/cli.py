@@ -46,17 +46,13 @@ class Application:
             configure(self.config.faults)
 
     def _apply_device_type(self) -> None:
-        if self.config.device_type == "cpu":
-            # must run before any JAX backend initializes; overrides the
-            # platform even when the environment pins JAX_PLATFORMS
-            # (device_type=tpu keeps the environment's accelerator platform,
-            # whatever its registered name)
-            import jax
-            jax.config.update("jax_platforms", "cpu")
+        # pins device_type, initializes the backend and is fatal when
+        # it is not the one asked for (utils/device.py)
+        from .utils.device import resolve_device
+        resolve_device(self.config.device_type)
 
     def run(self) -> None:
         if self.config.task == "train":
-            self._apply_device_type()
             self.init_train()
             self.train()
         elif self.config.task == "ingest":
@@ -83,12 +79,12 @@ class Application:
             if self.config.serve_workers > 1:
                 # multi-process front-end: the SUPERVISOR stays jax-free
                 # (it only forks and watches); each spawned worker
-                # applies the device platform itself (_worker_main)
+                # resolves its device itself
                 from .serving.frontend import frontend_forever
                 frontend_forever(self.config)
                 return
-            if self.config.serve_backend != "native":
-                self._apply_device_type()
+            # the device is resolved where the forest picks its engine
+            # (ServingForest._pick_engine -> utils/device.py)
             from .serving.server import serve_forever
             serve_forever(self.config)
         else:
@@ -103,6 +99,13 @@ class Application:
     # ------------------------------------------------------------------
     def init_train(self) -> None:
         cfg = self.config
+        from .utils.device import pin_platform
+        # pin only, before anything touches a JAX backend:
+        # jax.distributed.initialize refuses once a backend is live,
+        # and init_distributed reads jax_platforms to choose the CPU
+        # collectives.  The backend check (_apply_device_type) comes
+        # after the distributed runtime is up
+        pin_platform(cfg.device_type)
         # multi-host: bring up the JAX distributed runtime from the
         # machine list (replaces Network::Init, application.cpp:185).
         # Each process loads its row shard (query-granular for ranking;
@@ -126,6 +129,7 @@ class Application:
             self.rank, self.num_machines = init_distributed(cfg)
             sync_config_by_min(cfg)
             check_config_fingerprint(cfg)
+        self._apply_device_type()
         self.boosting_old: Optional[GBDT] = None
         self._warm_start_ckpt: Optional[str] = None
         if cfg.input_model:

@@ -204,13 +204,16 @@ class Config:
     #                                       in-register on the VMEM-
     #                                       resident accumulators instead
     #                                       of a separate XLA pass over
-    #                                       the [F, B, 3] tensor.  auto
-    #                                       engages with hist_impl=pallas
-    #                                       (serial learner; other
-    #                                       learners keep the two-op
-    #                                       path); off IS the retained
-    #                                       two-op oracle — fused on is
-    #                                       bit-parity with it (the
+    #                                       the [F, B, 3] tensor.  The
+    #                                       fused kernels do not lower
+    #                                       for the TPU under jax 0.9.0,
+    #                                       so auto is the two-op path
+    #                                       and on runs (interpreted) on
+    #                                       the CPU backend only, fatal
+    #                                       elsewhere (models/gbdt.py
+    #                                       resolve_hist_fused); off IS
+    #                                       the two-op oracle — fused on
+    #                                       is bit-parity with it (the
     #                                       kernel runs the oracle's
     #                                       exact scan ops)
     hist_acc: str = "f32"                 # f32 | bf16 | i32: Pallas
@@ -247,10 +250,11 @@ class Config:
     #                                       with the per-iteration oracle
     #                                       (iter_batch=1); auto picks a K
     #                                       that divides metric_freq on
-    #                                       accelerators and 1 on CPU (local
-    #                                       dispatch is cheap; the K-scan
-    #                                       exists to kill remote-attached
-    #                                       dispatch round-trips)
+    #                                       accelerators and 1 on CPU (the
+    #                                       K-scan saves one host dispatch
+    #                                       and sync per iteration, which
+    #                                       the CPU backend's extra compile
+    #                                       time does not repay)
     donate_buffers: bool = True
     device_type: str = ""                 # "" = default JAX platform | cpu | tpu
 
@@ -717,6 +721,11 @@ class Config:
         if c.device_type not in ("", "cpu", "tpu"):
             log.fatal("Unknown device_type %s (expect cpu|tpu)"
                       % c.device_type)
+        if c.device_type == "tpu" and c.serve_backend == "native" \
+                and c.task == "serve":
+            # device_type=tpu never ends on the host engine with exit 0
+            log.fatal("device_type=tpu contradicts serve_backend=native "
+                      "(the jax-free host engine)")
         if c.hist_impl not in ("auto", "xla", "pallas"):
             log.fatal("Unknown hist_impl %s (expect auto|xla|pallas)"
                       % c.hist_impl)

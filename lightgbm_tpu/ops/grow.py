@@ -81,8 +81,8 @@ class GrowState(NamedTuple):
 # column layout of the packed per-leaf best-split state.  Packing the
 # 11 BestSplit fields into two stacked arrays turns the per-split
 # bookkeeping (2 leaves updated, 1 read) into 6 row-sized ops instead of
-# ~33 scalar gathers/updates — on remote-attached TPUs every extra op in
-# the sequential split chain costs launch latency.
+# ~33 scalar gathers/updates — every extra op in the sequential split
+# chain costs launch latency.
 BF_GAIN, BF_LG, BF_LH, BF_RG, BF_RH, BF_LOUT, BF_ROUT = range(7)
 BI_FEAT, BI_THR, BI_LCNT, BI_RCNT = range(4)
 
@@ -412,8 +412,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                                             row_chunk=row_chunk))
 
     # -- compacted small-leaf histograms (serial fast path) ------------
-    # Profiling (BASELINE.md): full-row sweeps are ~90% of the fused
-    # iteration, and every split sweeps all N rows for the SMALLER child
+    # Full-row sweeps dominate the fused iteration (~90% in the r05-era
+    # profile), and every split sweeps all N rows for the SMALLER child
     # (O(N*num_leaves) row-touches per tree vs the reference's O(N*depth)
     # leaf-row partitions, data_partition.hpp).  Here the smaller child's
     # in-bag rows are compacted (order-preserving cumsum scatter, so
@@ -530,8 +530,9 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
 
     # Fixed-trip scan instead of lax.while_loop: a while_loop's per-
     # iteration continuation check serializes against the body's full
-    # critical path and costs ~ms/step on remote-attached TPUs, ~8x the
-    # body itself.  The scan always runs max_leaves-1 steps; once growth
+    # critical path (measured ~ms/step before round 6, ~8x the body
+    # itself; not re-measured on the current code).  The scan always
+    # runs max_leaves-1 steps; once growth
     # stops (no positive gain / leaf budget reached) every update is
     # redirected to the DUMMY slot (index max_leaves for leaves, the last
     # node slot for nodes) so the real state passes through untouched —

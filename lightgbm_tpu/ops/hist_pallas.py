@@ -33,6 +33,15 @@ output — counts come out exact.  bf16/i32 round the inputs, so they are
 opt-in behind the f32 parity gate (config.hist_acc; tests pin their
 divergence envelopes).
 
+What the chip does with them (TPU v5e, jax 0.9.0, measured in PR 21 —
+PERF.md Findings): the "f32" dot runs at Mosaic's default matmul
+precision, which rounds the grad/hess operand to bfloat16 before the
+product, so COMPILED f32 histograms equal bf16-mode ones (counts stay
+exact; grad/hess carry up to 2**-8 relative operand error) — as do the
+XLA one-hot histograms of ops/histogram.py, for the same reason.  Only
+the interpreted kernels (CPU) accumulate true f32 products.  "i32" is
+refused by the Mosaic back end on v5e (no int32 matmul).
+
 Fused histogram+gain kernels (round 16): the *_fused variants extend the
 masked / ranged / blocklist sweeps so the LAST grid step, with the
 feature block's accumulators still resident in VMEM, also runs the

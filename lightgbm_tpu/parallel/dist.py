@@ -185,11 +185,7 @@ def init_distributed(config) -> Tuple[int, int]:
         # automatic backend selection, which may well land on CPU; the
         # setting only configures the CPU client, so it is harmless
         # when an accelerator wins.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     # connect with exponential backoff under an overall deadline (the
     # reference's linkers_socket.cpp:24-45 retry loop, typed): the
     # coordinator routinely comes up AFTER the workers in a preemptible

@@ -25,9 +25,9 @@ per-step psum/all-gather collectives are exactly the K=1 ones (issued
 K times inside the loop), and the stacked per-iteration inputs/outputs
 ([K, F] feature masks in, [K, T_ints]/[K, T_floats] packed trees out)
 ride the replicated P() specs unchanged — P() constrains no axis, so
-the extra leading K dimension needs no new partition rules.  The
-check_vma/check_rep=False knob in the wrapper is what already permits
-replicated outputs from loop-carried computations.
+the extra leading K dimension needs no new partition rules.
+check_vma=False in the wrapper is what permits replicated outputs from
+loop-carried computations.
 """
 
 from __future__ import annotations
@@ -50,17 +50,11 @@ FEATURE_AXIS = "feature"
 
 
 def shard_map(fn, *, mesh: Mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level API (with its
-    check_vma knob) when present, else the older experimental API (whose
-    equivalent knob is check_rep).  Every shard_map in this package goes
-    through here so version skew cannot silently disable one path."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    """jax.shard_map with check_vma off (module docstring: replicated
+    outputs from loop-carried computations).  Every shard_map in this
+    package goes through here."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(num_shards: int = 0, axis: str = DATA_AXIS) -> Mesh:

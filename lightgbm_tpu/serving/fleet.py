@@ -176,7 +176,8 @@ class ModelFleet:
                              num_model_predict=cfg.num_model_predict,
                              backend=cfg.serve_backend,
                              matmul=cfg.serve_matmul,
-                             matmul_min_rows=cfg.serve_matmul_min_rows)
+                             matmul_min_rows=cfg.serve_matmul_min_rows,
+                             device_type=cfg.device_type)
         # lazy warm: flat table + host packs NOW (the fast lane serves
         # the first hit), device buckets on first routed batch — the
         # cold-hit cost stays bounded at thousand-model fleet scale.

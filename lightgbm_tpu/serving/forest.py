@@ -14,12 +14,12 @@ model work:
     recompile regardless of batch size.  Batches of
     >= serve_matmul_min_rows rows route through the gather-free matmul
     predictor (`ops.predict.predict_leaf_matmul`, the same kernel and
-    pack builder as the batch predict path) — BASELINE.md measured it
-    >15x over host descent on locally attached TPU — with leaf indices
-    identical to the descent's by construction (exact rank-encoded
-    compares), so the served bytes cannot change with the route.  Score
-    accumulation stays on the host in f64 (boosting order),
-    byte-identical to `task=predict`.
+    pack builder as the batch predict path; its speed on the chip is
+    not measured on the current code) with leaf indices identical to
+    the descent's by construction (exact rank-encoded compares), so
+    the served bytes cannot change with the route.  Score accumulation
+    stays on the host in f64 (boosting order), byte-identical to
+    `task=predict`.
   - host engine (JAX-free fallback, `serve_backend=native` or jax
     unavailable): raw CSV/TSV request text goes through the fused
     native kernel (`native.predict_chunk` — parse -> descend ->
@@ -86,7 +86,8 @@ class ServingForest:
 
     def __init__(self, model_text: str, num_model_predict: int = -1,
                  backend: str = "auto", source: str = "<string>",
-                 matmul: str = "auto", matmul_min_rows: int = 1024):
+                 matmul: str = "auto", matmul_min_rows: int = 1024,
+                 device_type: str = ""):
         header, trees = parse_model_text(model_text)
         self.num_class: int = header["num_class"]
         self.label_idx: int = header["label_index"]
@@ -113,7 +114,7 @@ class ServingForest:
         self.identity: Tuple[str, int] = (self.content_sha,
                                           next(_INSTANCE_SEQ))
 
-        self._engine = self._pick_engine(backend)
+        self._engine = self._pick_engine(backend, device_type)
         self._degraded = False          # circuit breaker pinned us to host
         self._lock = threading.Lock()   # guards lazy pack builds only
         self._jax_pack: Optional[Dict[str, Any]] = None
@@ -146,17 +147,26 @@ class ServingForest:
 
     # -- engine selection ----------------------------------------------
     @staticmethod
-    def _pick_engine(backend: str) -> str:
+    def _pick_engine(backend: str, device_type: str = "") -> str:
+        """native -> host engine.  jax / auto -> the device engine,
+        on the platform `device_type` names (utils/device.py: fatal
+        when it is another, so a forest built outside cli.run cannot
+        answer device_type=tpu from the CPU backend).  Only auto with a
+        jax that does not import selects the host engine, and says so;
+        under device_type=tpu that import error is raised instead."""
         if backend == "native":
             return "host"
-        if backend == "jax":
-            import jax  # noqa: F401  (raises when truly unavailable)
-            return "jax"
         try:
             import jax  # noqa: F401
-            return "jax"
-        except Exception:
+        except ImportError as ex:
+            if backend == "jax" or device_type == "tpu":
+                raise
+            log.warning("serve_backend=auto: jax does not import (%s); "
+                        "serving from the host engine" % ex)
             return "host"
+        from ..utils.device import resolve_device
+        resolve_device(device_type)
+        return "jax"
 
     @property
     def engine(self) -> str:
@@ -370,8 +380,8 @@ class ServingForest:
             # indices identical to both device routes by construction
             return self._build_flat().leaves(x)
         if (engine or self._engine) == "jax":
-            # the device dispatch is a real failure seam (remote TPU
-            # tunnel, OOM, backend death): chaos schedules fail it here
+            # the device dispatch is a real failure seam (OOM, backend
+            # death): chaos schedules fail it here
             faultpoint("serve.dispatch")
             import jax.numpy as jnp
             from ..ops.predict import (predict_leaf_matmul,
@@ -540,7 +550,8 @@ class ServingForest:
 
 def load_forest(path: str, num_model_predict: int = -1,
                 backend: str = "auto", matmul: str = "auto",
-                matmul_min_rows: int = 1024) -> ServingForest:
+                matmul_min_rows: int = 1024,
+                device_type: str = "") -> ServingForest:
     """Read + parse + pack a model file (no warm-up; callers warm)."""
     with open(path) as f:
         text = f.read()
@@ -548,4 +559,5 @@ def load_forest(path: str, num_model_predict: int = -1,
         log.fatal("Model file %s is empty" % path)
     return ServingForest(text, num_model_predict=num_model_predict,
                          backend=backend, source=path, matmul=matmul,
-                         matmul_min_rows=matmul_min_rows)
+                         matmul_min_rows=matmul_min_rows,
+                         device_type=device_type)

@@ -86,20 +86,15 @@ def _worker_main(cfg: Config, idx: int, port: int,
                  ready_path: Optional[str] = None) -> None:
     """Body of one front-end worker process (fresh interpreter, so this
     re-applies the per-process setup the CLI would have done — log
-    level, fault schedule, device platform)."""
+    level, fault schedule).  The device platform is resolved where the
+    forest picks its engine (ServingForest._pick_engine ->
+    utils/device.py).  Every worker takes the default device: on one
+    chip workers 2..N cannot get it, and device_type=tpu makes that
+    fatal there instead of a silent CPU-backend worker."""
     log.set_level_from_verbosity(cfg.verbose)
     if cfg.faults:
         from ..resilience.faults import configure
         configure(cfg.faults)
-    if cfg.serve_backend != "native" and cfg.device_type == "cpu":
-        # mirror cli.Application._apply_device_type: must run before
-        # any JAX backend initializes in this fresh process
-        import jax
-        # graftlint: disable=GL007 -- _worker_main IS a process entry
-        # point (spawned fresh): it re-applies the CLI's device_type in
-        # its own interpreter before any backend initializes, exactly
-        # like cli.Application._apply_device_type does for task=serve
-        jax.config.update("jax_platforms", "cpu")
     from .server import ServingServer, run_until_signal
     cfg = dataclasses.replace(cfg, serve_port=port)
     server = ServingServer(cfg, reuse_port=True, worker_index=idx)

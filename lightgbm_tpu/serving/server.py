@@ -719,7 +719,8 @@ class ServingState:
                     num_model_predict=self.cfg.num_model_predict,
                     backend=self.cfg.serve_backend,
                     matmul=self.cfg.serve_matmul,
-                    matmul_min_rows=self.cfg.serve_matmul_min_rows)
+                    matmul_min_rows=self.cfg.serve_matmul_min_rows,
+                    device_type=self.cfg.device_type)
                 fresh.warm(self.cfg.serve_max_batch_rows)
                 return fresh
 
@@ -1101,7 +1102,16 @@ class ServingServer:
                                  num_model_predict=cfg.num_model_predict,
                                  backend=cfg.serve_backend,
                                  matmul=cfg.serve_matmul,
-                                 matmul_min_rows=cfg.serve_matmul_min_rows)
+                                 matmul_min_rows=cfg.serve_matmul_min_rows,
+                                 device_type=cfg.device_type)
+        elif cfg.device_type == "tpu":
+            # a forest the caller built is held to the config's device
+            # like one loaded here (ServingForest._pick_engine)
+            if forest.engine != "jax":
+                log.fatal("device_type=tpu but the forest serves from "
+                          "the %s engine" % forest.engine)
+            from ..utils.device import resolve_device
+            resolve_device(cfg.device_type)
         t0 = time.time()
         n_buckets = forest.warm(cfg.serve_max_batch_rows)
         log.info("Warmed %s serving forest (%d trees, %d bucket "

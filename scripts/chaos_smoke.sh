@@ -26,11 +26,10 @@ here="$(CDPATH= cd -- "$(dirname -- "$0")/.." && pwd)"
 PY="${PYTHON:-python3}"
 export PYTHONPATH="$here${PYTHONPATH:+:$PYTHONPATH}"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-# jaxlib 0.4.36's persistent compilation cache corrupts the heap on the
-# CPU backend (see tests/conftest.py — root-caused by bisection there);
-# a corrupted training subprocess changes the trajectory mid-run and
-# aborts at teardown, which this smoke would misreport as a resume
-# defect.  Smoke runs don't need cold-compile amortization.
+# CPU smoke runs stay off the persistent compilation cache (see
+# tests/conftest.py: hermetic, and jaxlib 0.9.0 logs an XLA:CPU AOT
+# machine-feature error on every cached load); they don't need
+# cold-compile amortization.
 export LGBM_TPU_NO_COMPILE_CACHE="${LGBM_TPU_NO_COMPILE_CACHE:-1}"
 
 work="$(mktemp -d)"

@@ -9,7 +9,8 @@ hand-derived formula.
 
 Each device count needs its own process (the virtual CPU device count is
 fixed at backend init), so the script re-execs itself per row.  Prints a
-markdown table + one JSON line; results go into BASELINE.md.
+markdown table + one JSON line (counts from compiled HLO, not a device
+measurement).
 """
 
 import json

@@ -25,9 +25,8 @@ here="$(CDPATH= cd -- "$(dirname -- "$0")/.." && pwd)"
 PY="${PYTHON:-python3}"
 export PYTHONPATH="$here${PYTHONPATH:+:$PYTHONPATH}"
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
-# jaxlib 0.4.36's persistent compilation cache corrupts the heap on the
-# CPU backend (see tests/conftest.py); smoke runs don't need
-# cold-compile amortization.
+# CPU smoke runs stay off the persistent compilation cache (see
+# tests/conftest.py); they don't need cold-compile amortization.
 export LGBM_TPU_NO_COMPILE_CACHE="${LGBM_TPU_NO_COMPILE_CACHE:-1}"
 
 work="$(mktemp -d)"

@@ -12,12 +12,15 @@ import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
-# jaxlib 0.4.36's persistent compilation cache corrupts the heap on this
-# CPU backend (layout-sensitive "corrupted size vs. prev_size" aborts /
-# segfaults that killed whole pytest runs at ~test 14 — root-caused by
-# bisection: disabling ONLY the cache makes every run complete).  Tests
-# don't need cold-compile amortization; production keeps the cache.
-# setdefault: an operator who explicitly configured the cache wins.
+# The tier-1 process runs without the persistent compilation cache.
+# Re-tested under jaxlib 0.9.0 (PR 21): the heap corruption 0.4.36
+# showed on this CPU backend is gone — two passes over ~50 tests with
+# the cache on, cold then warm, complete with identical results — but
+# every cached load logs an XLA:CPU AOT "machine feature
+# +prefer-no-scatter is not supported ... could lead to SIGILL" error,
+# and a cache shared across runs makes compile counts (xla_guard)
+# depend on what ran before.  Tests stay hermetic; production keeps the
+# cache.  setdefault: an operator who explicitly configured it wins.
 os.environ.setdefault("LGBM_TPU_NO_COMPILE_CACHE", "1")
 
 import jax  # noqa: E402

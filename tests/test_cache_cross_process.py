@@ -2,18 +2,18 @@
 "repeat shapes pay zero compile" across RUNS, not just in-process).
 
 Two FRESH python processes train the identical tiny model with
-utils/compile_cache.py pointed at a shared temporary cache directory.
-The first run populates the cache (backend compiles > 0); the second
+JAX_COMPILATION_CACHE_DIR pointing at a shared temporary directory —
+the way the cache is placed from outside (utils/compile_cache.py sets
+no directory in code then, only drops the size/time thresholds).  The
+first run populates the cache (backend compiles > 0); the second
 process must lower (tracing always happens) but pay ZERO backend XLA
 compiles — every executable deserializes from the persistent cache —
 and produce byte-identical model text.
 
 The in-process zero-compile test lives in test_compile_guard.py; THIS
 is the cross-run half the ROADMAP claims.  tests/conftest.py disables
-the persistent cache in the tier-1 process itself (jaxlib 0.4.36 CPU
-heap corruption); the subprocesses opt back in deliberately, and an
-abnormal child termination (that known jaxlib defect) skips rather
-than fails.
+the persistent cache in the tier-1 process itself (hermetic compile
+counts); the subprocesses opt back in deliberately.
 """
 
 import json
@@ -36,7 +36,8 @@ from lightgbm_tpu.api import Dataset, train
 from lightgbm_tpu.utils.compile_cache import enable_compilation_cache
 
 enable_compilation_cache()
-assert jax.config.jax_compilation_cache_dir, "cache must be enabled"
+assert (jax.config.jax_compilation_cache_dir
+        == os.environ["JAX_COMPILATION_CACHE_DIR"]), "env dir must win"
 
 x = np.sin(np.linspace(0.0, 1.0, 240 * 5) * 17.0).reshape(240, 5)
 y = (x.sum(axis=1) > 0).astype(np.float32)
@@ -62,24 +63,14 @@ def _run_child(tmp_path, cache_dir):
     env = {k: v for k, v in os.environ.items()
            # the tier-1 parent disables the cache (conftest); children
            # opt back in with their own directory
-           if k not in ("LGBM_TPU_NO_COMPILE_CACHE",
-                        "LIGHTGBM_TPU_NO_CACHE",
-                        "JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS")}
-    env["LIGHTGBM_TPU_CACHE_DIR"] = str(cache_dir)
+           if k not in ("LGBM_TPU_NO_COMPILE_CACHE", "XLA_FLAGS")}
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env["LGBM_TPU_REPO"] = os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))
     proc = subprocess.run([sys.executable, str(script)], env=env,
                           capture_output=True, text=True, timeout=600)
-    if proc.returncode != 0:
-        if proc.returncode < 0:
-            # killed by a signal: the documented jaxlib 0.4.36 CPU
-            # persistent-cache heap corruption, an environment defect,
-            # not a regression in the cache plumbing under test
-            pytest.skip("persistent-cache child crashed with signal %d "
-                        "(known jaxlib CPU cache instability)"
-                        % -proc.returncode)
-        raise AssertionError("cache child failed:\n%s\n%s"
-                             % (proc.stdout, proc.stderr))
+    assert proc.returncode == 0, ("cache child failed:\n%s\n%s"
+                                  % (proc.stdout, proc.stderr))
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 

@@ -7,7 +7,7 @@
     NOTHING (the power-of-two pre-compile contract, PR 2).
   * training: two identical in-process trainings compile only in the
     first run — the fused step really is one compile per
-    (shape, config) (the compile-amortization contract, PR 1/BASELINE).
+    (shape, config) (the compile-amortization contract, PR 1).
   * serving metrics: the lock-discipline regression the GL006 audit
     demanded (threaded hammer on the counters).
 """

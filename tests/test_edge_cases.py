@@ -2,6 +2,8 @@
 without crashing (the reference has no tests at all here; these pin the
 padding, trivial-feature, dummy-slot and regularization edge paths)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -114,8 +116,9 @@ def test_lambdarank_query_undercount_fatals(rng):
 
 
 def test_compile_cache_documented_optout(monkeypatch):
-    """BASELINE.md documents LGBM_TPU_NO_COMPILE_CACHE as the opt-out; it
-    must actually disable the cache (round-2 doc/flag mismatch)."""
+    """utils/compile_cache.py documents LGBM_TPU_NO_COMPILE_CACHE as the
+    opt-out; it must actually disable the cache (round-2 doc/flag
+    mismatch)."""
     import jax
     from lightgbm_tpu.utils import compile_cache as cc
     monkeypatch.setenv("LGBM_TPU_NO_COMPILE_CACHE", "1")
@@ -128,6 +131,82 @@ def test_compile_cache_documented_optout(monkeypatch):
         assert cc._enabled is False
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+@pytest.fixture
+def cache_config(monkeypatch):
+    """enable_compilation_cache() from a clean slate, jax.config
+    restored afterwards."""
+    import jax
+    from lightgbm_tpu.utils import compile_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.delenv("LGBM_TPU_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(cc, "_enabled", False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    try:
+        yield cc
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+
+
+def test_compile_cache_default_dir_is_fixed_inside_checkout(
+        cache_config, monkeypatch, tmp_path):
+    """Unset JAX_COMPILATION_CACHE_DIR -> <checkout>/.jax_cache: a fixed
+    path (the chip tool keeps what is inside the checkout; a path with
+    a pid, time or tempdir component never hits twice)."""
+    import jax
+    cc = cache_config
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    # the mechanism, without writing into the checkout from a test
+    target = str(tmp_path / ".jax_cache")
+    monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", target)
+    cc.enable_compilation_cache()
+    assert jax.config.jax_compilation_cache_dir == target
+    assert os.path.isdir(target)
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+
+def test_compile_cache_env_dir_wins_and_thresholds_still_drop(
+        cache_config, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set -> no directory is set in code (JAX
+    reads the variable itself), but sub-second jits are cached there
+    too."""
+    import jax
+    cc = cache_config
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "ext"))
+    monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR",
+                        str(tmp_path / "must_not_exist"))
+    cc.enable_compilation_cache()
+    assert jax.config.jax_compilation_cache_dir is None   # untouched
+    assert not os.path.exists(str(tmp_path / "must_not_exist"))
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+
+def test_compile_cache_dir_preset_through_jax_config_is_kept(
+        cache_config, monkeypatch, tmp_path):
+    """An embedding process that pointed jax.config at its own cache
+    keeps it (an installed package's default resolves inside
+    site-packages); the thresholds still drop."""
+    import jax
+    cc = cache_config
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "own"))
+    monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR",
+                        str(tmp_path / "must_not_exist"))
+    cc.enable_compilation_cache()
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "own")
+    assert not os.path.exists(str(tmp_path / "must_not_exist"))
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
 
 
 def test_predict_empty_input_preserves_output(rng, tmp_path):

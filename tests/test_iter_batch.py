@@ -296,8 +296,7 @@ def test_auto_k_divides_metric_freq():
     g = _booster({"iter_batch": "auto", "metric": "binary_logloss",
                   "metric_freq": 6})
     # this suite runs on the CPU backend, where auto resolves to the
-    # per-iteration oracle (local dispatch is cheap; the K-scan exists
-    # to kill remote-attached dispatch round-trips)
+    # per-iteration oracle (gbdt._auto_iter_batch)
     assert g._auto_iter_batch() == 1
     # the accelerator policy: default 8, shrunk to the largest divisor
     # of metric_freq once metric output is live
