@@ -22,6 +22,7 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..analysis.contracts import contract
 from ..config import Config
@@ -34,7 +35,7 @@ from ..ops.split import SplitParams
 from ..resilience.atomic import read_npz, text_writer, write_npz
 from ..resilience.snapshot import fingerprint_diff, resume_fingerprint
 from ..resilience.faults import faultpoint
-from ..utils import log
+from ..utils import log, spans
 from ..utils.mt19937 import Mt19937Random
 from .tree import Tree
 
@@ -191,15 +192,23 @@ def _batch_iters(body, spec, k):
     return batched
 
 
-# Device-dispatch accounting for bench.py (dispatches_per_tree): every
-# training-path executable invocation notes itself here.  A host counter,
-# not a guard — analysis/guards.py counts the transfers.
+# Device-dispatch accounting: every training-path executable is called
+# inside `with _enqueue(kind, k):`, which counts the dispatch and opens
+# the profiler's lgbm.enqueue host span (utils/spans.py) in one place, so
+# the count and the spans cannot drift.  dispatch_count() is read by the
+# benchmark's trees_per_dispatch and by bench.py.  A host counter, not a
+# guard — analysis/guards.py counts the transfers.
 _DISPATCHES = 0
 
 
-def _note_dispatch() -> None:
+def _enqueue(kind: str, k: int) -> TraceAnnotation:
+    """The span around one call of a jitted training executable:
+    `kind` names it (spans.ENQUEUE_KINDS), `k` is the boosting
+    iterations it covers.  The call returns when the work is enqueued,
+    not when the device is done (unless it compiles first)."""
     global _DISPATCHES
     _DISPATCHES += 1
+    return TraceAnnotation(spans.ENQUEUE, kind=kind, k=k)
 
 
 def dispatch_count() -> int:
@@ -214,10 +223,13 @@ def _fused_step_body(grad_fn, grow_kw, lr, dtype, compact_rows=0):
     def step(scores, valid_scores, bag_mask, fmask, bins, valid_bins,
              gstate, stopped):
         bag = _unpack_bag(bag_mask, bins.shape[1])
-        grad, hess = grad_fn(scores[0], gstate)
-        dev_tree, leaf_id = grow_tree_bagged(
-            bins, grad.astype(dtype), hess.astype(dtype),
-            bag, fmask, bag_rows=compact_rows, **grow_kw)
+        with jax.named_scope(spans.OBJECTIVE):
+            grad, hess = grad_fn(scores[0], gstate)
+            grad, hess = grad.astype(dtype), hess.astype(dtype)
+        with jax.named_scope(spans.GROW):
+            dev_tree, leaf_id = grow_tree_bagged(
+                bins, grad, hess, bag, fmask, bag_rows=compact_rows,
+                **grow_kw)
         # deferred stump stop: once any tree fails to split, every later
         # step no-ops its score updates, so a late host flush truncates
         # at the exact reference stop point (gbdt.cpp:186) with scores
@@ -225,16 +237,19 @@ def _fused_step_body(grad_fn, grow_kw, lr, dtype, compact_rows=0):
         # with bagging/feature_fraction
         live = jnp.logical_not(stopped)
         stopped = stopped | (dev_tree.num_leaves <= 1)
-        leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
-                              0.0).astype(jnp.float32)
-        scores = scores.at[0].add(leaf_vals[leaf_id])
+        with jax.named_scope(spans.SCORE_UPDATE):
+            leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
+                                  0.0).astype(jnp.float32)
+            scores = scores.at[0].add(leaf_vals[leaf_id])
         new_valid = []
-        for vs, vbins in zip(valid_scores, valid_bins):
-            vleaf = predict_leaf_binned(
-                dev_tree.split_feature, dev_tree.threshold_bin,
-                dev_tree.left_child, dev_tree.right_child, vbins)
-            new_valid.append(vs.at[0].add(leaf_vals[vleaf]))
-        ints, floats = _pack_tree(dev_tree)
+        with jax.named_scope(spans.VALID_UPDATE):
+            for vs, vbins in zip(valid_scores, valid_bins):
+                vleaf = predict_leaf_binned(
+                    dev_tree.split_feature, dev_tree.threshold_bin,
+                    dev_tree.left_child, dev_tree.right_child, vbins)
+                new_valid.append(vs.at[0].add(leaf_vals[vleaf]))
+        with jax.named_scope(spans.PACK_TREE):
+            ints, floats = _pack_tree(dev_tree)
         return scores, new_valid, ints, floats, stopped
     return step
 
@@ -292,41 +307,49 @@ def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype,
     def step(scores, valid_scores, bag_mask, fmask, bins, valid_bins,
              gstate, row_order, stopped):
         bag = _unpack_bag(bag_mask, bins.shape[1])
-        grad, hess = grad_fn(scores[0], gstate)
-        dev_tree, leaf_id = grow_tree_bagged(
-            bins, grad.astype(dtype), hess.astype(dtype),
-            bag, fmask, bag_rows=compact_rows, **grow_kw)
+        with jax.named_scope(spans.OBJECTIVE):
+            grad, hess = grad_fn(scores[0], gstate)
+            grad, hess = grad.astype(dtype), hess.astype(dtype)
+        with jax.named_scope(spans.GROW):
+            dev_tree, leaf_id = grow_tree_bagged(
+                bins, grad, hess, bag, fmask, bag_rows=compact_rows,
+                **grow_kw)
         live = jnp.logical_not(stopped)
         stopped = stopped | (dev_tree.num_leaves <= 1)
-        leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
-                              0.0).astype(jnp.float32)
-        scores = scores.at[0].add(leaf_vals[leaf_id])
+        with jax.named_scope(spans.SCORE_UPDATE):
+            leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
+                                  0.0).astype(jnp.float32)
+            scores = scores.at[0].add(leaf_vals[leaf_id])
         new_valid = []
-        for vs, vbins in zip(valid_scores, valid_bins):
-            vleaf = predict_leaf_binned(
-                dev_tree.split_feature, dev_tree.threshold_bin,
-                dev_tree.left_child, dev_tree.right_child, vbins)
-            new_valid.append(vs.at[0].add(leaf_vals[vleaf]))
-        ints, floats = _pack_tree(dev_tree)
+        with jax.named_scope(spans.VALID_UPDATE):
+            for vs, vbins in zip(valid_scores, valid_bins):
+                vleaf = predict_leaf_binned(
+                    dev_tree.split_feature, dev_tree.threshold_bin,
+                    dev_tree.left_child, dev_tree.right_child, vbins)
+                new_valid.append(vs.at[0].add(leaf_vals[vleaf]))
+        with jax.named_scope(spans.PACK_TREE):
+            ints, floats = _pack_tree(dev_tree)
         n = bins.shape[1]
-        if 0 < compact_rows < n:
-            # window-local stable sort; the OOB tail stays in place and
-            # every gather below touches only the window
-            m = compact_rows
-            rel_w = jnp.argsort(leaf_id[:m], stable=True).astype(jnp.int32)
-            rel, (bins_new, scores, bag_new, order_new) = \
-                _permute_window_rows(rel_w, m, n,
-                                     [bins, scores, bag, row_order])
-        else:
-            # stable sort by this tree's leaves; padded rows ride along
-            # via their tracked leaf_id and stay permanently out-of-bag
-            # through the permuted bag mask
-            rel = jnp.argsort(leaf_id, stable=True).astype(jnp.int32)
-            bins_new = jnp.take(bins, rel, axis=1)
-            scores = jnp.take(scores, rel, axis=1)
-            bag_new = jnp.take(bag, rel)
-            order_new = jnp.take(row_order, rel)
-        gstate_new = permute_state(gstate, rel)
+        with jax.named_scope(spans.RESORT):
+            if 0 < compact_rows < n:
+                # window-local stable sort; the OOB tail stays in place
+                # and every gather below touches only the window
+                m = compact_rows
+                rel_w = jnp.argsort(leaf_id[:m],
+                                    stable=True).astype(jnp.int32)
+                rel, (bins_new, scores, bag_new, order_new) = \
+                    _permute_window_rows(rel_w, m, n,
+                                         [bins, scores, bag, row_order])
+            else:
+                # stable sort by this tree's leaves; padded rows ride
+                # along via their tracked leaf_id and stay permanently
+                # out-of-bag through the permuted bag mask
+                rel = jnp.argsort(leaf_id, stable=True).astype(jnp.int32)
+                bins_new = jnp.take(bins, rel, axis=1)
+                scores = jnp.take(scores, rel, axis=1)
+                bag_new = jnp.take(bag, rel)
+                order_new = jnp.take(row_order, rel)
+            gstate_new = permute_state(gstate, rel)
         return (scores, new_valid, ints, floats, bins_new, bag_new,
                 gstate_new, order_new, stopped)
     return step
@@ -424,40 +447,49 @@ def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
                                   sc, bf)
             return (sc, bf), None
 
-        (scores, bank_f), _ = jax.lax.scan(drop_body, (scores, bank_f),
-                                           (drop_idx, drop_mask))
+        with jax.named_scope(spans.DART_BANK):
+            (scores, bank_f), _ = jax.lax.scan(
+                drop_body, (scores, bank_f), (drop_idx, drop_mask))
 
         bag = _unpack_bag(bag_mask, bins.shape[1])
-        grad, hess = grad_fn(scores[0], gstate)
-        dev_tree, leaf_id = grow_tree_bagged(bins, grad.astype(dtype),
-                                             hess.astype(dtype), bag,
-                                             fmask,
-                                             bag_rows=compact_rows,
-                                             **grow_kw)
+        with jax.named_scope(spans.OBJECTIVE):
+            grad, hess = grad_fn(scores[0], gstate)
+            grad, hess = grad.astype(dtype), hess.astype(dtype)
+        with jax.named_scope(spans.GROW):
+            dev_tree, leaf_id = grow_tree_bagged(
+                bins, grad, hess, bag, fmask, bag_rows=compact_rows,
+                **grow_kw)
         stopped = stopped | (dev_tree.num_leaves <= 1)
-        leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
-                              0.0).astype(jnp.float32)
-        scores = scores.at[0].add(leaf_vals[leaf_id])
+        with jax.named_scope(spans.SCORE_UPDATE):
+            leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
+                                  0.0).astype(jnp.float32)
+            scores = scores.at[0].add(leaf_vals[leaf_id])
         wrow = jnp.where(live, t_row, bank_i.shape[0] - 1)  # dead -> dummy
         new_valid = []
         new_vbanks = []
         for vs, vbins, vb in zip(valid_scores, valid_bins, vbanks):
-            vleaf = predict_leaf_binned(
-                dev_tree.split_feature, dev_tree.threshold_bin,
-                dev_tree.left_child, dev_tree.right_child, vbins)
-            new_valid.append(vs.at[0].add(leaf_vals[vleaf]))
-            new_vbanks.append(vb.at[wrow].set(
-                vleaf.astype(leaf_bank.dtype)))
-        ints, floats = _pack_tree(dev_tree)
+            with jax.named_scope(spans.VALID_UPDATE):
+                vleaf = predict_leaf_binned(
+                    dev_tree.split_feature, dev_tree.threshold_bin,
+                    dev_tree.left_child, dev_tree.right_child, vbins)
+                new_valid.append(vs.at[0].add(leaf_vals[vleaf]))
+            with jax.named_scope(spans.DART_BANK):
+                new_vbanks.append(vb.at[wrow].set(
+                    vleaf.astype(leaf_bank.dtype)))
+        with jax.named_scope(spans.PACK_TREE):
+            ints, floats = _pack_tree(dev_tree)
         # the bank row holds the tree's CURRENT (shrunk) leaf values,
         # like the reference's in-memory trees; the RETURNED floats stay
         # raw — the host applies the iteration's shrinkage in f64 like
         # every other fused path, so materialized models carry no extra
         # device-dtype rounding
-        bank_row_f = floats.at[LV0:LV1].set(dev_tree.leaf_value[:-1] * lr)
-        bank_i = bank_i.at[wrow].set(ints)
-        bank_f = bank_f.at[wrow].set(bank_row_f)
-        leaf_bank = leaf_bank.at[wrow].set(leaf_id.astype(leaf_bank.dtype))
+        with jax.named_scope(spans.DART_BANK):
+            bank_row_f = floats.at[LV0:LV1].set(
+                dev_tree.leaf_value[:-1] * lr)
+            bank_i = bank_i.at[wrow].set(ints)
+            bank_f = bank_f.at[wrow].set(bank_row_f)
+            leaf_bank = leaf_bank.at[wrow].set(
+                leaf_id.astype(leaf_bank.dtype))
 
         def norm_body(carry, xs):
             sc, vss, bf = carry
@@ -480,9 +512,10 @@ def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
                 sc, vss, bf)
             return (sc, vss, bf), None
 
-        (scores, vss, bank_f), _ = jax.lax.scan(
-            norm_body, (scores, tuple(new_valid), bank_f),
-            (drop_idx, drop_mask))
+        with jax.named_scope(spans.DART_BANK):
+            (scores, vss, bank_f), _ = jax.lax.scan(
+                norm_body, (scores, tuple(new_valid), bank_f),
+                (drop_idx, drop_mask))
         # ints/floats (the AS-TRAINED packed tree, before any later drop
         # mutation) also return to the host: materialization needs the
         # pristine values for the f64 factor replay, with no bank pull
@@ -524,27 +557,33 @@ def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder=False,
     the SAME dispatch, exactly like the single-class reorder step."""
     def step(scores, valid_scores, bag_masks, fmasks, bins, valid_bins,
              gstate, stopped, *row_order):
-        grad, hess = grad_fn(scores, gstate)            # [K, N] each
+        with jax.named_scope(spans.OBJECTIVE):
+            grad, hess = grad_fn(scores, gstate)        # [K, N] each
         num_class = grad.shape[0]
 
         def body(carry, xs):
             sc, vss, stop = carry
             cls, g, h, bag, fm = xs
-            dev_tree, leaf_id = grow_tree_bagged(
-                bins, g.astype(dtype), h.astype(dtype), bag, fm,
-                bag_rows=compact_rows, **grow_kw)
+            with jax.named_scope(spans.OBJECTIVE):
+                g, h = g.astype(dtype), h.astype(dtype)
+            with jax.named_scope(spans.GROW):
+                dev_tree, leaf_id = grow_tree_bagged(
+                    bins, g, h, bag, fm, bag_rows=compact_rows, **grow_kw)
             live = jnp.logical_not(stop)
             stop = stop | (dev_tree.num_leaves <= 1)
-            leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
-                                  0.0).astype(jnp.float32)
-            sc = sc.at[cls].add(leaf_vals[leaf_id])
+            with jax.named_scope(spans.SCORE_UPDATE):
+                leaf_vals = jnp.where(live, dev_tree.leaf_value * lr,
+                                      0.0).astype(jnp.float32)
+                sc = sc.at[cls].add(leaf_vals[leaf_id])
             new_vss = []
-            for vs, vbins in zip(vss, valid_bins):
-                vleaf = predict_leaf_binned(
-                    dev_tree.split_feature, dev_tree.threshold_bin,
-                    dev_tree.left_child, dev_tree.right_child, vbins)
-                new_vss.append(vs.at[cls].add(leaf_vals[vleaf]))
-            ints, floats = _pack_tree(dev_tree)
+            with jax.named_scope(spans.VALID_UPDATE):
+                for vs, vbins in zip(vss, valid_bins):
+                    vleaf = predict_leaf_binned(
+                        dev_tree.split_feature, dev_tree.threshold_bin,
+                        dev_tree.left_child, dev_tree.right_child, vbins)
+                    new_vss.append(vs.at[cls].add(leaf_vals[vleaf]))
+            with jax.named_scope(spans.PACK_TREE):
+                ints, floats = _pack_tree(dev_tree)
             ys = ((ints, floats, leaf_id) if reorder else (ints, floats))
             return (sc, tuple(new_vss), stop), ys
 
@@ -561,29 +600,30 @@ def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder=False,
         # construction), composing the relative permutation.  Under bag
         # compaction only the static union window re-sorts; the OOB
         # tail keeps its positions (it never enters histograms)
-        n = bins.shape[1]
-        m = compact_rows if 0 < compact_rows < n else n
-        rel = jnp.argsort(leaf_k[num_class - 1, :m],
-                          stable=True).astype(jnp.int32)
-        for k in range(num_class - 2, -1, -1):
-            keys = jnp.take(leaf_k[k, :m], rel)
-            rel = jnp.take(rel, jnp.argsort(keys,
-                                            stable=True).astype(jnp.int32))
-        if m < n:
-            # window-local gathers + contiguous tail copy, like the
-            # single-class reorder branch — only gstate needs the
-            # composed full-length permutation (doc_idx remaps etc.)
-            rel, (bins_new, scores, bag_new, order_new) = \
-                _permute_window_rows(rel, m, n, [bins, scores, bag_masks,
-                                                 row_order[0]])
-        else:
-            bins_new = jnp.take(bins, rel, axis=1)
-            scores = jnp.take(scores, rel, axis=1)
-            bag_new = jnp.take(bag_masks, rel, axis=1)
-            order_new = jnp.take(row_order[0], rel)
-        gstate_new = (permute_state(gstate, rel) if permute_state
-                      is not None else jax.tree_util.tree_map(
-                          lambda a: jnp.take(a, rel, axis=-1), gstate))
+        with jax.named_scope(spans.RESORT):
+            n = bins.shape[1]
+            m = compact_rows if 0 < compact_rows < n else n
+            rel = jnp.argsort(leaf_k[num_class - 1, :m],
+                              stable=True).astype(jnp.int32)
+            for k in range(num_class - 2, -1, -1):
+                keys = jnp.take(leaf_k[k, :m], rel)
+                rel = jnp.take(
+                    rel, jnp.argsort(keys, stable=True).astype(jnp.int32))
+            if m < n:
+                # window-local gathers + contiguous tail copy, like the
+                # single-class reorder branch — only gstate needs the
+                # composed full-length permutation (doc_idx remaps etc.)
+                rel, (bins_new, scores, bag_new, order_new) = \
+                    _permute_window_rows(
+                        rel, m, n, [bins, scores, bag_masks, row_order[0]])
+            else:
+                bins_new = jnp.take(bins, rel, axis=1)
+                scores = jnp.take(scores, rel, axis=1)
+                bag_new = jnp.take(bag_masks, rel, axis=1)
+                order_new = jnp.take(row_order[0], rel)
+            gstate_new = (permute_state(gstate, rel) if permute_state
+                          is not None else jax.tree_util.tree_map(
+                              lambda a: jnp.take(a, rel, axis=-1), gstate))
         return (scores, list(vss), ints_k, floats_k, stopped,
                 bins_new, bag_new, gstate_new, order_new)
     return step
@@ -713,18 +753,19 @@ def _bag_arrange_body(permute_state, multi):
     masks (the static window bounds the union; each class still masks
     its own rows inside it)."""
     def arrange(bins, scores, mask, gstate, order, *bank):
-        key = mask.any(axis=0) if multi else mask
-        rel = jnp.argsort(jnp.logical_not(key),
-                          stable=True).astype(jnp.int32)
-        bins_new = jnp.take(bins, rel, axis=1)
-        scores_new = jnp.take(scores, rel, axis=1)
-        mask_new = (jnp.take(mask, rel, axis=1) if multi
-                    else jnp.take(mask, rel))
-        gstate_new = permute_state(gstate, rel)
-        order_new = jnp.take(order, rel)
-        out = (bins_new, scores_new, mask_new, gstate_new, order_new)
-        for b in bank:   # DART leaf bank [T, N]: per-row on its last axis
-            out += (jnp.take(b, rel, axis=1),)
+        with jax.named_scope(spans.BAG_ARRANGE):
+            key = mask.any(axis=0) if multi else mask
+            rel = jnp.argsort(jnp.logical_not(key),
+                              stable=True).astype(jnp.int32)
+            bins_new = jnp.take(bins, rel, axis=1)
+            scores_new = jnp.take(scores, rel, axis=1)
+            mask_new = (jnp.take(mask, rel, axis=1) if multi
+                        else jnp.take(mask, rel))
+            gstate_new = permute_state(gstate, rel)
+            order_new = jnp.take(order, rel)
+            out = (bins_new, scores_new, mask_new, gstate_new, order_new)
+            for b in bank:   # DART leaf bank [T, N]: per-row, last axis
+                out += (jnp.take(b, rel, axis=1),)
         return out
     return arrange
 
@@ -1332,19 +1373,25 @@ class GBDT:
     def train_one_iter(self, gradients=None, hessians=None,
                        is_eval: bool = True) -> bool:
         """One boosting iteration (gbdt.cpp:169-205). Returns True when
-        training must stop."""
+        training must stop.  A segment of one in the profiler's trace;
+        subclasses override _one_iter."""
+        with TraceAnnotation(spans.SEGMENT, iter=self.iter, k=1):
+            return self._one_iter(gradients, hessians, is_eval)
+
+    def _one_iter(self, gradients, hessians, is_eval: bool) -> bool:
         cfg = self.config
         if gradients is None and self._can_fuse():
             # fully-fused iteration: gradients -> grow -> score updates ->
             # tree packing in ONE dispatch with donated score buffers
-            self._ensure_layout()
-            self._bagging(self.iter, 0)
-            self._ensure_bag_arranged()
-            fmask = self._feature_mask(0)
-            fmask_dev = (self.grower.replicate(fmask) if self._mh_fused
-                         else jnp.asarray(fmask))
-            self._models.extend(self._run_fused(
-                self._bag_mask_dev_fused(0), fmask_dev))
+            with TraceAnnotation(spans.HOST_INPUTS):
+                self._ensure_layout()
+                self._bagging(self.iter, 0)
+                self._ensure_bag_arranged()
+                fmask = self._feature_mask(0)
+                fmask_dev = (self.grower.replicate(fmask) if self._mh_fused
+                             else jnp.asarray(fmask))
+                bag_dev = self._bag_mask_dev_fused(0)
+            self._models.extend(self._run_fused(bag_dev, fmask_dev))
         elif gradients is None and self._can_fuse_multi():
             # multiclass fused iteration: all K per-iteration trees in
             # one dispatch (class-wise scan, _make_fused_step_multi)
@@ -1370,11 +1417,12 @@ class GBDT:
                     grad = jnp.pad(grad, pad)
                     hess = jnp.pad(hess, pad)
             for cls in range(self.num_class):
-                self._bagging(self.iter, cls)
-                fmask = self._feature_mask(cls)
+                with TraceAnnotation(spans.HOST_INPUTS):
+                    self._bagging(self.iter, cls)
+                    fmask = self._feature_mask(cls)
+                    bag_dev = self._bag_mask_dev(cls)
                 self._models.append(self._train_tree(
-                    grad[cls], hess[cls], self._bag_mask_dev(cls), fmask,
-                    cls))
+                    grad[cls], hess[cls], bag_dev, fmask, cls))
         self.iter += 1
         self.num_used_model = len(self._models) // self.num_class
         custom_grads = gradients is not None
@@ -1495,15 +1543,17 @@ class GBDT:
         # scheduler ends segments at re-bag boundaries), then the K
         # per-class feature masks
         fmasks_list = []
-        for j in range(k_iters):
-            for cls in range(self.num_class):
-                self._bagging(self.iter + j, cls)
-            if j == 0:
-                self._ensure_bag_arranged()
-            fmasks_list.append(np.stack([self._feature_mask(c)
-                                         for c in range(self.num_class)]))
-        fmasks = (fmasks_list[0] if k_iters == 1
-                  else np.stack(fmasks_list))
+        with TraceAnnotation(spans.HOST_INPUTS):
+            for j in range(k_iters):
+                for cls in range(self.num_class):
+                    self._bagging(self.iter + j, cls)
+                if j == 0:
+                    self._ensure_bag_arranged()
+                fmasks_list.append(
+                    np.stack([self._feature_mask(c)
+                              for c in range(self.num_class)]))
+            fmasks = (fmasks_list[0] if k_iters == 1
+                      else np.stack(fmasks_list))
         # shared-joint-order ordered-partition growth (round 4): same
         # gate and cadence as the single-class reorder — re-sort after
         # the first iteration, then every reorder_every (hist_ranged
@@ -1544,24 +1594,26 @@ class GBDT:
                                           compact, k_iters)
 
         fn = _get_fused_step(key, make)
-        _note_dispatch()
-        fmasks_dev = (self.grower.replicate(fmasks) if self._mh_fused
-                      else jnp.asarray(fmasks))
-        common = (self.scores, list(self.valid_scores),
-                  self._bag_masks_stacked_dev(), fmasks_dev,
-                  self.bins_dev, tuple(self.valid_bins_dev), gstate,
-                  self._dev_stopped)
+        with TraceAnnotation(spans.HOST_INPUTS):
+            fmasks_dev = (self.grower.replicate(fmasks) if self._mh_fused
+                          else jnp.asarray(fmasks))
+            common = (self.scores, list(self.valid_scores),
+                      self._bag_masks_stacked_dev(), fmasks_dev,
+                      self.bins_dev, tuple(self.valid_bins_dev), gstate,
+                      self._dev_stopped)
+            if reorder:
+                common += (self._row_order if self._row_order is not None
+                           else self._identity_order_dev(),)
+        with _enqueue("multi", k_iters):
+            out = fn(*common)
         if reorder:
-            order = (self._row_order if self._row_order is not None
-                     else self._identity_order_dev())
             (scores, valid, ints_k, floats_k, self._dev_stopped,
              self.bins_dev, self._bag_stacked, self._gstate_override,
-             self._row_order) = fn(*common, order)
+             self._row_order) = out
             self._inv_order = None
             self._trees_since_reorder = 0
         else:
-            (scores, valid, ints_k, floats_k,
-             self._dev_stopped) = fn(*common)
+            scores, valid, ints_k, floats_k, self._dev_stopped = out
             self._trees_since_reorder += k_iters
         self.scores = scores
         self.valid_scores = list(valid)
@@ -1737,36 +1789,40 @@ class GBDT:
         (metric lines, early stopping, flushes, re-bagging draws) runs
         only at segment boundaries, exactly where the K=1 loop would
         have run it.  Returns (stop, iterations_done)."""
-        k = self._plan_segment(max_iters, is_eval)
+        with TraceAnnotation(spans.HOST_INPUTS):
+            k = self._plan_segment(max_iters, is_eval)
         if k <= 1:
             return self.train_one_iter(None, None, is_eval), 1
-        it0 = self.iter
-        self._train_segment_fused(k)
-        self.iter += k
-        self.num_used_model = len(self._models) // self.num_class
-        if is_eval:
-            return self.eval_and_check_early_stopping(), k
-        if it0 // self._flush_every != self.iter // self._flush_every:
-            # the segment crossed a deferred-flush boundary: same
-            # amortized device->host pull cadence as the K=1 loop
-            if self._sync_stop(self._flush_pending()):
-                log.info("Stopped training because there are no more "
-                         "leafs that meet the split requirements.")
-                return True, k
-        return False, k
+        with TraceAnnotation(spans.SEGMENT, iter=self.iter, k=k):
+            it0 = self.iter
+            self._train_segment_fused(k)
+            self.iter += k
+            self.num_used_model = len(self._models) // self.num_class
+            if is_eval:
+                return self.eval_and_check_early_stopping(), k
+            if it0 // self._flush_every != self.iter // self._flush_every:
+                # the segment crossed a deferred-flush boundary: same
+                # amortized device->host pull cadence as the K=1 loop
+                if self._sync_stop(self._flush_pending()):
+                    log.info("Stopped training because there are no more "
+                             "leafs that meet the split requirements.")
+                    return True, k
+            return False, k
 
     def _train_segment_fused(self, k: int) -> None:
         """Dispatch one K-iteration segment and append the pending trees
         (DART overrides with its banked variant)."""
         if self._can_fuse():
-            self._ensure_layout()
-            self._bagging(self.iter, 0)
-            self._ensure_bag_arranged()
-            fmasks = np.stack([self._feature_mask(0) for _ in range(k)])
-            fmasks_dev = (self.grower.replicate(fmasks) if self._mh_fused
-                          else jnp.asarray(fmasks))
-            self._models.extend(self._run_fused(
-                self._bag_mask_dev_fused(0), fmasks_dev, k))
+            with TraceAnnotation(spans.HOST_INPUTS):
+                self._ensure_layout()
+                self._bagging(self.iter, 0)
+                self._ensure_bag_arranged()
+                fmasks = np.stack([self._feature_mask(0)
+                                   for _ in range(k)])
+                fmasks_dev = (self.grower.replicate(fmasks)
+                              if self._mh_fused else jnp.asarray(fmasks))
+                bag_dev = self._bag_mask_dev_fused(0)
+            self._models.extend(self._run_fused(bag_dev, fmasks_dev, k))
         else:
             self._models.extend(self._run_fused_multi(k))
 
@@ -1953,11 +2009,11 @@ class GBDT:
                                      bank is not None)
 
         fn = _get_fused_step(key, make)
-        _note_dispatch()
         args = (self.bins_dev, self.scores, mask, gstate, order)
         if bank is not None:
             args += (bank,)
-        out = fn(*args)
+        with _enqueue("arrange", 0):
+            out = fn(*args)
         self.bins_dev, self.scores, mask_new, gstate_new, order_new = \
             out[:5]
         if bank is not None:
@@ -2028,7 +2084,6 @@ class GBDT:
                                     k_iters)
 
         fn = _get_fused_step(key, make)
-        _note_dispatch()
         if reorder:
             # the reorder executable must see ONE bag-mask signature:
             # dispatches under an active row order pass the cached
@@ -2041,11 +2096,12 @@ class GBDT:
                 bag_mask_dev = _unpack_bag_jit(bag_mask_dev, self.n_pad)
             order = (self._row_order if self._row_order is not None
                      else self._identity_order_dev())
-            (scores, valid, ints, floats, bins_new, bag_new, gstate_new,
-             order_new, self._dev_stopped) = fn(
-                self.scores, list(self.valid_scores), bag_mask_dev,
-                fmask_dev, self.bins_dev, tuple(self.valid_bins_dev),
-                gstate, order, self._dev_stopped)
+            with _enqueue("resort", k_iters):
+                (scores, valid, ints, floats, bins_new, bag_new,
+                 gstate_new, order_new, self._dev_stopped) = fn(
+                    self.scores, list(self.valid_scores), bag_mask_dev,
+                    fmask_dev, self.bins_dev, tuple(self.valid_bins_dev),
+                    gstate, order, self._dev_stopped)
             self.bins_dev = bins_new
             self._bag_dev_packed[0] = bag_new
             self._gstate_override = gstate_new
@@ -2053,10 +2109,11 @@ class GBDT:
             self._inv_order = None
             self._trees_since_reorder = 0
         else:
-            scores, valid, ints, floats, self._dev_stopped = fn(
-                self.scores, list(self.valid_scores), bag_mask_dev,
-                fmask_dev, self.bins_dev, tuple(self.valid_bins_dev),
-                gstate, self._dev_stopped)
+            with _enqueue("scan", k_iters):
+                scores, valid, ints, floats, self._dev_stopped = fn(
+                    self.scores, list(self.valid_scores), bag_mask_dev,
+                    fmask_dev, self.bins_dev, tuple(self.valid_bins_dev),
+                    gstate, self._dev_stopped)
             self._trees_since_reorder += k_iters
         self.scores = scores
         self.valid_scores = list(valid)
@@ -2073,41 +2130,44 @@ class GBDT:
                             "path is parity-tested against (PARITY.md)")
     def _train_tree(self, grad, hess, bag_mask_dev, fmask, cls):
         cfg = self.config
-        _note_dispatch()   # the general path: one grow dispatch per tree
-        if self.grower is not None and self._mh:
-            # assemble process-local grad/hess into global sharded arrays,
-            # grow SPMD across hosts, then pull the tree (replicated) and
-            # this process's leaf_id block back to local
-            g = self.grower.shard_rows(
-                np.asarray(grad, dtype=self.dtype), self.n_pad)
-            h = self.grower.shard_rows(
-                np.asarray(hess, dtype=self.dtype), self.n_pad)
-            dev_tree, leaf_id = self.grower.grow(
-                self.bins_dev, g, h, bag_mask_dev,
-                self.grower.replicate(fmask))
-            dev_tree = self.grower.replicated_to_local(dev_tree)
-            leaf_id = self.grower.local_rows(leaf_id)
-        elif self.grower is not None and self._feat_mh:
-            # feature-parallel across hosts: rows replicated (every
-            # process computes identical grad/hess on its full local
-            # copy), features split; pull the replicated outputs local
-            g = self.grower.shard_rows(
-                np.asarray(grad, dtype=self.dtype), self.n_pad)
-            h = self.grower.shard_rows(
-                np.asarray(hess, dtype=self.dtype), self.n_pad)
-            dev_tree, leaf_id = self.grower.grow(
-                self.bins_dev, g, h, bag_mask_dev, fmask)
-            dev_tree = self.grower.replicated_to_local(dev_tree)
-            leaf_id = self.grower.local_replicated(leaf_id)
-        elif self.grower is not None:
-            dev_tree, leaf_id = self.grower.grow(
-                self.bins_dev, grad.astype(self.dtype),
-                hess.astype(self.dtype), bag_mask_dev, jnp.asarray(fmask))
-        else:
-            dev_tree, leaf_id = grow_tree(
-                self.bins_dev,
-                grad.astype(self.dtype), hess.astype(self.dtype),
-                bag_mask_dev, jnp.asarray(fmask), **self._grow_kw())
+        with _enqueue("general", 1):   # one grow dispatch per tree
+            if self.grower is not None and self._mh:
+                # assemble process-local grad/hess into global sharded
+                # arrays, grow SPMD across hosts, then pull the tree
+                # (replicated) and this process's leaf_id block back to
+                # local
+                g = self.grower.shard_rows(
+                    np.asarray(grad, dtype=self.dtype), self.n_pad)
+                h = self.grower.shard_rows(
+                    np.asarray(hess, dtype=self.dtype), self.n_pad)
+                dev_tree, leaf_id = self.grower.grow(
+                    self.bins_dev, g, h, bag_mask_dev,
+                    self.grower.replicate(fmask))
+                dev_tree = self.grower.replicated_to_local(dev_tree)
+                leaf_id = self.grower.local_rows(leaf_id)
+            elif self.grower is not None and self._feat_mh:
+                # feature-parallel across hosts: rows replicated (every
+                # process computes identical grad/hess on its full local
+                # copy), features split; pull the replicated outputs
+                # local
+                g = self.grower.shard_rows(
+                    np.asarray(grad, dtype=self.dtype), self.n_pad)
+                h = self.grower.shard_rows(
+                    np.asarray(hess, dtype=self.dtype), self.n_pad)
+                dev_tree, leaf_id = self.grower.grow(
+                    self.bins_dev, g, h, bag_mask_dev, fmask)
+                dev_tree = self.grower.replicated_to_local(dev_tree)
+                leaf_id = self.grower.local_replicated(leaf_id)
+            elif self.grower is not None:
+                dev_tree, leaf_id = self.grower.grow(
+                    self.bins_dev, grad.astype(self.dtype),
+                    hess.astype(self.dtype), bag_mask_dev,
+                    jnp.asarray(fmask))
+            else:
+                dev_tree, leaf_id = grow_tree(
+                    self.bins_dev,
+                    grad.astype(self.dtype), hess.astype(self.dtype),
+                    bag_mask_dev, jnp.asarray(fmask), **self._grow_kw())
 
         lr = self.shrinkage_rate
         # train-score update: leaf_value[leaf_id] gather for ALL rows —
@@ -2163,25 +2223,37 @@ class GBDT:
         # flush stacks all pending ints/floats on device (this also
         # fuses multiclass batch-row slices) and materializes them in
         # two transfers, amortized over _flush_every iterations
-        pend = [m for m in self._models
-                if isinstance(m, _PendingTree)
-                and not isinstance(m.ints, np.ndarray)]
-        if pend:
-            # _pack_tree pads every tree to the config-fixed leaf count
-            # (see _PendingTree); a future variable-size packing change
-            # must group by shape before stacking
-            assert len({m.ints.shape for m in pend}) == 1 \
-                and len({m.floats.shape for m in pend}) == 1, \
-                "pending tree buffers must share one packed shape"
-            # explicit device_get: ONE counted transfer for the whole
-            # batch (analysis/guards.py device_get accounting — bench
-            # reports it as the per-tree sync metric)
-            faultpoint("flush.device_get")
-            ints_all, floats_all = jax.device_get(
-                (jnp.stack([m.ints for m in pend]),
-                 jnp.stack([m.floats for m in pend])))
-            for m, ih, fh in zip(pend, ints_all, floats_all):
-                m.ints, m.floats = ih, fh
+        pending = [m for m in self._models if isinstance(m, _PendingTree)]
+        if not pending:      # nothing to do, and no span: `models` asks
+            return self._stopped                        # on every read
+        pend = [m for m in pending if not isinstance(m.ints, np.ndarray)]
+        with TraceAnnotation(
+                spans.FLUSH, trees=len(pending),
+                bytes=sum(m.ints.nbytes + m.floats.nbytes for m in pend)):
+            if pend:
+                # _pack_tree pads every tree to the config-fixed leaf
+                # count (see _PendingTree); a future variable-size packing
+                # change must group by shape before stacking
+                assert len({m.ints.shape for m in pend}) == 1 \
+                    and len({m.floats.shape for m in pend}) == 1, \
+                    "pending tree buffers must share one packed shape"
+                # explicit device_get: ONE counted transfer for the whole
+                # batch (analysis/guards.py device_get accounting — bench
+                # reports it as the per-tree sync metric)
+                faultpoint("flush.device_get")
+                with TraceAnnotation(spans.FLUSH_PULL):
+                    ints_all, floats_all = jax.device_get(
+                        (jnp.stack([m.ints for m in pend]),
+                         jnp.stack([m.floats for m in pend])))
+                for m, ih, fh in zip(pend, ints_all, floats_all):
+                    m.ints, m.floats = ih, fh
+            with TraceAnnotation(spans.FLUSH_UNPACK):
+                self._unpack_pending()
+        return self._stopped
+
+    def _unpack_pending(self) -> None:
+        """_flush_pending's host half: pulled buffers -> host Trees,
+        truncated at the first stump."""
         stop_at = None
         gated_flags = {}
         for idx, m in enumerate(self._models):
@@ -2204,7 +2276,6 @@ class GBDT:
             self._stopped = True
             self.num_used_model = len(self._models) // self.num_class
             self.iter = self.num_used_model
-        return self._stopped
 
     def _subtract_tree_scores(self, tree: Tree, cls: int) -> None:
         """Remove a discarded tree's leaf values from train/valid scores
@@ -2460,23 +2531,24 @@ class GBDT:
         return stop
 
     def eval_and_check_early_stopping(self) -> bool:
-        # Flush BEFORE evaluating: if a pending 1-leaf stump stopped
-        # training, that stop wins — evaluating or popping trees off the
-        # truncated model would corrupt it (the reference never reaches
-        # its early-stopping path after the stump stop, gbdt.cpp:186).
-        if self._sync_stop(self._flush_pending()):
-            log.info("Stopped training because there are no more leafs "
-                     "that meet the split requirements.")
-            return True
-        stop = self._sync_stop(self.output_metric(self.iter))
-        if stop:
-            log.info("Early stopping at iteration %d, the best iteration "
-                     "round is %d" % (self.iter,
-                                      self.iter - self.early_stopping_round))
-            for _ in range(self.early_stopping_round * self.num_class):
-                self.models.pop()
-            self.num_used_model = len(self.models) // self.num_class
-        return stop
+        with TraceAnnotation(spans.EVAL, iter=self.iter):
+            # Flush BEFORE evaluating: if a pending 1-leaf stump stopped
+            # training, that stop wins — evaluating or popping trees off the
+            # truncated model would corrupt it (the reference never reaches
+            # its early-stopping path after the stump stop, gbdt.cpp:186).
+            if self._sync_stop(self._flush_pending()):
+                log.info("Stopped training because there are no more leafs "
+                         "that meet the split requirements.")
+                return True
+            stop = self._sync_stop(self.output_metric(self.iter))
+            if stop:
+                log.info("Early stopping at iteration %d, the best iteration "
+                         "round is %d"
+                         % (self.iter, self.iter - self.early_stopping_round))
+                for _ in range(self.early_stopping_round * self.num_class):
+                    self.models.pop()
+                self.num_used_model = len(self.models) // self.num_class
+            return stop
 
     def output_metric(self, it: int) -> bool:
         """GBDT::OutputMetric (gbdt.cpp:231-267)."""
@@ -3160,13 +3232,12 @@ class DART(GBDT):
         self._dropping_trees()
         return super()._score_for_gradients()
 
-    def train_one_iter(self, gradients=None, hessians=None,
-                       is_eval: bool = True) -> bool:
+    def _one_iter(self, gradients, hessians, is_eval: bool) -> bool:
         if (gradients is None and self._can_fuse_dart()
                 and (self._bank is not None or not self._models)):
             return self._train_one_iter_banked(is_eval)
         self._exit_bank_mode()
-        stopped = super().train_one_iter(gradients, hessians, False)
+        stopped = super()._one_iter(gradients, hessians, False)
         self._normalize()
         if stopped:
             return True
@@ -3257,24 +3328,25 @@ class DART(GBDT):
         # host/mt19937 state, so a K-segment precomputes them all and
         # feeds them as stacked [K, ...] scan inputs
         drops, rates, kfs, fmasks = [], [], [], []
-        for j in range(k_iters):
-            it = self.iter + j
-            self._draw_drops(it)
-            kd = len(self.drop_index)
-            # record this cycle's f64 factor pair against every dropped
-            # row (replayed at materialization; entries from iterations
-            # past a stump stop are filtered out there, matching the
-            # device gating)
-            for i in self.drop_index:
-                self._bank_hist.setdefault(i, []).append(
-                    (it, self.shrinkage_rate, float(kd)))
-            drops.append(list(self.drop_index))
-            rates.append(self.shrinkage_rate)
-            kfs.append(float(kd))
-            self._bagging(it, 0)
-            if j == 0:
-                self._ensure_bag_arranged()
-            fmasks.append(self._feature_mask(0))
+        with TraceAnnotation(spans.HOST_INPUTS):
+            for j in range(k_iters):
+                it = self.iter + j
+                self._draw_drops(it)
+                kd = len(self.drop_index)
+                # record this cycle's f64 factor pair against every dropped
+                # row (replayed at materialization; entries from iterations
+                # past a stump stop are filtered out there, matching the
+                # device gating)
+                for i in self.drop_index:
+                    self._bank_hist.setdefault(i, []).append(
+                        (it, self.shrinkage_rate, float(kd)))
+                drops.append(list(self.drop_index))
+                rates.append(self.shrinkage_rate)
+                kfs.append(float(kd))
+                self._bagging(it, 0)
+                if j == 0:
+                    self._ensure_bag_arranged()
+                fmasks.append(self._feature_mask(0))
         compact = self._bag_compact_rows() if self._bag_arranged else 0
         # fixed cap -> ONE executable for every drop count <= 8 (padded
         # slots are lax.cond-skipped); pow2 buckets beyond are the rare
@@ -3301,30 +3373,33 @@ class DART(GBDT):
                                          k_iters)
 
         fn = _get_fused_step(key, make)
-        _note_dispatch()
-        if k_iters == 1:
-            dev_in = (jnp.asarray(drop_idx[0]), jnp.asarray(drop_mask[0]),
-                      jnp.asarray(rates[0], dtype=self.dtype),
-                      jnp.asarray(kfs[0], dtype=self.dtype))
-            t_row = jnp.int32(self._bank_count)
-        else:
-            dev_in = (jnp.asarray(drop_idx), jnp.asarray(drop_mask),
-                      jnp.asarray(np.asarray(rates, dtype=np.float64)
-                                  .astype(self.dtype)),
-                      jnp.asarray(np.asarray(kfs, dtype=np.float64)
-                                  .astype(self.dtype)))
-            t_row = jnp.arange(self._bank_count,
-                               self._bank_count + k_iters,
-                               dtype=jnp.int32)
-        (self.scores, valid, bi, bf, lb, vbs, ints, floats,
-         self._dev_stopped) = fn(
-            self.scores, list(self.valid_scores), self._bank[0],
-            self._bank[1], self._bank[2], list(self._bank[3]),
-            dev_in[0], dev_in[1], dev_in[2], dev_in[3],
-            self._bag_mask_dev_fused(0),
-            jnp.asarray(fmasks[0] if k_iters == 1 else np.stack(fmasks)),
-            self.bins_dev, tuple(self.valid_bins_dev),
-            self._gstate_for_fused(), self._dev_stopped, t_row)
+        with TraceAnnotation(spans.HOST_INPUTS):
+            if k_iters == 1:
+                dev_in = (jnp.asarray(drop_idx[0]),
+                          jnp.asarray(drop_mask[0]),
+                          jnp.asarray(rates[0], dtype=self.dtype),
+                          jnp.asarray(kfs[0], dtype=self.dtype))
+                t_row = jnp.int32(self._bank_count)
+            else:
+                dev_in = (jnp.asarray(drop_idx), jnp.asarray(drop_mask),
+                          jnp.asarray(np.asarray(rates, dtype=np.float64)
+                                      .astype(self.dtype)),
+                          jnp.asarray(np.asarray(kfs, dtype=np.float64)
+                                      .astype(self.dtype)))
+                t_row = jnp.arange(self._bank_count,
+                                   self._bank_count + k_iters,
+                                   dtype=jnp.int32)
+            args = (self.scores, list(self.valid_scores), self._bank[0],
+                    self._bank[1], self._bank[2], list(self._bank[3]),
+                    dev_in[0], dev_in[1], dev_in[2], dev_in[3],
+                    self._bag_mask_dev_fused(0),
+                    jnp.asarray(fmasks[0] if k_iters == 1
+                                else np.stack(fmasks)),
+                    self.bins_dev, tuple(self.valid_bins_dev),
+                    self._gstate_for_fused(), self._dev_stopped, t_row)
+        with _enqueue("dart", k_iters):
+            (self.scores, valid, bi, bf, lb, vbs, ints, floats,
+             self._dev_stopped) = fn(*args)
         self._bank = [bi, bf, lb, list(vbs)]
         self.valid_scores = list(valid)
         # raw floats + each iteration's 1/(1+k) shrinkage applied on the
@@ -3350,19 +3425,21 @@ class DART(GBDT):
         if self._bank is None or not self._bank_dirty:
             return
         stop_iter = self.iter if self._stopped else float("inf")
-        for idx, tree in enumerate(self._models):
-            lv0 = self._bank_lv0.get(idx)
-            if lv0 is None:
-                lv0 = np.asarray(tree.leaf_value, dtype=np.float64).copy()
-                self._bank_lv0[idx] = lv0
-            v = lv0.copy()
-            for it, rate, k in self._bank_hist.get(idx, ()):
-                if it > stop_iter:
-                    break
-                v *= -1.0
-                v *= rate
-                v *= -k
-            tree.leaf_value = v
+        with TraceAnnotation(spans.FLUSH_UNPACK):
+            for idx, tree in enumerate(self._models):
+                lv0 = self._bank_lv0.get(idx)
+                if lv0 is None:
+                    lv0 = np.asarray(tree.leaf_value,
+                                     dtype=np.float64).copy()
+                    self._bank_lv0[idx] = lv0
+                v = lv0.copy()
+                for it, rate, k in self._bank_hist.get(idx, ()):
+                    if it > stop_iter:
+                        break
+                    v *= -1.0
+                    v *= rate
+                    v *= -k
+                tree.leaf_value = v
         self._bank_dirty = False
 
     def _flush_pending(self) -> bool:

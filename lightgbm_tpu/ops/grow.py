@@ -25,6 +25,11 @@ reference's ReduceScatter of histogram buffers,
 src/treelearner/data_parallel_tree_learner.cpp:124-154), after which every
 shard computes the identical split — the same invariant the reference
 relies on (global counts, data_parallel_tree_learner.cpp:226-232).
+
+The phases carry `jax.named_scope`s from utils/spans.py (root sweep,
+block list, sweep, pool, exchange, gain scan, partition, tree update):
+HLO metadata, so a profiler trace can be split by phase
+(benchmark/phase_table.py) at no cost to the compiled step.
 """
 
 from __future__ import annotations
@@ -36,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.contracts import contract
+from ..utils import spans
 from .histogram import leaf_histogram, make_gvals
 from .predict import predict_leaf_binned
 from .split import (BestSplit, SplitParams, find_best_split,
@@ -130,8 +136,9 @@ def _reduce_best_over_features(s: BestSplit, f_offset, feature_axis: str
     feature index, so every shard picks the identical winner.
     """
     glob = s._replace(feature=s.feature + f_offset)
-    gathered = jax.tree_util.tree_map(
-        lambda a: jax.lax.all_gather(a, feature_axis), glob)
+    with jax.named_scope(spans.HIST_EXCHANGE):
+        gathered = jax.tree_util.tree_map(
+            lambda a: jax.lax.all_gather(a, feature_axis), glob)
     mx = jnp.max(gathered.gain)
     eligible = gathered.gain == mx
     win = jnp.argmin(jnp.where(eligible, gathered.feature,
@@ -222,15 +229,20 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         f_offset = (jax.lax.axis_index(feature_axis) * f).astype(jnp.int32)
 
     def psum(x):
-        return jax.lax.psum(x, psum_axis) if psum_axis else x
+        if not psum_axis:
+            return x
+        with jax.named_scope(spans.HIST_EXCHANGE):
+            return jax.lax.psum(x, psum_axis)
 
+    @jax.named_scope(spans.GAIN_SCAN)
     def best_of(hist, cnt, sg, sh):
         """find_best_split + cross-shard reduction.  In voting/scatter mode
         `hist` is shard-LOCAL; cnt/sg/sh are always global leaf stats."""
         if scatter:
-            histp = jnp.pad(hist, ((0, f_pad - f), (0, 0), (0, 0)))
-            mine = jax.lax.psum_scatter(histp, psum_axis,
-                                        scatter_dimension=0, tiled=True)
+            with jax.named_scope(spans.HIST_EXCHANGE):
+                histp = jnp.pad(hist, ((0, f_pad - f), (0, 0), (0, 0)))
+                mine = jax.lax.psum_scatter(histp, psum_axis,
+                                            scatter_dimension=0, tiled=True)
             fm = jax.lax.dynamic_slice_in_dim(fmask_pad, my_off, f_chunk)
             s = find_best_split(mine, cnt, sg, sh, fm, params)
             return _reduce_best_over_features(s, my_off, psum_axis)
@@ -245,13 +257,13 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
             topv, topi = jax.lax.top_k(gains_f, k)
             votes = jnp.zeros(f, dtype=jnp.float32).at[topi].add(
                 jnp.where(topv > K_MIN_SCORE, 1.0, 0.0))
-            votes = jax.lax.psum(votes, psum_axis)
+            votes = psum(votes)
             # global top-2k by votes, ties to the smaller feature index
             # (unique integer-valued keys keep top_k deterministic)
             k2 = min(2 * voting_top_k, f)
             key = votes * (f + 1) - jnp.arange(f, dtype=jnp.float32)
             cand = jax.lax.top_k(key, k2)[1].astype(jnp.int32)
-            cand_hist = jax.lax.psum(hist[cand], psum_axis)
+            cand_hist = psum(hist[cand])
             s = find_best_split(cand_hist, cnt, sg, sh,
                                 feature_mask[cand], params)
             return s._replace(feature=cand[s.feature])
@@ -286,7 +298,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))
         packed = jnp.sum(bits * weights[None, :], axis=1,
                          dtype=jnp.int32).astype(jnp.uint8)
-        packed = jax.lax.psum(packed, feature_axis)
+        with jax.named_scope(spans.HIST_EXCHANGE):
+            packed = jax.lax.psum(packed, feature_axis)
         unpacked = (packed[:, None] >> jnp.arange(8, dtype=jnp.uint8)) \
             & jnp.uint8(1)
         return unpacked.reshape(-1)[:n].astype(bool)
@@ -314,7 +327,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                                   leaf_histogram_masked,
                                   leaf_histogram_masked_fused,
                                   make_gh2_acc)
-        gh2, inv_scale = make_gh2_acc(grad, hess, hist_acc)
+        with jax.named_scope(spans.HIST_SWEEP):
+            gh2, inv_scale = make_gh2_acc(grad, hess, hist_acc)
         # TPU runs the compiled kernel; CPU (tests) uses interpret mode
         interpret = jax.default_backend() == "cpu"
     if ranged_on:
@@ -355,9 +369,10 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 sel = jnp.where(n_sel <= ladder[i], jnp.int32(i), sel)
             return blist, n_occ, sel
 
-        def hist_leaf(leaf_id, target):
-            leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-            blist, n_occ, sel = _block_plan(leaf_eff, target)
+        def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
+            with jax.named_scope(spans.BLOCK_LIST):
+                leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
+                blist, n_occ, sel = _block_plan(leaf_eff, target)
 
             def mk(g):
                 def branch(le, bl, na):
@@ -367,13 +382,16 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                         grid_blocks=g, interpret=interpret).astype(dtype)
                 return branch
 
-            return hist_psum(jax.lax.switch(sel, [mk(g) for g in ladder],
-                                            leaf_eff, blist, n_occ))
+            with jax.named_scope(scope):
+                h = jax.lax.switch(sel, [mk(g) for g in ladder],
+                                   leaf_eff, blist, n_occ)
+            return hist_psum(h)
 
         if fused_on:
             def hist_best(leaf_id, target, parent_hist, s_stats, l_stats):
-                leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-                blist, n_occ, sel = _block_plan(leaf_eff, target)
+                with jax.named_scope(spans.BLOCK_LIST):
+                    leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
+                    blist, n_occ, sel = _block_plan(leaf_eff, target)
 
                 def mk(g):
                     def branch(le, bl, na):
@@ -389,12 +407,14 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 return jax.lax.switch(sel, [mk(g) for g in ladder],
                                       leaf_eff, blist, n_occ)
     elif hist_impl == "pallas":
-        def hist_leaf(leaf_id, target):
-            leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-            return hist_psum(leaf_histogram_masked(
-                bins_t, gh2, leaf_eff, target, max_bin=max_bin,
-                hist_acc=hist_acc, inv_scale=inv_scale,
-                interpret=interpret).astype(dtype))
+        def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
+            with jax.named_scope(scope):
+                leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
+                h = leaf_histogram_masked(
+                    bins_t, gh2, leaf_eff, target, max_bin=max_bin,
+                    hist_acc=hist_acc, inv_scale=inv_scale,
+                    interpret=interpret).astype(dtype)
+            return hist_psum(h)
 
         if fused_on:
             def hist_best(leaf_id, target, parent_hist, s_stats, l_stats):
@@ -406,10 +426,13 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                     interpret=interpret)
                 return h.astype(dtype), pfs, pfl
     else:
-        def hist_leaf(leaf_id, target):
-            gv = make_gvals(grad, hess, (leaf_id == target) & bag_mask, dtype)
-            return hist_psum(leaf_histogram(bins_t, gv, max_bin=max_bin,
-                                            row_chunk=row_chunk))
+        def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
+            with jax.named_scope(scope):
+                gv = make_gvals(grad, hess, (leaf_id == target) & bag_mask,
+                                dtype)
+                h = leaf_histogram(bins_t, gv, max_bin=max_bin,
+                                   row_chunk=row_chunk)
+            return hist_psum(h)
 
     # -- compacted small-leaf histograms (serial fast path) ------------
     # Full-row sweeps dominate the fused iteration (~90% in the r05-era
@@ -480,33 +503,37 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         def hist_small(leaf_id, target, cnt):
             return hist_leaf(leaf_id, target)
 
-    def depth_gated(gain, depth):
+    def packed_best(best, depth):
+        """The depth gate (no split at max_depth) and the packing."""
         if max_depth > 0:
-            return jnp.where(depth >= max_depth, K_MIN_SCORE, gain)
-        return gain
+            best = best._replace(
+                gain=jnp.where(depth >= max_depth, K_MIN_SCORE, best.gain))
+        return _pack_best(best, dtype)
 
     # ---- root ----
-    root_hist = hist_leaf(jnp.zeros(n, dtype=jnp.int32), jnp.int32(0))
+    root_hist = hist_leaf(jnp.zeros(n, dtype=jnp.int32), jnp.int32(0),
+                          scope=spans.HIST_ROOT)
     # every row lands in exactly one bin of feature 0, so its histogram sums
     # are the root totals (LeafSplits::Init root sumup, leaf_splits.hpp:36-117);
     # in voting mode the hist is local, so all-reduce the three scalars
     # (the reference's root Allreduce, data_parallel_tree_learner.cpp:94-122)
-    root_g = jnp.sum(root_hist[0, :, 0])
-    root_h = jnp.sum(root_hist[0, :, 1])
-    root_c = jnp.sum(root_hist[0, :, 2])
-    if voting or scatter:
-        root_g, root_h, root_c = (psum(root_g), psum(root_h), psum(root_c))
-    root_cnt = jnp.round(root_c).astype(jnp.int32)
+    with jax.named_scope(spans.HIST_ROOT):
+        root_g = jnp.sum(root_hist[0, :, 0])
+        root_h = jnp.sum(root_hist[0, :, 1])
+        root_c = jnp.sum(root_hist[0, :, 2])
+        if voting or scatter:
+            root_g, root_h, root_c = (psum(root_g), psum(root_h),
+                                      psum(root_c))
+        root_cnt = jnp.round(root_c).astype(jnp.int32)
 
     tree = _empty_tree(max_leaves, dtype)
     tree = tree._replace(leaf_count=tree.leaf_count.at[0].set(root_cnt))
     best_f0, best_i0 = _empty_best_packed(max_leaves, dtype)
-    root_best = best_of(root_hist, root_cnt, root_g, root_h)
-    root_best = root_best._replace(
-        gain=depth_gated(root_best.gain, jnp.int32(1)))
-    rbf, rbi = _pack_best(root_best, dtype)
-    best_f0 = best_f0.at[0].set(rbf)
-    best_i0 = best_i0.at[0].set(rbi)
+    with jax.named_scope(spans.GAIN_SCAN):
+        rbf, rbi = packed_best(best_of(root_hist, root_cnt, root_g, root_h),
+                               jnp.int32(1))
+        best_f0 = best_f0.at[0].set(rbf)
+        best_i0 = best_i0.at[0].set(rbi)
 
     pooled = 0 < hist_slots < max_leaves + 1
     K = hist_slots if pooled else max_leaves
@@ -517,11 +544,13 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
     else:   # zero-size placeholders keep the scan-state pytree uniform
         leaf_slot0 = slot_leaf0 = slot_used0 = jnp.zeros(0, dtype=jnp.int32)
 
+    with jax.named_scope(spans.HIST_POOL):
+        hist0 = jnp.zeros((K + 1, f, max_bin, 3), dtype=dtype) \
+                   .at[0].set(root_hist)
     state = GrowState(
         tree=tree,
         leaf_id=jnp.zeros(n, dtype=jnp.int32),
-        hist=jnp.zeros((K + 1, f, max_bin, 3), dtype=dtype)
-            .at[0].set(root_hist),
+        hist=hist0,
         leaf_sum_g=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_g),
         leaf_sum_h=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_h),
         best_f=best_f0, best_i=best_i0,
@@ -540,156 +569,167 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
     # (serial_tree_learner.cpp:121-129) without a whole-state select.
     def step(st: GrowState, t):
         tree = st.tree
-        # argmax over leaves; first max ⇒ smaller leaf index, matching
-        # ArrayArgs::ArgMax over best_split_per_leaf_ (serial_tree_learner.cpp:121)
-        bl = jnp.argmax(st.best_f[:max_leaves, BF_GAIN]).astype(jnp.int32)
-        sf = st.best_f[bl]
-        si = st.best_i[bl]
-        s_gain = sf[BF_GAIN]
-        s_feature = si[BI_FEAT]
-        s_threshold = si[BI_THR]
-        keep = (tree.num_leaves < max_leaves) & (s_gain > 0.0)
+        with jax.named_scope(spans.TREE_UPDATE):
+            # argmax over leaves; first max ⇒ smaller leaf index, matching
+            # ArrayArgs::ArgMax over best_split_per_leaf_
+            # (serial_tree_learner.cpp:121)
+            bl = jnp.argmax(
+                st.best_f[:max_leaves, BF_GAIN]).astype(jnp.int32)
+            sf = st.best_f[bl]
+            si = st.best_i[bl]
+            s_gain = sf[BF_GAIN]
+            s_feature = si[BI_FEAT]
+            s_threshold = si[BI_THR]
+            keep = (tree.num_leaves < max_leaves) & (s_gain > 0.0)
 
-        node = tree.num_leaves - 1
-        right = tree.num_leaves           # new leaf index
-        # dummy-slot redirection: all writes of an inactive step land in
-        # scratch entries that the output never reads
-        wl = jnp.where(keep, bl, max_leaves)          # leaf-array writes
-        wr = jnp.where(keep, right, max_leaves)
-        wn = jnp.where(keep, node, max_leaves - 1)    # node-array writes
-        parent = tree.leaf_parent[bl]
+            node = tree.num_leaves - 1
+            right = tree.num_leaves           # new leaf index
+            # dummy-slot redirection: all writes of an inactive step land in
+            # scratch entries that the output never reads
+            wl = jnp.where(keep, bl, max_leaves)          # leaf-array writes
+            wr = jnp.where(keep, right, max_leaves)
+            wn = jnp.where(keep, node, max_leaves - 1)    # node-array writes
+            parent = tree.leaf_parent[bl]
 
-        # --- Tree::Split (reference src/io/tree.cpp:42-77) ---
-        pidx = jnp.where(keep & (parent >= 0), parent, max_leaves - 1)
-        lc = tree.left_child
-        lc = lc.at[pidx].set(jnp.where(keep & (parent >= 0)
-                                       & (lc[pidx] == ~bl), node, lc[pidx]))
-        rc = tree.right_child
-        rc = rc.at[pidx].set(jnp.where(keep & (parent >= 0)
-                                       & (rc[pidx] == ~bl), node, rc[pidx]))
-        lc = lc.at[wn].set(jnp.where(keep, ~bl, lc[wn]))
-        rc = rc.at[wn].set(jnp.where(keep, ~right, rc[wn]))
+            # --- Tree::Split (reference src/io/tree.cpp:42-77) ---
+            pidx = jnp.where(keep & (parent >= 0), parent, max_leaves - 1)
+            lc = tree.left_child
+            lc = lc.at[pidx].set(jnp.where(
+                keep & (parent >= 0) & (lc[pidx] == ~bl), node, lc[pidx]))
+            rc = tree.right_child
+            rc = rc.at[pidx].set(jnp.where(
+                keep & (parent >= 0) & (rc[pidx] == ~bl), node, rc[pidx]))
+            lc = lc.at[wn].set(jnp.where(keep, ~bl, lc[wn]))
+            rc = rc.at[wn].set(jnp.where(keep, ~right, rc[wn]))
 
-        new_tree = TreeArrays(
-            split_feature=tree.split_feature.at[wn].set(
-                jnp.where(keep, s_feature, tree.split_feature[wn])),
-            threshold_bin=tree.threshold_bin.at[wn].set(
-                jnp.where(keep, s_threshold, tree.threshold_bin[wn])),
-            split_gain=tree.split_gain.at[wn].set(
-                jnp.where(keep, s_gain, tree.split_gain[wn])),
-            left_child=lc, right_child=rc,
-            leaf_parent=tree.leaf_parent.at[wl].set(node).at[wr].set(node),
-            leaf_value=tree.leaf_value.at[wl].set(sf[BF_LOUT])
-                                      .at[wr].set(sf[BF_ROUT]),
-            internal_value=tree.internal_value.at[wn].set(
-                jnp.where(keep, tree.leaf_value[bl],
-                          tree.internal_value[wn])),
-            leaf_depth=tree.leaf_depth
-                .at[wr].set(tree.leaf_depth[bl] + 1)
-                .at[wl].add(1),
-            leaf_count=tree.leaf_count.at[wl].set(si[BI_LCNT])
-                                      .at[wr].set(si[BI_RCNT]),
-            num_leaves=tree.num_leaves + keep.astype(jnp.int32),
-        )
+            new_tree = TreeArrays(
+                split_feature=tree.split_feature.at[wn].set(
+                    jnp.where(keep, s_feature, tree.split_feature[wn])),
+                threshold_bin=tree.threshold_bin.at[wn].set(
+                    jnp.where(keep, s_threshold, tree.threshold_bin[wn])),
+                split_gain=tree.split_gain.at[wn].set(
+                    jnp.where(keep, s_gain, tree.split_gain[wn])),
+                left_child=lc, right_child=rc,
+                leaf_parent=tree.leaf_parent.at[wl].set(node)
+                                            .at[wr].set(node),
+                leaf_value=tree.leaf_value.at[wl].set(sf[BF_LOUT])
+                                          .at[wr].set(sf[BF_ROUT]),
+                internal_value=tree.internal_value.at[wn].set(
+                    jnp.where(keep, tree.leaf_value[bl],
+                              tree.internal_value[wn])),
+                leaf_depth=tree.leaf_depth
+                    .at[wr].set(tree.leaf_depth[bl] + 1)
+                    .at[wl].add(1),
+                leaf_count=tree.leaf_count.at[wl].set(si[BI_LCNT])
+                                          .at[wr].set(si[BI_RCNT]),
+                num_leaves=tree.num_leaves + keep.astype(jnp.int32),
+            )
 
         # --- partition: one vectorized compare (replaces DataPartition::Split,
         # src/treelearner/data_partition.hpp:84-132) ---
-        go_right = (keep & (st.leaf_id == bl)
-                    & feature_go_right(s_feature, s_threshold))
-        leaf_id = jnp.where(go_right, right, st.leaf_id)
+        with jax.named_scope(spans.PARTITION):
+            go_right = (keep & (st.leaf_id == bl)
+                        & feature_go_right(s_feature, s_threshold))
+            leaf_id = jnp.where(go_right, right, st.leaf_id)
 
         # --- histograms: smaller child scanned, larger by subtraction ---
-        left_is_smaller = si[BI_LCNT] <= si[BI_RCNT]
-        small_leaf = jnp.where(left_is_smaller, bl, right)
-        small_cnt = jnp.where(left_is_smaller, si[BI_LCNT], si[BI_RCNT])
-        if pooled:
-            # parent histogram from its pool slot, or a full recompute
-            # when it was LRU-evicted (the reference recomputes evicted
-            # leaves the same way, feature_histogram.hpp:275-398 +
-            # serial_tree_learner.cpp BeforeFindBestSplit)
-            parent_slot = st.leaf_slot[bl]
-            parent_hist = jax.lax.cond(
-                parent_slot >= 0,
-                lambda: st.hist[jnp.clip(parent_slot, 0, K - 1)],
-                lambda: hist_leaf(st.leaf_id, bl))
-        else:
-            parent_hist = st.hist[bl]
-        if fused_on:
-            # fused sweep + in-register gain scan: the kernel consumes
-            # the parent block, sweeps the small child, and emits both
-            # children's per-feature best rows alongside the histogram
-            s_g = jnp.where(left_is_smaller, sf[BF_LG], sf[BF_RG])
-            s_h = jnp.where(left_is_smaller, sf[BF_LH], sf[BF_RH])
-            l_g = jnp.where(left_is_smaller, sf[BF_RG], sf[BF_LG])
-            l_h = jnp.where(left_is_smaller, sf[BF_RH], sf[BF_LH])
-            large_cnt = jnp.where(left_is_smaller, si[BI_RCNT],
-                                  si[BI_LCNT])
-            small_hist, pf_small, pf_large = hist_best(
-                leaf_id, small_leaf, parent_hist,
-                (small_cnt, s_g, s_h), (large_cnt, l_g, l_h))
-        else:
-            small_hist = hist_small(leaf_id, small_leaf, small_cnt)
-        large_hist = parent_hist - small_hist
-        left_hist = jnp.where(left_is_smaller, small_hist, large_hist)
-        right_hist = jnp.where(left_is_smaller, large_hist, small_hist)
-        if pooled:
-            # slot allocation: the left child (which keeps leaf index bl)
-            # reuses the parent's slot when cached, else takes the LRU
-            # slot; the right child takes the LRU slot among the rest
-            slot_l = jnp.where(
-                parent_slot >= 0, parent_slot,
-                jnp.argmin(st.slot_used[:K]).astype(jnp.int32))
-            used_tmp = st.slot_used.at[jnp.clip(slot_l, 0, K - 1)].set(t)
-            slot_r = jnp.argmin(used_tmp[:K]).astype(jnp.int32)
-            wsl = jnp.where(keep, slot_l, K)      # dummy-slot redirection
-            wsr = jnp.where(keep, slot_r, K)
-            hist = st.hist.at[wsl].set(left_hist).at[wsr].set(right_hist)
-            # drop the evicted occupants' mappings, then map the children
-            # (ordering matters: when the parent's slot is reused its
-            # occupant IS bl — cleared first, remapped after)
-            evict_l = st.slot_leaf[jnp.clip(slot_l, 0, K - 1)]
-            evict_r = st.slot_leaf[jnp.clip(slot_r, 0, K - 1)]
-            leaf_slot = (
-                st.leaf_slot
-                .at[jnp.where(keep & (evict_l >= 0), evict_l,
-                              max_leaves)].set(-1)
-                .at[jnp.where(keep & (evict_r >= 0), evict_r,
-                              max_leaves)].set(-1)
-                .at[wl].set(jnp.where(keep, slot_l, -1))
-                .at[wr].set(jnp.where(keep, slot_r, -1)))
-            slot_leaf = st.slot_leaf.at[wsl].set(bl).at[wsr].set(right)
-            slot_used = st.slot_used.at[wsl].set(t).at[wsr].set(t)
-        else:
-            hist = st.hist.at[wl].set(left_hist).at[wr].set(right_hist)
-            leaf_slot, slot_leaf, slot_used = (st.leaf_slot, st.slot_leaf,
-                                               st.slot_used)
+        with jax.named_scope(spans.HIST_POOL):
+            left_is_smaller = si[BI_LCNT] <= si[BI_RCNT]
+            small_leaf = jnp.where(left_is_smaller, bl, right)
+            small_cnt = jnp.where(left_is_smaller, si[BI_LCNT],
+                                  si[BI_RCNT])
+            if pooled:
+                # parent histogram from its pool slot, or a full recompute
+                # when it was LRU-evicted (the reference recomputes evicted
+                # leaves the same way, feature_histogram.hpp:275-398 +
+                # serial_tree_learner.cpp BeforeFindBestSplit)
+                parent_slot = st.leaf_slot[bl]
+                parent_hist = jax.lax.cond(
+                    parent_slot >= 0,
+                    lambda: st.hist[jnp.clip(parent_slot, 0, K - 1)],
+                    lambda: hist_leaf(st.leaf_id, bl))
+            else:
+                parent_hist = st.hist[bl]
+        with jax.named_scope(spans.HIST_SWEEP):
+            if fused_on:
+                # fused sweep + in-register gain scan: the kernel consumes
+                # the parent block, sweeps the small child, and emits both
+                # children's per-feature best rows alongside the histogram
+                s_g = jnp.where(left_is_smaller, sf[BF_LG], sf[BF_RG])
+                s_h = jnp.where(left_is_smaller, sf[BF_LH], sf[BF_RH])
+                l_g = jnp.where(left_is_smaller, sf[BF_RG], sf[BF_LG])
+                l_h = jnp.where(left_is_smaller, sf[BF_RH], sf[BF_LH])
+                large_cnt = jnp.where(left_is_smaller, si[BI_RCNT],
+                                      si[BI_LCNT])
+                small_hist, pf_small, pf_large = hist_best(
+                    leaf_id, small_leaf, parent_hist,
+                    (small_cnt, s_g, s_h), (large_cnt, l_g, l_h))
+            else:
+                small_hist = hist_small(leaf_id, small_leaf, small_cnt)
+        with jax.named_scope(spans.HIST_POOL):
+            large_hist = parent_hist - small_hist
+            left_hist = jnp.where(left_is_smaller, small_hist, large_hist)
+            right_hist = jnp.where(left_is_smaller, large_hist, small_hist)
+            if pooled:
+                # slot allocation: the left child (which keeps leaf index bl)
+                # reuses the parent's slot when cached, else takes the LRU
+                # slot; the right child takes the LRU slot among the rest
+                slot_l = jnp.where(
+                    parent_slot >= 0, parent_slot,
+                    jnp.argmin(st.slot_used[:K]).astype(jnp.int32))
+                used_tmp = st.slot_used.at[
+                    jnp.clip(slot_l, 0, K - 1)].set(t)
+                slot_r = jnp.argmin(used_tmp[:K]).astype(jnp.int32)
+                wsl = jnp.where(keep, slot_l, K)      # dummy-slot redirection
+                wsr = jnp.where(keep, slot_r, K)
+                hist = st.hist.at[wsl].set(left_hist) \
+                              .at[wsr].set(right_hist)
+                # drop the evicted occupants' mappings, then map the children
+                # (ordering matters: when the parent's slot is reused its
+                # occupant IS bl — cleared first, remapped after)
+                evict_l = st.slot_leaf[jnp.clip(slot_l, 0, K - 1)]
+                evict_r = st.slot_leaf[jnp.clip(slot_r, 0, K - 1)]
+                leaf_slot = (
+                    st.leaf_slot
+                    .at[jnp.where(keep & (evict_l >= 0), evict_l,
+                                  max_leaves)].set(-1)
+                    .at[jnp.where(keep & (evict_r >= 0), evict_r,
+                                  max_leaves)].set(-1)
+                    .at[wl].set(jnp.where(keep, slot_l, -1))
+                    .at[wr].set(jnp.where(keep, slot_r, -1)))
+                slot_leaf = st.slot_leaf.at[wsl].set(bl).at[wsr].set(right)
+                slot_used = st.slot_used.at[wsl].set(t).at[wsr].set(t)
+            else:
+                hist = st.hist.at[wl].set(left_hist).at[wr].set(right_hist)
+                leaf_slot, slot_leaf, slot_used = (
+                    st.leaf_slot, st.slot_leaf, st.slot_used)
 
-        leaf_sum_g = st.leaf_sum_g.at[wl].set(sf[BF_LG]) \
-                                  .at[wr].set(sf[BF_RG])
-        leaf_sum_h = st.leaf_sum_h.at[wl].set(sf[BF_LH]) \
-                                  .at[wr].set(sf[BF_RH])
+            leaf_sum_g = st.leaf_sum_g.at[wl].set(sf[BF_LG]) \
+                                      .at[wr].set(sf[BF_RG])
+            leaf_sum_h = st.leaf_sum_h.at[wl].set(sf[BF_LH]) \
+                                      .at[wr].set(sf[BF_RH])
 
         # --- best splits for the two children ---
-        child_depth = new_tree.leaf_depth[bl]
-        if fused_on:
-            # finish from the kernel's per-feature rows: a tiny argmax
-            # over [F, 8] instead of two full [F, B, 3] scan passes
-            lpf = jnp.where(left_is_smaller, pf_small, pf_large)
-            rpf = jnp.where(left_is_smaller, pf_large, pf_small)
-            lbest = find_best_split_fused(lpf, sf[BF_LG], sf[BF_LH],
-                                          params)
-            rbest = find_best_split_fused(rpf, sf[BF_RG], sf[BF_RH],
-                                          params)
-        else:
-            lbest = best_of(left_hist, si[BI_LCNT], sf[BF_LG], sf[BF_LH])
-            rbest = best_of(right_hist, si[BI_RCNT], sf[BF_RG],
-                            sf[BF_RH])
-        lbf, lbi = _pack_best(lbest._replace(
-            gain=depth_gated(lbest.gain, child_depth)), dtype)
-        rbf, rbi = _pack_best(rbest._replace(
-            gain=depth_gated(rbest.gain, child_depth)), dtype)
-        best_f = st.best_f.at[wl].set(lbf).at[wr].set(rbf)
-        best_i = st.best_i.at[wl].set(lbi).at[wr].set(rbi)
+        with jax.named_scope(spans.GAIN_SCAN):
+            child_depth = new_tree.leaf_depth[bl]
+            if fused_on:
+                # finish from the kernel's per-feature rows: a tiny argmax
+                # over [F, 8] instead of two full [F, B, 3] scan passes
+                lpf = jnp.where(left_is_smaller, pf_small, pf_large)
+                rpf = jnp.where(left_is_smaller, pf_large, pf_small)
+                lbest = find_best_split_fused(lpf, sf[BF_LG], sf[BF_LH],
+                                              params)
+                rbest = find_best_split_fused(rpf, sf[BF_RG], sf[BF_RH],
+                                              params)
+            else:
+                lbest = best_of(left_hist, si[BI_LCNT], sf[BF_LG],
+                                sf[BF_LH])
+                rbest = best_of(right_hist, si[BI_RCNT], sf[BF_RG],
+                                sf[BF_RH])
+            lbf, lbi = packed_best(lbest, child_depth)
+            rbf, rbi = packed_best(rbest, child_depth)
+            best_f = st.best_f.at[wl].set(lbf).at[wr].set(rbf)
+            best_i = st.best_i.at[wl].set(lbi).at[wr].set(rbi)
 
         return GrowState(tree=new_tree, leaf_id=leaf_id, hist=hist,
                          leaf_sum_g=leaf_sum_g, leaf_sum_h=leaf_sum_h,
@@ -740,12 +780,13 @@ def grow_tree_bagged(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
     tree, leaf_w = grow_tree(bins_t[:, :bag_rows], grad[:bag_rows],
                              hess[:bag_rows], bag_mask[:bag_rows],
                              feature_mask, **grow_kw)
-    oob = predict_leaf_binned(tree.split_feature, tree.threshold_bin,
-                              tree.left_child, tree.right_child,
-                              bins_t[:, bag_rows:])
-    # a 1-leaf stump's all-zero child arrays make the bounded descent
-    # return the dummy ~0 = -1; the scan's leaf_id keeps such rows at
-    # leaf 0 (whose value drives the score update), so mirror it — the
-    # two paths must agree row-for-row with the masked full sweep
-    oob = jnp.maximum(oob, 0)
-    return tree, jnp.concatenate([leaf_w, oob.astype(leaf_w.dtype)])
+    with jax.named_scope(spans.OOB_DESCENT):
+        oob = predict_leaf_binned(tree.split_feature, tree.threshold_bin,
+                                  tree.left_child, tree.right_child,
+                                  bins_t[:, bag_rows:])
+        # a 1-leaf stump's all-zero child arrays make the bounded descent
+        # return the dummy ~0 = -1; the scan's leaf_id keeps such rows at
+        # leaf 0 (whose value drives the score update), so mirror it — the
+        # two paths must agree row-for-row with the masked full sweep
+        oob = jnp.maximum(oob, 0)
+        return tree, jnp.concatenate([leaf_w, oob.astype(leaf_w.dtype)])

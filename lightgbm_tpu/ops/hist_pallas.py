@@ -97,6 +97,15 @@ PALLAS_ROW_BLOCK = 8192   # rows per grid step; N must be a multiple —
 
 HIST_ACC_MODES = ("f32", "bf16", "i32")
 
+# Every pl.pallas_call below has an explicit name= that holds
+# "leaf_histogram".  A Pallas custom call's HLO instruction, and so its
+# event in a device trace, is named after the INNERMOST component of its
+# name stack: the kernel's name= when it has one, else whatever
+# jax.named_scope or jit wrapper encloses the call.  The benchmark's sweep
+# reader sums the events named `%leaf_histogram*`, and the kernel body
+# (name included) is part of the persistent compile cache's key where
+# scopes, being metadata, are not (tests/test_spans.py; PERF.md, PR 25).
+
 # SMEM scalar layouts of the fused kernels: info (int32[8]) and
 # stats (float32[8])
 IF_TARGET, IF_START, IF_ACTIVE, IF_CNT_S, IF_CNT_L = range(5)
@@ -276,6 +285,7 @@ def leaf_histogram_masked(bins_t: jax.Array, gh2: jax.Array,
             (groups, fb // MM_FEATS, M_ROWS, N_COLS),
             _acc_dtype(hist_acc)),
         interpret=interpret,
+        name="leaf_histogram_masked",
     )(target, bins_t, gh2, leaf_eff)
     # rows are (f, c, hi), cols are (f', lo); feature f's histogram is the
     # f == f' diagonal of the 4x4 block structure
@@ -364,6 +374,7 @@ def leaf_histogram_ranged(bins_t: jax.Array, gh2: jax.Array,
             (groups, fb // MM_FEATS, M_ROWS, N_COLS),
             _acc_dtype(hist_acc)),
         interpret=interpret,
+        name="leaf_histogram_ranged",
     )(info, bins_t, gh2, leaf_eff)
     hist = _diag_hist_xla(out, fpad, hist_acc, inv_scale)
     return hist[:f, :max_bin, :]
@@ -433,6 +444,7 @@ def leaf_histogram_blocklist(bins_t: jax.Array, gh2: jax.Array,
             (groups, fb // MM_FEATS, M_ROWS, N_COLS),
             _acc_dtype(hist_acc)),
         interpret=interpret,
+        name="leaf_histogram_blocklist",
     )(info, blist, bins_t, gh2, leaf_eff)
     hist = _diag_hist_xla(out, fpad, hist_acc, inv_scale)
     return hist[:f, :max_bin, :]
@@ -656,6 +668,7 @@ def leaf_histogram_masked_fused(bins_t: jax.Array, gh2: jax.Array,
         ),
         out_shape=_fused_outs(groups, fb, fpad, hist_acc),
         interpret=interpret,
+        name="leaf_histogram_masked_fused",
     )(info, stats, bins_t, gh2, leaf_eff, parent, fmask_f)
     hist = _diag_hist_xla(out, fpad, hist_acc, inv_scale)
     return hist[:f, :max_bin, :], pfs[:f], pfl[:f]
@@ -727,6 +740,7 @@ def leaf_histogram_blocklist_fused(bins_t: jax.Array, gh2: jax.Array,
         grid_spec=grid_spec,
         out_shape=_fused_outs(groups, fb, fpad, hist_acc),
         interpret=interpret,
+        name="leaf_histogram_blocklist_fused",
     )(info, stats, blist, bins_t, gh2, leaf_eff, parent, fmask_f)
     hist = _diag_hist_xla(out, fpad, hist_acc, inv_scale)
     return hist[:f, :max_bin, :], pfs[:f], pfl[:f]
@@ -781,6 +795,7 @@ def leaf_histogram_ranged_fused(bins_t: jax.Array, gh2: jax.Array,
         grid_spec=grid_spec,
         out_shape=_fused_outs(groups, fb, fpad, hist_acc),
         interpret=interpret,
+        name="leaf_histogram_ranged_fused",
     )(info, stats, bins_t, gh2, leaf_eff, parent, fmask_f)
     hist = _diag_hist_xla(out, fpad, hist_acc, inv_scale)
     return hist[:f, :max_bin, :], pfs[:f], pfl[:f]

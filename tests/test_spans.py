@@ -1,0 +1,201 @@
+"""The names a training job shows under in a JAX profiler trace
+(lightgbm_tpu/utils/spans.py): device scopes in every fused step, host
+spans with their counts around the segment loop, named Pallas kernels,
+and the benchmark's copy of the lists.  All on the CPU: what the scopes
+read on the chip is benchmark/phase_table.py's business.
+"""
+
+import ast
+import glob
+import json
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu.models import gbdt
+from lightgbm_tpu.ops import hist_pallas
+from lightgbm_tpu.utils import spans
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PACKAGE = os.path.join(ROOT, "lightgbm_tpu")
+SCOPE_RE = re.compile(r"lgbm\.[a-z_]+")
+
+# what every fused step must name, and what each path adds
+CORE = {spans.OBJECTIVE, spans.GROW, spans.HIST_ROOT, spans.HIST_SWEEP,
+        spans.HIST_POOL, spans.GAIN_SCAN, spans.PARTITION,
+        spans.TREE_UPDATE, spans.SCORE_UPDATE, spans.PACK_TREE}
+PATHS = {
+    # name: (params, classes, with a validation set, scopes beyond CORE)
+    "plain": ({}, 1, True, {spans.VALID_UPDATE}),
+    "reorder": ({"hist_impl": "pallas", "hist_reorder_every": 2}, 1, False,
+                {spans.BLOCK_LIST, spans.RESORT}),
+    "multiclass": ({"objective": "multiclass", "num_class": 3}, 3, False,
+                   set()),
+    "dart": ({"boosting_type": "dart", "drop_rate": 0.5}, 1, False,
+             {spans.DART_BANK}),
+    "sharded": ({"tree_learner": "data", "num_shards": 4}, 1, False,
+                {spans.HIST_EXCHANGE}),
+    "bagged": ({"bagging_fraction": 0.5, "bagging_freq": 1,
+                "bag_compact": "on"}, 1, False,
+               {spans.OOB_DESCENT, spans.BAG_ARRANGE}),
+}
+
+
+def _data(classes, n=8192, f=6, seed=3):
+    rng = np.random.RandomState(seed)
+    x = rng.randn(n, f).astype(np.float32)
+    z = x[:, 0] + 0.5 * x[:, 1] * x[:, 2] + 0.3 * rng.randn(n)
+    if classes == 1:
+        return x, (z > 0).astype(np.float32)
+    return x, np.digitize(z, [-0.5, 0.5]).astype(np.float32)
+
+
+def _train(extra, classes, valid, rounds):
+    x, y = _data(classes)
+    params = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
+              "verbose": -1, "device_type": "cpu", **extra}
+    train = lgb.Dataset(x, label=y)
+    sets = [lgb.Dataset(x[:512], label=y[:512], reference=train)] * valid
+    return lgb.train(params, train, num_boost_round=rounds, valid_sets=sets)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_step_carries_its_scopes(path, monkeypatch):
+    """The lowered text of every executable the path dispatches holds
+    the scopes the path must have (a scope is HLO metadata: what is in
+    the lowering is what a device trace shows)."""
+    extra, classes, valid, more = PATHS[path]
+    texts = []
+    cached = gbdt._get_fused_step
+
+    def lowering_too(key, make):
+        fn = cached(key, make)
+
+        def call(*args):
+            texts.append(fn.lower(*args).as_text(debug_info=True))
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(gbdt, "_get_fused_step", lowering_too)
+    _train(extra, classes, valid, rounds=3)
+    found = set(SCOPE_RE.findall("\n".join(texts)))
+    assert CORE | more <= found, sorted((CORE | more) - found)
+    assert found <= set(spans.DEVICE_SCOPES), found
+
+
+def test_no_name_outside_the_registry():
+    """Every `lgbm.*` name in the package is in utils/spans.py, and no
+    site passes a string literal where a constant belongs."""
+    known = set(spans.DEVICE_SCOPES) | set(spans.HOST_SPANS)
+    assert len(known) == len(spans.DEVICE_SCOPES) + len(spans.HOST_SPANS)
+    for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),
+                          recursive=True):
+        with open(path) as fh:
+            src = fh.read()
+        strangers = set(SCOPE_RE.findall(src)) - known
+        assert not strangers, (path, strangers)
+        if not path.endswith(os.path.join("utils", "spans.py")):
+            literal = re.findall(
+                r"(?:named_scope|TraceAnnotation)\(\s*[\"']", src)
+            assert not literal, (path, literal)
+
+
+# -- host spans ------------------------------------------------------------
+ROUNDS = 12
+SEGMENTED = {"hist_impl": "pallas", "iter_batch": 4, "hist_reorder_every": 4}
+
+
+def _program_spans(trace_dir):
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name in spans.HOST_SPANS]
+
+
+@pytest.fixture(scope="module")
+def traced_and_plain(tmp_path_factory):
+    """The same segmented training job under the profiler and without."""
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0     # the spans are the host tracer's
+    d0 = gbdt.dispatch_count()
+    with jax.profiler.trace(trace_dir, profiler_options=options):
+        traced = _train(SEGMENTED, 1, 0, ROUNDS)
+    dispatches = gbdt.dispatch_count() - d0
+    plain = _train(SEGMENTED, 1, 0, ROUNDS)
+    return _program_spans(trace_dir), dispatches, traced, plain
+
+
+def test_spans_count_what_was_trained(traced_and_plain):
+    found, dispatches, booster, _ = traced_and_plain
+    by_name = {}
+    for name, stats in found:
+        by_name.setdefault(name, []).append(stats)
+    assert len(by_name[spans.SEGMENT]) >= 3
+    for name in (spans.SEGMENT, spans.ENQUEUE):
+        assert sum(s["k"] for s in by_name[name]) == ROUNDS
+    assert len(by_name[spans.ENQUEUE]) == dispatches
+    assert {s["kind"] for s in by_name[spans.ENQUEUE]} == {"scan", "resort"}
+    assert {s["kind"] for s in by_name[spans.ENQUEUE]} \
+        <= set(spans.ENQUEUE_KINDS)
+    assert sum(s["trees"] for s in by_name[spans.FLUSH]) == ROUNDS
+    assert all(s["bytes"] > 0 for s in by_name[spans.FLUSH])
+    assert len(by_name[spans.FLUSH_PULL]) == len(by_name[spans.FLUSH])
+    assert [s["iter"] for s in by_name[spans.SEGMENT]] == sorted(
+        s["iter"] for s in by_name[spans.SEGMENT])
+    assert spans.HOST_INPUTS in by_name and spans.EVAL in by_name
+    assert len(booster._gbdt.models) == ROUNDS
+
+
+def test_profiler_changes_no_bit(traced_and_plain):
+    _, _, traced, plain = traced_and_plain
+    assert traced.model_to_string() == plain.model_to_string()
+    assert np.array_equal(np.asarray(traced._gbdt._training_score()),
+                          np.asarray(plain._gbdt._training_score()))
+
+
+# -- kernel names ----------------------------------------------------------
+def _pallas_call_names():
+    with open(hist_pallas.__file__) as fh:
+        tree = ast.parse(fh.read())
+    calls = [n for n in ast.walk(tree) if isinstance(n, ast.Call)
+             and isinstance(n.func, ast.Attribute)
+             and n.func.attr == "pallas_call"]
+    return [next((k.value.value for k in c.keywords if k.arg == "name"),
+                 None) for c in calls]
+
+
+@pytest.mark.parametrize("wrapper", [
+    "leaf_histogram_masked", "leaf_histogram_ranged",
+    "leaf_histogram_blocklist", "leaf_histogram_masked_fused",
+    "leaf_histogram_ranged_fused", "leaf_histogram_blocklist_fused"])
+def test_kernel_is_named_after_its_wrapper(wrapper):
+    """A Pallas custom call's device event is named after the innermost
+    component of its name stack: without a name= a scope around the call
+    renames it and the benchmark's `%leaf_histogram` reader goes blind."""
+    names = _pallas_call_names()
+    assert len(names) == 6 and all(n and "leaf_histogram" in n
+                                   for n in names), names
+    assert wrapper in names and callable(getattr(hist_pallas, wrapper))
+
+
+# -- the benchmark's copy --------------------------------------------------
+@pytest.mark.parametrize("key,ours", [
+    ("device_scopes", spans.DEVICE_SCOPES),
+    ("host_spans", spans.HOST_SPANS),
+    ("enqueue_kinds", spans.ENQUEUE_KINDS)])
+def test_benchmark_copy_is_equal(key, ours):
+    with open(os.path.join(ROOT, "benchmark", "harness",
+                           "scopes.json")) as fh:
+        theirs = json.load(fh)
+    assert tuple(theirs[key]) == ours
+    if key == "device_scopes":      # every scope feeds exactly one metric
+        grouped = [s for g in theirs["device_groups"].values() for s in g]
+        assert sorted(grouped) == sorted(ours)
