@@ -21,7 +21,14 @@ gh2 [2, N] (grad, hess; built once per tree), and ONE leaf_eff [N]
 int32 with the bagging mask pre-folded (out-of-bag rows get -1, which can
 never equal a target leaf).  The (leaf_eff == target) mask is computed
 in-kernel, so per-split traffic is bins + gh2 + leaf_eff only — no [N]
-per-split gvals materialization.
+per-split gvals materialization.  The bin matrix is read as it lies in
+HBM: F need not divide the feature block, the last block then runs past
+the array (39 features: rows 32-47 of 39), and no wrapper copies the
+matrix to whole blocks.  What the rows past the array hold is
+unspecified and cannot reach a result (_feat_grid says why); the
+compiled kernels agree to the bit with the same kernels on a matrix
+padded by the caller, with zeros or with random bytes (TPU v5e, F = 13,
+28, 39, 47: PERF.md, PR 26).
 
 Accumulator modes (`hist_acc`, round 16): "f32" is the default and the
 parity configuration; "bf16" streams gh2 and builds the one-hot operands
@@ -114,6 +121,28 @@ SF_SG_S, SF_SH_S, SF_SG_L, SF_SH_L, SF_INV = range(5)
 
 def _feat_block(f: int) -> int:
     return min(MAX_FEAT_BLOCK, ((f + 7) // 8) * 8)
+
+
+def _feat_grid(f: int):
+    """(fb, fpad, groups) of an [F, N] bin matrix: the feature block, F
+    rounded up to it, and the grid's feature extent cdiv(F, fb).
+
+    The kernels read the matrix IN PLACE: where fb does not divide F the
+    last feature block runs past the array (39 features: rows 32-47 of a
+    39-row array), and no wrapper pads it — a pad here is a copy of the
+    whole resident matrix at every split, which XLA hoists out of neither
+    the ladder's switch nor the grow scan (16% of a tree at 68M x 39:
+    PERF.md, PR 26).  What the rows past the array hold is unspecified,
+    and cannot reach a result: a bin is a byte that _accumulate turns
+    into one-hot operands by integer compares (hi == iota, lo == iota),
+    so ANY byte gives zeros and ones, never a NaN; in the block-diagonal
+    product a feature's rows and columns meet only its own diagonal
+    block, the one _diag_hist_xla extracts, so the rows past F fill only
+    their own slices of the [fpad, ...] output, which every wrapper cuts
+    off (hist[:f], pfs[:f])."""
+    fb = _feat_block(f)
+    groups = (f + fb - 1) // fb
+    return fb, groups * fb, groups
 
 
 def _operand_dtype(hist_acc: str):
@@ -258,11 +287,7 @@ def leaf_histogram_masked(bins_t: jax.Array, gh2: jax.Array,
     f, n = bins_t.shape
     assert n % row_block == 0, (n, row_block)
     assert max_bin <= N_HI * N_LO, max_bin
-    fb = _feat_block(f)
-    fpad = ((f + fb - 1) // fb) * fb
-    if fpad != f:
-        bins_t = jnp.pad(bins_t, ((0, fpad - f), (0, 0)))
-    groups = fpad // fb
+    fb, fpad, groups = _feat_grid(f)
     nblocks = n // row_block
     target = jnp.asarray(target_leaf, dtype=jnp.int32).reshape(1)
 
@@ -337,11 +362,7 @@ def leaf_histogram_ranged(bins_t: jax.Array, gh2: jax.Array,
     f, n = bins_t.shape
     assert n % row_block == 0, (n, row_block)
     assert max_bin <= N_HI * N_LO, max_bin
-    fb = _feat_block(f)
-    fpad = ((f + fb - 1) // fb) * fb
-    if fpad != f:
-        bins_t = jnp.pad(bins_t, ((0, fpad - f), (0, 0)))
-    groups = fpad // fb
+    fb, fpad, groups = _feat_grid(f)
     nblocks = n // row_block
     # n_active >= 1 keeps the clamp and the r==0 init well-defined; an
     # EMPTY target leaf stays correct because the in-kernel mask
@@ -406,11 +427,7 @@ def leaf_histogram_blocklist(bins_t: jax.Array, gh2: jax.Array,
     f, n = bins_t.shape
     assert n % row_block == 0, (n, row_block)
     assert max_bin <= N_HI * N_LO, max_bin
-    fb = _feat_block(f)
-    fpad = ((f + fb - 1) // fb) * fb
-    if fpad != f:
-        bins_t = jnp.pad(bins_t, ((0, fpad - f), (0, 0)))
-    groups = fpad // fb
+    fb, fpad, groups = _feat_grid(f)
     nblocks = n // row_block
     if grid_blocks <= 0 or grid_blocks > nblocks:
         grid_blocks = nblocks
@@ -566,15 +583,15 @@ def _hist_fused_kernel_blocklist(hist_acc, max_bin, params, grid_blocks,
                      hist_acc)
 
 
-def _fused_prep(bins_t, parent_hist, feature_mask,
+def _fused_prep(f, parent_hist, feature_mask,
                 small_stats, large_stats, inv_scale, max_bin):
     """Shared padding + SMEM packing of the fused wrappers.  Returns
-    (bins_t, parent, fmask_f, info_tail, stats, fb, fpad, groups)."""
-    f, _ = bins_t.shape
-    fb = _feat_block(f)
-    fpad = ((f + fb - 1) // fb) * fb
+    (parent, fmask_f, info_tail, stats, fb, fpad, groups)."""
+    fb, fpad, groups = _feat_grid(f)
     if fpad != f:
-        bins_t = jnp.pad(bins_t, ((0, fpad - f), (0, 0)))
+        # kilobytes; the bin matrix itself stays as it is (_feat_grid),
+        # and the False mask is what keeps a feature past F, whatever
+        # its rows held, from winning the in-kernel scan
         parent_hist = jnp.pad(parent_hist,
                               ((0, fpad - f), (0, 0), (0, 0)))
         feature_mask = jnp.pad(feature_mask, (0, fpad - f))
@@ -590,8 +607,8 @@ def _fused_prep(bins_t, parent_hist, feature_mask,
                        jnp.asarray(sg_l, f32), jnp.asarray(sh_l, f32),
                        inv, f32(0), f32(0), f32(0)])
     fmask_f = feature_mask.astype(jnp.float32)
-    return (bins_t, parent_hist.astype(jnp.float32), fmask_f, info_tail,
-            stats, fb, fpad, fpad // fb)
+    return (parent_hist.astype(jnp.float32), fmask_f, info_tail, stats,
+            fb, fpad, groups)
 
 
 def _fused_outs(groups, fb, fpad, hist_acc):
@@ -631,8 +648,8 @@ def leaf_histogram_masked_fused(bins_t: jax.Array, gh2: jax.Array,
     f, n = bins_t.shape
     assert n % row_block == 0, (n, row_block)
     assert max_bin <= N_HI * N_LO, max_bin
-    (bins_t, parent, fmask_f, info_tail, stats, fb, fpad,
-     groups) = _fused_prep(bins_t, parent_hist, feature_mask,
+    (parent, fmask_f, info_tail, stats, fb, fpad,
+     groups) = _fused_prep(f, parent_hist, feature_mask,
                            small_stats, large_stats, inv_scale,
                            max_bin)
     nblocks = n // row_block
@@ -697,8 +714,8 @@ def leaf_histogram_blocklist_fused(bins_t: jax.Array, gh2: jax.Array,
     f, n = bins_t.shape
     assert n % row_block == 0, (n, row_block)
     assert max_bin <= N_HI * N_LO, max_bin
-    (bins_t, parent, fmask_f, info_tail, stats, fb, fpad,
-     groups) = _fused_prep(bins_t, parent_hist, feature_mask,
+    (parent, fmask_f, info_tail, stats, fb, fpad,
+     groups) = _fused_prep(f, parent_hist, feature_mask,
                            small_stats, large_stats, inv_scale,
                            max_bin)
     nblocks = n // row_block
@@ -772,8 +789,8 @@ def leaf_histogram_ranged_fused(bins_t: jax.Array, gh2: jax.Array,
     f, n = bins_t.shape
     assert n % row_block == 0, (n, row_block)
     assert max_bin <= N_HI * N_LO, max_bin
-    (bins_t, parent, fmask_f, info_tail, stats, fb, fpad,
-     groups) = _fused_prep(bins_t, parent_hist, feature_mask,
+    (parent, fmask_f, info_tail, stats, fb, fpad,
+     groups) = _fused_prep(f, parent_hist, feature_mask,
                            small_stats, large_stats, inv_scale,
                            max_bin)
     nblocks = n // row_block

@@ -83,12 +83,15 @@ def _assert_best_equal(want, got, msg=""):
             err_msg="%s field %s" % (msg, fld))
 
 
-def test_fused_masked_kernel_bit_identical():
+# f = 9 is one feature block larger than the array, 39 has a ragged
+# third block: the kernels read the bin matrix in place either way
+@pytest.mark.parametrize("f", [9, 39])
+def test_fused_masked_kernel_bit_identical(f):
     """Fused sweep: histogram bit-equal to the plain kernel, and the
     per-feature rows finish to the EXACT BestSplit the two-op oracle
     (find_best_split over the materialized tensor) produces — for the
     swept child and the subtracted sibling."""
-    c = _kernel_case()
+    c = _kernel_case(f=f)
     hist, pfs, pfl = leaf_histogram_masked_fused(
         c["bins"], c["gh2"], c["leaf_eff"], jnp.int32(2), c["parent"],
         c["fmask"], c["s_stats"], c["l_stats"], None, max_bin=c["b"],
@@ -104,11 +107,12 @@ def test_fused_masked_kernel_bit_identical():
         find_best_split_fused(pfl, sgl, shl, c["params"]), "large")
 
 
-def test_fused_blocklist_and_ranged_bit_identical():
+@pytest.mark.parametrize("f", [9, 39])
+def test_fused_blocklist_and_ranged_bit_identical(f):
     """The ordered-partition fused variants: full block list == full
     sweep == masked fused, per-feature rows included; a partial list
     covering the target's blocks is bit-identical too."""
-    c = _kernel_case(n=1024, row_block=128)
+    c = _kernel_case(n=1024, f=f, row_block=128)
     nblk = c["n"] // c["row_block"]
     want = leaf_histogram_masked_fused(
         c["bins"], c["gh2"], c["leaf_eff"], jnp.int32(2), c["parent"],

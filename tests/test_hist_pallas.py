@@ -7,10 +7,12 @@ import numpy as np
 import pytest
 
 from lightgbm_tpu.ops.histogram import leaf_histogram, make_gvals
-from lightgbm_tpu.ops.hist_pallas import (PALLAS_ROW_BLOCK,
+from lightgbm_tpu.ops.hist_pallas import (PALLAS_ROW_BLOCK, _feat_grid,
                                           fold_leaf_mask,
+                                          leaf_histogram_blocklist,
                                           leaf_histogram_masked,
-                                          leaf_histogram_pallas, make_gh2)
+                                          leaf_histogram_pallas,
+                                          leaf_histogram_ranged, make_gh2)
 
 
 def _data(n, f, b, seed=0):
@@ -51,6 +53,58 @@ def test_masked_kernel_matches_xla_oracle():
     mask = (leaf_id == target) & (bag != 0)
     gv = make_gvals(jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask),
                     jnp.float32)
+    want = leaf_histogram(jnp.asarray(bins_t), gv, max_bin=b)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+def _sweep(kernel, bins, gh2, leaf_eff, target, nblocks, b):
+    """One of the three two-op kernels over blocks [1, nblocks - 1)."""
+    kw = dict(max_bin=b, row_block=128, interpret=True)
+    if kernel == "masked":
+        return leaf_histogram_masked(bins, gh2, leaf_eff, target, **kw)
+    if kernel == "ranged":
+        return leaf_histogram_ranged(bins, gh2, leaf_eff, target,
+                                     jnp.int32(1), jnp.int32(nblocks - 2),
+                                     **kw)
+    blist = jnp.concatenate([jnp.arange(1, nblocks - 1, dtype=jnp.int32),
+                             jnp.zeros(2, jnp.int32)])
+    return leaf_histogram_blocklist(bins, gh2, leaf_eff, target, blist,
+                                    jnp.int32(nblocks - 2), **kw)
+
+
+@pytest.mark.parametrize("kernel", ["masked", "ranged", "blocklist"])
+@pytest.mark.parametrize("b", [63, 255])
+@pytest.mark.parametrize("f", [13, 28, 39, 47])
+def test_ragged_feature_block_reads_bins_in_place(f, b, kernel):
+    """F that its feature block does not divide: the kernel reads the
+    [F, N] matrix as it is (the last block runs past the array) and gives
+    the same BITS as on a matrix the caller padded to whole blocks —
+    with zeros, as the wrappers did, or with any other bytes: the rows
+    past F reach only their own slices of the output, which are cut."""
+    n, nblocks, target = 1024, 8, 3
+    bins_t, grad, hess, _ = _data(n, f, b, seed=f + b)
+    rng = np.random.RandomState(f)
+    leaf_id = rng.randint(2, 5, size=n).astype(np.int32)
+    if kernel != "masked":      # the target's rows lie in the swept range
+        leaf_id[:128] = 0
+        leaf_id[-128:] = 0
+    bag = rng.rand(n) < 0.8
+    gh2 = make_gh2(jnp.asarray(grad), jnp.asarray(hess))
+    leaf_eff = fold_leaf_mask(jnp.asarray(leaf_id), jnp.asarray(bag))
+    _, fpad, _ = _feat_grid(f)
+    assert fpad > f
+    got = _sweep(kernel, jnp.asarray(bins_t), gh2, leaf_eff,
+                 jnp.int32(target), nblocks, b)
+    assert got.shape == (f, b, 3)
+    for tail in (np.zeros((fpad - f, n), np.uint8),
+                 rng.randint(0, 256, size=(fpad - f, n)).astype(np.uint8)):
+        padded = _sweep(kernel, jnp.asarray(np.vstack([bins_t, tail])), gh2,
+                        leaf_eff, jnp.int32(target), nblocks, b)
+        assert padded.shape == (fpad, b, 3)
+        assert jnp.array_equal(got, padded[:f])
+    gv = make_gvals(jnp.asarray(grad), jnp.asarray(hess),
+                    jnp.asarray((leaf_id == target) & bag), jnp.float32)
     want = leaf_histogram(jnp.asarray(bins_t), gv, max_bin=b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
