@@ -201,14 +201,15 @@ def _batch_iters(body, spec, k):
 _DISPATCHES = 0
 
 
-def _enqueue(kind: str, k: int) -> TraceAnnotation:
+def _enqueue(kind: str, k: int, shards: int = 1) -> TraceAnnotation:
     """The span around one call of a jitted training executable:
     `kind` names it (spans.ENQUEUE_KINDS), `k` is the boosting
-    iterations it covers.  The call returns when the work is enqueued,
-    not when the device is done (unless it compiles first)."""
+    iterations it covers, `shards` the devices the one program runs on.
+    The call returns when the work is enqueued, not when the device is
+    done (unless it compiles first)."""
     global _DISPATCHES
     _DISPATCHES += 1
-    return TraceAnnotation(spans.ENQUEUE, kind=kind, k=k)
+    return TraceAnnotation(spans.ENQUEUE, kind=kind, k=k, shards=shards)
 
 
 def dispatch_count() -> int:
@@ -1125,14 +1126,18 @@ class GBDT:
             self.scores = jnp.asarray(
                 self._shard_layout.place(np.asarray(self.scores)))
         elif self.n_pad != n:
-            if bins is not None:
+            # a row-sharded grower pads block by block (shard_bins)
+            if bins is not None and not self.rows_sharded:
                 bins = np.pad(bins, ((0, 0), (0, self.n_pad - n)))
             self.scores = jnp.pad(self.scores,
                                   ((0, 0), (0, self.n_pad - n)))
         if self.grower is not None:
-            self.bins_dev = (self._put_bins_sharded_streamed(train_data)
-                             if streamed
-                             else self.grower.shard_bins(bins))
+            if streamed:
+                self.bins_dev = self._put_bins_sharded_streamed(train_data)
+            elif self.rows_sharded:
+                self.bins_dev = self.grower.shard_bins(bins, self.n_pad)
+            else:
+                self.bins_dev = self.grower.shard_bins(bins)
             if self.rows_sharded and not self._mh:
                 # single-host: shard scores so the leaf_id gather-add
                 # stays on-device
@@ -1187,9 +1192,14 @@ class GBDT:
                       or self._can_fuse_multi())
         self._flush_every = 16 if deferrable else 1
         # multi-host fused: every input of the global fused dispatch must
-        # be a global array, including the scalar stopped flag
+        # be a global array, including the scalar stopped flag.  Single-
+        # host sharded: the first dispatch's inputs are placed as every
+        # later dispatch's are (the step's own outputs), else the re-sort
+        # step compiles a second time at its second call (the flag here,
+        # the bag mask in _bag_mask_dev_fused, the gradient state in
+        # _gstate_for_fused)
         self._dev_stopped = (self.grower.replicate(np.asarray(False))
-                             if self._mh_fused else jnp.asarray(False))
+                             if self._fused_sharded else jnp.asarray(False))
         self.bag_rng = Mt19937Random(config.bagging_seed)
         # bag compaction (config.bag_compact): in-bag rows arranged into
         # a contiguous STATIC window at every re-bagging so the fused
@@ -1604,7 +1614,7 @@ class GBDT:
             if reorder:
                 common += (self._row_order if self._row_order is not None
                            else self._identity_order_dev(),)
-        with _enqueue("multi", k_iters):
+        with _enqueue("multi", k_iters, self._shards):
             out = fn(*common)
         if reorder:
             (scores, valid, ints_k, floats_k, self._dev_stopped,
@@ -1648,6 +1658,15 @@ class GBDT:
                     lambda a: self.grower.shard_rows(np.asarray(a),
                                                      self.n_pad), gstate)
                 self._gstate_override = gstate
+            elif self._fused_sharded:
+                # chip to chip, once: the objective's arrays sit on the
+                # first device
+                from jax.sharding import NamedSharding
+                gstate = jax.tree_util.tree_map(
+                    lambda a, spec: jax.device_put(
+                        a, NamedSharding(self.grower.mesh, spec)),
+                    gstate, self._fused_gspecs(gstate))
+                self._gstate_override = gstate
         return gstate
 
     def _build_sharded_gstate_host(self):
@@ -1670,6 +1689,9 @@ class GBDT:
             return self.grower.shard_rows(
                 np.arange(base, base + self.n_pad, dtype=np.int32),
                 self.n_pad)
+        if self._fused_sharded:     # each chip makes its own block
+            return jnp.arange(self.n_pad, dtype=jnp.int32,
+                              device=self.grower.row_sharding())
         return jnp.arange(self.n_pad, dtype=jnp.int32)
 
     def _reorder_enabled(self) -> bool:
@@ -1846,8 +1868,7 @@ class GBDT:
                     # into the per-shard blocks; gap rows stay False
                     m_host = self._shard_layout.place(
                         m_host[:self.num_data], fill=False)
-                m = (self.grower.shard_rows(m_host, self.n_pad)
-                     if self._mh_fused else jnp.asarray(m_host))
+                m = self.grower.shard_rows(m_host, self.n_pad)
                 if self._row_order is not None:
                     m = self.grower.permute_rows(m, self._row_order)
                 self._bag_dev_packed[cls] = m
@@ -2012,7 +2033,7 @@ class GBDT:
         args = (self.bins_dev, self.scores, mask, gstate, order)
         if bank is not None:
             args += (bank,)
-        with _enqueue("arrange", 0):
+        with _enqueue("arrange", 0, self._shards):
             out = fn(*args)
         self.bins_dev, self.scores, mask_new, gstate_new, order_new = \
             out[:5]
@@ -2096,7 +2117,7 @@ class GBDT:
                 bag_mask_dev = _unpack_bag_jit(bag_mask_dev, self.n_pad)
             order = (self._row_order if self._row_order is not None
                      else self._identity_order_dev())
-            with _enqueue("resort", k_iters):
+            with _enqueue("resort", k_iters, self._shards):
                 (scores, valid, ints, floats, bins_new, bag_new,
                  gstate_new, order_new, self._dev_stopped) = fn(
                     self.scores, list(self.valid_scores), bag_mask_dev,
@@ -2109,7 +2130,7 @@ class GBDT:
             self._inv_order = None
             self._trees_since_reorder = 0
         else:
-            with _enqueue("scan", k_iters):
+            with _enqueue("scan", k_iters, self._shards):
                 scores, valid, ints, floats, self._dev_stopped = fn(
                     self.scores, list(self.valid_scores), bag_mask_dev,
                     fmask_dev, self.bins_dev, tuple(self.valid_bins_dev),
@@ -2130,7 +2151,7 @@ class GBDT:
                             "path is parity-tested against (PARITY.md)")
     def _train_tree(self, grad, hess, bag_mask_dev, fmask, cls):
         cfg = self.config
-        with _enqueue("general", 1):   # one grow dispatch per tree
+        with _enqueue("general", 1, self._shards):   # one dispatch per tree
             if self.grower is not None and self._mh:
                 # assemble process-local grad/hess into global sharded
                 # arrays, grow SPMD across hosts, then pull the tree
@@ -2206,6 +2227,28 @@ class GBDT:
     def models(self, value) -> None:
         self._models = list(value)
 
+    @property
+    def _shards(self) -> int:
+        """Devices one training executable runs on (lgbm.enqueue)."""
+        return 1 if self.grower is None else self.grower.num_shards
+
+    def _exchange_bytes(self, leaves: int) -> Optional[int]:
+        """Bytes ONE shard gave to histogram collectives for trees with
+        `leaves` leaves in all (lgbm.flush): a tree reduces its root's
+        histogram and each split's smaller child's, so one [F, B, 3]
+        histogram a leaf under tree_learner=data (hist_agg=scatter pads
+        F to the shard count).  0 without a grower; nothing for the
+        voting and feature learners, whose exchange is not a histogram
+        a leaf."""
+        if self.grower is None:
+            return 0
+        if self.config.tree_learner != "data":
+            return None
+        f = self.train_data.num_features
+        if self.config.hist_agg == "scatter":
+            f = -(-f // self.grower.num_shards) * self.grower.num_shards
+        return leaves * f * self.max_bin * 3 * np.dtype(self.dtype).itemsize
+
     @contract.counted_flush
     def _flush_pending(self) -> bool:
         """Unpack pending device trees; truncate at the first 1-leaf stump
@@ -2229,7 +2272,8 @@ class GBDT:
         pend = [m for m in pending if not isinstance(m.ints, np.ndarray)]
         with TraceAnnotation(
                 spans.FLUSH, trees=len(pending),
-                bytes=sum(m.ints.nbytes + m.floats.nbytes for m in pend)):
+                bytes=sum(m.ints.nbytes + m.floats.nbytes for m in pend)
+                ) as flush_span:
             if pend:
                 # _pack_tree pads every tree to the config-fixed leaf
                 # count (see _PendingTree); a future variable-size packing
@@ -2247,6 +2291,11 @@ class GBDT:
                          jnp.stack([m.floats for m in pend])))
                 for m, ih, fh in zip(pend, ints_all, floats_all):
                     m.ints, m.floats = ih, fh
+            # a packed tree's first int is its leaf count
+            wire = self._exchange_bytes(sum(int(m.ints[0])
+                                            for m in pending))
+            if wire is not None:
+                flush_span.set_metadata(exchange_bytes=wire)
             with TraceAnnotation(spans.FLUSH_UNPACK):
                 self._unpack_pending()
         return self._stopped
@@ -2349,8 +2398,24 @@ class GBDT:
         if self._row_order is None:
             return None
         if self._inv_order is None:
-            self._inv_order = jnp.argsort(self._row_order)
+            self._inv_order = (
+                self.grower.inverse_order(self._row_order)
+                if self._rows_local() else jnp.argsort(self._row_order))
         return self._inv_order
+
+    def _rows_local(self) -> bool:
+        """Single-host fused data-parallel: the row order keeps every
+        shard's rows in its own block, so inverting it and taking rows by
+        it are per-shard work (ShardedGrower.inverse_order, permute_rows)
+        and not a sort and a gather of the whole array on each device."""
+        return self._fused_sharded and not self._mh
+
+    def _take_rows(self, arr, index):
+        """arr[:, index] for a [K, n_pad] per-row array and an order or
+        its inverse."""
+        if self._rows_local():
+            return self.grower.permute_rows(arr, index)
+        return jnp.take(arr, index, axis=1)
 
     def _ensure_layout(self) -> None:
         """(Re-)place per-row state into the query-granular layout when
@@ -2449,7 +2514,7 @@ class GBDT:
             return
         inv = self._inverse_row_order()
         if inv is not None:
-            self.scores = jnp.take(self.scores, inv, axis=1)
+            self.scores = self._take_rows(self.scores, inv)
         if self._layout_active:
             # query-granular layout -> file order + trailing pad (the
             # general path's convention); _ensure_layout re-places when
@@ -2504,7 +2569,7 @@ class GBDT:
         if inv is not None:
             # ordered-partition mode keeps per-row state sorted by tree
             # leaves; metrics (and any external reader) see file order
-            s = jnp.take(s, inv, axis=1)
+            s = self._take_rows(s, inv)
         if self._layout_active:
             s = jnp.take(s, self._layout_pos_dev(), axis=1)
         s = s[:, :self.num_data]
@@ -3397,7 +3462,7 @@ class DART(GBDT):
                                 else np.stack(fmasks)),
                     self.bins_dev, tuple(self.valid_bins_dev),
                     self._gstate_for_fused(), self._dev_stopped, t_row)
-        with _enqueue("dart", k_iters):
+        with _enqueue("dart", k_iters, self._shards):
             (self.scores, valid, bi, bf, lb, vbs, ints, floats,
              self._dev_stopped) = fn(*args)
         self._bank = [bi, bf, lb, list(vbs)]

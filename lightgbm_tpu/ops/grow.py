@@ -362,8 +362,10 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
             # the MAX occupancy over shards so every shard dispatches the
             # same compiled branch; each shard still sweeps only its OWN
             # occupied blocks (blist / n_occ stay shard-local)
-            n_sel = (jax.lax.pmax(n_occ, psum_axis) if psum_axis
-                     else n_occ)
+            n_sel = n_occ
+            if psum_axis:
+                with jax.named_scope(spans.HIST_EXCHANGE):
+                    n_sel = jax.lax.pmax(n_occ, psum_axis)
             sel = jnp.int32(len(ladder) - 1)
             for i in range(len(ladder) - 2, -1, -1):
                 sel = jnp.where(n_sel <= ladder[i], jnp.int32(i), sel)

@@ -13,6 +13,7 @@ import pytest
 
 from lightgbm_tpu.serving.forest import ServingForest
 from lightgbm_tpu.utils.device import resolve_device
+from lightgbm_tpu.utils import log
 from lightgbm_tpu.utils.log import LightGBMError
 
 from test_predict_fast import BINARY_MODEL
@@ -115,6 +116,8 @@ def test_serve_engine_auto_says_so_when_jax_does_not_import(
     silently; an explicit backend=jax raises, and so does auto under
     device_type=tpu."""
     monkeypatch.setitem(sys.modules, "jax", None)   # import jax -> ImportError
+    # a test that ran before on this worker may have left verbosity=-1
+    monkeypatch.setattr(log, "_level", log.WARNING)
     forest = ServingForest(BINARY_MODEL, backend="auto")
     assert forest.engine == "host"
     assert "jax does not import" in capsys.readouterr().out
