@@ -212,7 +212,7 @@ GLOBAL_JAX_KNOBS = {
 # convention (objective gradient factories; the fused-step makers are
 # caught structurally via jax.jit/shard_map dataflow).
 TRACED_FACTORY_NAMES = re.compile(
-    r"^(make_grad_fn|make_permute_fn|_fused_step\w*|fused_step\w*)$")
+    r"^(make_grad_fn|make_row_state_fn|_fused_step\w*|fused_step\w*)$")
 
 _JIT_NAMES = {"jax.jit", "jit", "jax.pjit", "pjit"}
 _TRACE_TRANSFORMS = {

@@ -201,15 +201,18 @@ def _batch_iters(body, spec, k):
 _DISPATCHES = 0
 
 
-def _enqueue(kind: str, k: int, shards: int = 1) -> TraceAnnotation:
+def _enqueue(kind: str, k: int, shards: int = 1,
+             **stats: int) -> TraceAnnotation:
     """The span around one call of a jitted training executable:
     `kind` names it (spans.ENQUEUE_KINDS), `k` is the boosting
-    iterations it covers, `shards` the devices the one program runs on.
-    The call returns when the work is enqueued, not when the device is
-    done (unless it compiles first)."""
+    iterations it covers, `shards` the devices the one program runs on;
+    a dispatch that re-sorts the rows adds `carried` and `taken`
+    (_resort_counts).  The call returns when the work is enqueued, not
+    when the device is done (unless it compiles first)."""
     global _DISPATCHES
     _DISPATCHES += 1
-    return TraceAnnotation(spans.ENQUEUE, kind=kind, k=k, shards=shards)
+    return TraceAnnotation(spans.ENQUEUE, kind=kind, k=k, shards=shards,
+                           **stats)
 
 
 def dispatch_count() -> int:
@@ -266,45 +269,111 @@ def _make_fused_step(grad_fn, grow_kw, lr, dtype, compact_rows=0,
     return jax.jit(body, donate_argnums=(0, 1))
 
 
-@contract.traced_pure
-def _permute_window_rows(rel_w, m, n, bufs):
-    """Window-local re-sort of row-major buffers (rows on the LAST
-    axis) under bag compaction: gather positions [:m] by rel_w and keep
-    the OOB tail as a contiguous copy — the tail-stays-in-place
-    invariant that _bag_arrange_body and grow_tree_bagged rely on (tail
-    rows never enter histograms, so their clustering is irrelevant and
-    their gathers would be pure waste).  Returns (full-length rel for
-    gstate permutation, permuted buffers)."""
-    rel = jnp.concatenate([rel_w, jnp.arange(m, n, dtype=jnp.int32)])
-    out = [jnp.concatenate([jnp.take(b[..., :m], rel_w, axis=-1),
-                            b[..., m:]], axis=-1) for b in bufs]
-    return rel, out
+def _moves_as_word(a, n: int) -> bool:
+    """One row a position, rows on the last axis ([N], or [1, N] like
+    the single-class scores), and a 32-bit word (bitcast) or a narrower
+    integer or bool (widened; a narrow float would change its value):
+    such an array follows a re-sort as one row of the stacked word
+    matrix that ONE gather moves.  Anything else ([F, N] bins, [K, N]
+    class-wise scores and masks, DART's [T, N] leaf bank, 64-bit state)
+    follows the permutation by a gather of its own."""
+    return a.shape[-1] == n and a.size == n and (
+        a.dtype.itemsize == 4 or (a.dtype.itemsize < 4 and (
+            a.dtype == jnp.bool_ or jnp.issubdtype(a.dtype, jnp.integer))))
+
+
+def _resort_counts(bufs, gstate, row_state):
+    """The `carried` / `taken` stats of a re-sorting dispatch's
+    lgbm.enqueue span: how many per-row arrays move together in the one
+    gather of 32-bit words and how many follow by a gather of their own
+    (_resort_rows makes the same choice from the same shapes).  A gather
+    costs per INDEX, hardly per byte (PERF.md section 6, PR 28), so a
+    leaf that silently falls from the first count to the second costs a
+    second and a half at 68M rows."""
+    arrays = list(bufs) + list(row_state(gstate)[0])
+    n = arrays[0].shape[-1]
+    carried = sum(_moves_as_word(a, n) for a in arrays)
+    return {"carried": carried, "taken": len(arrays) - carried}
 
 
 @contract.traced_pure
-def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype,
-                             permute_state=None, compact_rows=0):
+def _resort_rows(keys, bufs, gstate, row_state):
+    """Stable re-sort of every per-row buffer (rows on the LAST axis) by
+    `keys` (most significant first; ties keep their order).  One stable
+    lax.sort of the keys and an iota gives the permutation `rel` (new
+    position j holds old row rel[j]); every array with one row a
+    position (_moves_as_word) is bitcast or widened to a uint32 row of
+    ONE stacked matrix that a single gather moves, so a row's state
+    moves once; the wider arrays follow `rel` by a gather each.  Equal
+    to argsort(stable=True) and a take per array, to the bit, at a fifth
+    of their cost on the chip.  (The same arrays as payload operands of
+    the sort itself run another 0.8 s a re-sort faster at 68M rows and
+    compile a minute longer on a cold start: PERF.md section 6, PR 28.)
+
+    Keys shorter than the buffers (bag compaction: the static in-bag
+    window [:m]) sort the window only and keep the out-of-bag tail as a
+    contiguous copy — the tail-stays-in-place invariant that
+    _bag_arrange_body and grow_tree_bagged rely on (tail rows never
+    enter histograms, so their clustering is irrelevant and moving them
+    would be pure waste).
+
+    `row_state` is the objective's make_row_state_fn: which leaves of
+    `gstate` are per-row (they join `bufs`), and how the permuted state
+    is rebuilt from them and the full-length `rel` (lambdarank remaps
+    its doc_idx row positions through the inverse).  Returns (permuted
+    bufs, permuted gstate)."""
+    rows, rebuild = row_state(gstate)
+    arrays = list(bufs) + list(rows)
+    n = arrays[0].shape[-1]
+    m = keys[0].shape[0]
+    rel = jax.lax.sort(tuple(keys) + (jnp.arange(m, dtype=jnp.int32),),
+                       num_keys=len(keys), is_stable=True)[len(keys)]
+
+    def word(a):
+        a = a.reshape(n)[:m]
+        if a.dtype.itemsize == 4:
+            return jax.lax.bitcast_convert_type(a, jnp.uint32)
+        return a.astype(jnp.uint32)
+
+    def unword(w, a):
+        if a.dtype.itemsize == 4:
+            return jax.lax.bitcast_convert_type(w, a.dtype)
+        return w.astype(a.dtype)
+
+    carried = [i for i, a in enumerate(arrays) if _moves_as_word(a, n)]
+    # the row order is among them at every site, so the stack is never empty
+    words = jnp.take(jnp.stack([word(arrays[i]) for i in carried]), rel,
+                     axis=1)
+    together = {i: unword(w, arrays[i]) for i, w in zip(carried, words)}
+    moved = []
+    for i, a in enumerate(arrays):
+        w = (together[i].reshape(a.shape[:-1] + (m,)) if i in together
+             else jnp.take(a[..., :m], rel, axis=-1))
+        moved.append(w if m == n
+                     else jnp.concatenate([w, a[..., m:]], axis=-1))
+    if m < n:
+        rel = jnp.concatenate([rel, jnp.arange(m, n, dtype=jnp.int32)])
+    return moved[:len(bufs)], rebuild(moved[len(bufs):], rel)
+
+
+@contract.traced_pure
+def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype, row_state,
+                             compact_rows=0):
     """The fused step PLUS the ordered-partition row re-sort: after the
     tree lands, rows are stably re-sorted by its leaf assignment so later
     trees' leaves stay block-clustered and the block-list sweeps
     (ops/grow.py ranged mode) touch few blocks.  Everything per-row
     (bins, scores, bag mask, objective state, the composed row order)
-    comes back permuted in the SAME dispatch; valid sets and tree output
-    are row-order-free.
+    comes back permuted in the SAME dispatch (_resort_rows); valid sets
+    and tree output are row-order-free.
 
-    `permute_state` is the objective's make_permute_fn (how its
-    grad_state follows the permutation — default: every leaf per-row on
-    its last axis; lambdarank remaps its doc_idx row positions).
+    `row_state` is the objective's make_row_state_fn (how its
+    grad_state follows the permutation).
 
     `compact_rows` (bag compaction): only the static in-bag window
-    re-sorts — its gathers scale with the bag, and the out-of-bag tail
+    re-sorts — its work scales with the bag, and the out-of-bag tail
     keeps its positions (tail rows never enter histograms, so their
     clustering is irrelevant)."""
-    if permute_state is None:
-        def permute_state(gstate, rel):
-            return jax.tree_util.tree_map(
-                lambda a: jnp.take(a, rel, axis=-1), gstate)
-
     def step(scores, valid_scores, bag_mask, fmask, bins, valid_bins,
              gstate, row_order, stopped):
         bag = _unpack_bag(bag_mask, bins.shape[1])
@@ -332,25 +401,14 @@ def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype,
             ints, floats = _pack_tree(dev_tree)
         n = bins.shape[1]
         with jax.named_scope(spans.RESORT):
-            if 0 < compact_rows < n:
-                # window-local stable sort; the OOB tail stays in place
-                # and every gather below touches only the window
-                m = compact_rows
-                rel_w = jnp.argsort(leaf_id[:m],
-                                    stable=True).astype(jnp.int32)
-                rel, (bins_new, scores, bag_new, order_new) = \
-                    _permute_window_rows(rel_w, m, n,
-                                         [bins, scores, bag, row_order])
-            else:
-                # stable sort by this tree's leaves; padded rows ride
-                # along via their tracked leaf_id and stay permanently
-                # out-of-bag through the permuted bag mask
-                rel = jnp.argsort(leaf_id, stable=True).astype(jnp.int32)
-                bins_new = jnp.take(bins, rel, axis=1)
-                scores = jnp.take(scores, rel, axis=1)
-                bag_new = jnp.take(bag, rel)
-                order_new = jnp.take(row_order, rel)
-            gstate_new = permute_state(gstate, rel)
+            # stable sort by this tree's leaves; padded rows ride along
+            # via their tracked leaf_id and stay permanently out-of-bag
+            # through the permuted bag mask
+            m = compact_rows if 0 < compact_rows < n else n
+            (bins_new, scores, bag_new, order_new), gstate_new = \
+                _resort_rows((leaf_id[:m],),
+                             [bins, scores, bag, row_order], gstate,
+                             row_state)
         return (scores, new_valid, ints, floats, bins_new, bag_new,
                 gstate_new, order_new, stopped)
     return step
@@ -360,13 +418,12 @@ def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype,
 @contract.fused_body(extras=("order",),
                      collectives=("all_gather", "axis_index", "pmax",
                                   "psum", "psum_scatter"))
-def _make_fused_step_reorder(grad_fn, grow_kw, lr, dtype,
-                             permute_state=None, compact_rows=0,
-                             k_iters=1):
+def _make_fused_step_reorder(grad_fn, grow_kw, lr, dtype, row_state,
+                             compact_rows=0, k_iters=1):
     # gstate is NOT donated: on the first re-sort it aliases the
     # objective's own arrays, which must stay valid for metrics/restarts
     body = _batch_iters(_fused_step_body_reorder(grad_fn, grow_kw, lr,
-                                                 dtype, permute_state,
+                                                 dtype, row_state,
                                                  compact_rows),
                         _SCAN_REORDER, k_iters)
     return jax.jit(body, donate_argnums=(0, 1, 2, 4, 7))
@@ -527,8 +584,8 @@ def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
 
 
 @contract.traced_pure
-def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder=False,
-                           permute_state=None, compact_rows=0):
+def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder,
+                           row_state, compact_rows=0):
     """Fused MULTICLASS iteration (VERDICT r3 #4): gradients for all K
     classes from the pre-iteration scores, then a class-wise lax.scan
     grows the K per-iteration trees in ONE dispatch — the reference's
@@ -596,35 +653,17 @@ def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder=False,
             ints_k, floats_k = ys
             return scores, list(vss), ints_k, floats_k, stopped
         ints_k, floats_k, leaf_k = ys                   # leaf_k [K, N]
-        # stable lexicographic sort, class 0 primary: chained stable
-        # argsorts from the least-significant class up (np.lexsort's
-        # construction), composing the relative permutation.  Under bag
-        # compaction only the static union window re-sorts; the OOB
-        # tail keeps its positions (it never enters histograms)
+        # stable lexicographic sort, class 0 primary: the K leaf
+        # assignments are the sort's K keys.  Under bag compaction only
+        # the static union window re-sorts; the OOB tail keeps its
+        # positions (it never enters histograms)
         with jax.named_scope(spans.RESORT):
             n = bins.shape[1]
             m = compact_rows if 0 < compact_rows < n else n
-            rel = jnp.argsort(leaf_k[num_class - 1, :m],
-                              stable=True).astype(jnp.int32)
-            for k in range(num_class - 2, -1, -1):
-                keys = jnp.take(leaf_k[k, :m], rel)
-                rel = jnp.take(
-                    rel, jnp.argsort(keys, stable=True).astype(jnp.int32))
-            if m < n:
-                # window-local gathers + contiguous tail copy, like the
-                # single-class reorder branch — only gstate needs the
-                # composed full-length permutation (doc_idx remaps etc.)
-                rel, (bins_new, scores, bag_new, order_new) = \
-                    _permute_window_rows(
-                        rel, m, n, [bins, scores, bag_masks, row_order[0]])
-            else:
-                bins_new = jnp.take(bins, rel, axis=1)
-                scores = jnp.take(scores, rel, axis=1)
-                bag_new = jnp.take(bag_masks, rel, axis=1)
-                order_new = jnp.take(row_order[0], rel)
-            gstate_new = (permute_state(gstate, rel) if permute_state
-                          is not None else jax.tree_util.tree_map(
-                              lambda a: jnp.take(a, rel, axis=-1), gstate))
+            (bins_new, scores, bag_new, order_new), gstate_new = \
+                _resort_rows(tuple(leaf_k[k, :m] for k in range(num_class)),
+                             [bins, scores, bag_masks, row_order[0]],
+                             gstate, row_state)
         return (scores, list(vss), ints_k, floats_k, stopped,
                 bins_new, bag_new, gstate_new, order_new)
     return step
@@ -634,14 +673,14 @@ def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder=False,
 @contract.fused_body(extras=("order",),
                      collectives=("all_gather", "axis_index", "pmax",
                                   "psum", "psum_scatter"))
-def _make_fused_step_multi(grad_fn, grow_kw, lr, dtype, reorder=False,
-                           permute_state=None, compact_rows=0, k_iters=1):
+def _make_fused_step_multi(grad_fn, grow_kw, lr, dtype, reorder,
+                           row_state, compact_rows=0, k_iters=1):
     # gstate is NOT donated: on the first re-sort it aliases the
     # objective's own arrays (same constraint as the single-class
     # reorder step)
     body = _batch_iters(
         _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder,
-                               permute_state, compact_rows),
+                               row_state, compact_rows),
         _SCAN_MULTI_REORDER if reorder else _SCAN_MULTI, k_iters)
     return jax.jit(body,
                    donate_argnums=(0, 1, 2, 4, 8) if reorder else (0, 1))
@@ -653,7 +692,7 @@ def _make_fused_step_multi(grad_fn, grow_kw, lr, dtype, reorder=False,
                                   "psum", "psum_scatter"))
 def _make_fused_step_multi_sharded(grad_fn, grow_kw, lr, dtype, mesh,
                                    n_valid, gstate_specs, reorder,
-                                   permute_state=None, compact_rows=0,
+                                   row_state, compact_rows=0,
                                    k_iters=1):
     """The multiclass fused step under shard_map for single-host
     tree_learner=data (VERDICT r4 #3): the class-wise scan body already
@@ -671,7 +710,7 @@ def _make_fused_step_multi_sharded(grad_fn, grow_kw, lr, dtype, mesh,
     # replicated specs (P()) cover the [K, ...] xs/ys at any rank
     body = _batch_iters(
         _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder,
-                               permute_state, compact_rows),
+                               row_state, compact_rows),
         _SCAN_MULTI_REORDER if reorder else _SCAN_MULTI, k_iters)
     row = P(DATA_AXIS)
     row2 = P(None, DATA_AXIS)
@@ -699,7 +738,7 @@ def _make_fused_step_multi_sharded(grad_fn, grow_kw, lr, dtype, mesh,
                                   "psum", "psum_scatter"))
 def _make_fused_step_sharded(grad_fn, grow_kw, lr, dtype, mesh,
                              n_valid, gstate_specs, reorder,
-                             permute_state=None, compact_rows=0,
+                             row_state, compact_rows=0,
                              k_iters=1):
     """The fused step under shard_map for single-host tree_learner=data
     (VERDICT r3 #2): per-row state (scores row, bins, bag mask, gradient
@@ -716,7 +755,7 @@ def _make_fused_step_sharded(grad_fn, grow_kw, lr, dtype, mesh,
     from ..parallel.mesh import DATA_AXIS, shard_map
 
     body = (_batch_iters(_fused_step_body_reorder(grad_fn, grow_kw, lr,
-                                                  dtype, permute_state,
+                                                  dtype, row_state,
                                                   compact_rows),
                          _SCAN_REORDER, k_iters)
             if reorder
@@ -743,7 +782,7 @@ def _make_fused_step_sharded(grad_fn, grow_kw, lr, dtype, mesh,
 
 
 @contract.traced_pure
-def _bag_arrange_body(permute_state, multi):
+def _bag_arrange_body(row_state, multi):
     """In-bag-first stable arrangement of every per-row device buffer —
     the bag-compaction boundary step, ONE dispatch per re-bagging.  The
     arrangement is a plain row permutation (in-bag rows first, relative
@@ -756,30 +795,23 @@ def _bag_arrange_body(permute_state, multi):
     def arrange(bins, scores, mask, gstate, order, *bank):
         with jax.named_scope(spans.BAG_ARRANGE):
             key = mask.any(axis=0) if multi else mask
-            rel = jnp.argsort(jnp.logical_not(key),
-                              stable=True).astype(jnp.int32)
-            bins_new = jnp.take(bins, rel, axis=1)
-            scores_new = jnp.take(scores, rel, axis=1)
-            mask_new = (jnp.take(mask, rel, axis=1) if multi
-                        else jnp.take(mask, rel))
-            gstate_new = permute_state(gstate, rel)
-            order_new = jnp.take(order, rel)
-            out = (bins_new, scores_new, mask_new, gstate_new, order_new)
-            for b in bank:   # DART leaf bank [T, N]: per-row, last axis
-                out += (jnp.take(b, rel, axis=1),)
-        return out
+            # DART's leaf bank [T, N] is per-row on its last axis too
+            (bins, scores, mask, order, *bank), gstate = _resort_rows(
+                (jnp.logical_not(key),),
+                [bins, scores, mask, order, *bank], gstate, row_state)
+        return (bins, scores, mask, gstate, order, *bank)
     return arrange
 
 
-def _make_bag_arrange(permute_state, multi, with_bank):
+def _make_bag_arrange(row_state, multi, with_bank):
     # gstate is NOT donated (first arrangement aliases the objective's
     # own arrays); everything else is replaced by its permuted successor
     donate = (0, 1, 2, 4) + ((5,) if with_bank else ())
-    return jax.jit(_bag_arrange_body(permute_state, multi),
+    return jax.jit(_bag_arrange_body(row_state, multi),
                    donate_argnums=donate)
 
 
-def _make_bag_arrange_sharded(permute_state, multi, mesh, gstate_specs):
+def _make_bag_arrange_sharded(row_state, multi, mesh, gstate_specs):
     """The arrangement under shard_map: each shard sorts ITS OWN rows
     in-bag-first (rel is computed from the shard-local mask), so shard
     membership never changes and the grow step's psum invariants hold —
@@ -788,7 +820,7 @@ def _make_bag_arrange_sharded(permute_state, multi, mesh, gstate_specs):
 
     from ..parallel.mesh import DATA_AXIS, shard_map
 
-    body = _bag_arrange_body(permute_state, multi)
+    body = _bag_arrange_body(row_state, multi)
     row = P(DATA_AXIS)
     row2 = P(None, DATA_AXIS)
     mspec = row2 if multi else row
@@ -1580,6 +1612,8 @@ class GBDT:
                (cfg.hist_agg, self.grower.num_shards,
                 id(self.grower.mesh)) if self.grower is not None else None)
 
+        row_state = self.objective.make_row_state_fn()
+
         def make():
             grow_kw = self._grow_kw()
             if self.grower is not None:
@@ -1595,13 +1629,12 @@ class GBDT:
                     self.objective.make_grad_fn(), grow_kw, lr,
                     self.dtype, self.grower.mesh,
                     len(self.valid_bins_dev),
-                    self._fused_gspecs(gstate), reorder,
-                    self.objective.make_permute_fn(), compact, k_iters)
+                    self._fused_gspecs(gstate), reorder, row_state,
+                    compact, k_iters)
             return _make_fused_step_multi(self.objective.make_grad_fn(),
                                           grow_kw, lr, self.dtype,
-                                          reorder,
-                                          self.objective.make_permute_fn(),
-                                          compact, k_iters)
+                                          reorder, row_state, compact,
+                                          k_iters)
 
         fn = _get_fused_step(key, make)
         with TraceAnnotation(spans.HOST_INPUTS):
@@ -2016,24 +2049,25 @@ class GBDT:
         order = (self._row_order if self._row_order is not None
                  else self._identity_order_dev())
         bank = self._dart_bank_rows()
+        row_state = self.objective.make_row_state_fn()
         key = ("bag_arrange", multi, bank is not None,
                self.objective.fused_key(), self.dtype,
                id(self.grower.mesh) if self._fused_sharded else None)
 
         def make():
-            permute_state = self.objective.make_permute_fn()
             if self._fused_sharded:
                 return _make_bag_arrange_sharded(
-                    permute_state, multi, self.grower.mesh,
+                    row_state, multi, self.grower.mesh,
                     self._fused_gspecs(gstate))
-            return _make_bag_arrange(permute_state, multi,
-                                     bank is not None)
+            return _make_bag_arrange(row_state, multi, bank is not None)
 
         fn = _get_fused_step(key, make)
         args = (self.bins_dev, self.scores, mask, gstate, order)
         if bank is not None:
             args += (bank,)
-        with _enqueue("arrange", 0, self._shards):
+        with _enqueue("arrange", 0, self._shards,
+                      **_resort_counts(args[:3] + args[4:], gstate,
+                                       row_state)):
             out = fn(*args)
         self.bins_dev, self.scores, mask_new, gstate_new, order_new = \
             out[:5]
@@ -2078,6 +2112,8 @@ class GBDT:
                (cfg.hist_agg, self.grower.num_shards,
                 id(self.grower.mesh)) if self._fused_sharded else None)
 
+        row_state = self.objective.make_row_state_fn()
+
         def make():
             grow_kw = self._grow_kw()
             if self._fused_sharded:
@@ -2093,13 +2129,12 @@ class GBDT:
                     self.objective.make_grad_fn(), grow_kw, lr,
                     self.dtype, self.grower.mesh,
                     len(self.valid_bins_dev),
-                    self._fused_gspecs(gstate), reorder,
-                    self.objective.make_permute_fn(), compact, k_iters)
+                    self._fused_gspecs(gstate), reorder, row_state,
+                    compact, k_iters)
             if reorder:
                 return _make_fused_step_reorder(
                     self.objective.make_grad_fn(), grow_kw, lr,
-                    self.dtype, self.objective.make_permute_fn(),
-                    compact, k_iters)
+                    self.dtype, row_state, compact, k_iters)
             return _make_fused_step(self.objective.make_grad_fn(),
                                     grow_kw, lr, self.dtype, compact,
                                     k_iters)
@@ -2117,7 +2152,10 @@ class GBDT:
                 bag_mask_dev = _unpack_bag_jit(bag_mask_dev, self.n_pad)
             order = (self._row_order if self._row_order is not None
                      else self._identity_order_dev())
-            with _enqueue("resort", k_iters, self._shards):
+            with _enqueue("resort", k_iters, self._shards,
+                          **_resort_counts(
+                              [self.bins_dev, self.scores, bag_mask_dev,
+                               order], gstate, row_state)):
                 (scores, valid, ints, floats, bins_new, bag_new,
                  gstate_new, order_new, self._dev_stopped) = fn(
                     self.scores, list(self.valid_scores), bag_mask_dev,
@@ -3195,9 +3233,12 @@ class GBDT:
                          for a, sp in zip(host, specs))
         if not getattr(self.objective, "row_permutable", False):
             return None
-        gs = self.objective.make_permute_fn()(
-            self.objective.grad_state(),
-            jnp.asarray(np.asarray(ordl), dtype=jnp.int32))
+        # a restored order is any permutation, not a sort's: the rows
+        # follow it by a gather each, once a load
+        rel = jnp.asarray(np.asarray(ordl), dtype=jnp.int32)
+        rows, rebuild = self.objective.make_row_state_fn()(
+            self.objective.grad_state())
+        gs = rebuild([jnp.take(a, rel, axis=-1) for a in rows], rel)
         if self._mh_fused:
             gs = jax.tree_util.tree_map(
                 lambda a: self.grower.shard_rows(np.asarray(a),

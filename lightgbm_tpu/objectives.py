@@ -41,10 +41,10 @@ class Objective:
     # may be traced inside a fused training step (models/gbdt.py)
     jax_traceable = False
     # True when grad_state can follow a row reordering (the ordered-
-    # partition mode, models/gbdt.py) via make_permute_fn.  The default
-    # permute treats every leaf as per-row on its last axis; objectives
-    # whose state carries row INDICES (lambdarank's doc_idx) override
-    # make_permute_fn to remap them instead.
+    # partition mode, models/gbdt.py) via make_row_state_fn.  By default
+    # every leaf is per-row on its last axis; objectives whose state
+    # carries row INDICES (lambdarank's doc_idx) override
+    # make_row_state_fn to remap them instead.
     row_permutable = False
     # True when the data-parallel fused step can shard grad_state along
     # the data axis (models/gbdt.py _make_fused_step_sharded).  Two ways
@@ -100,23 +100,28 @@ class Objective:
         raise NotImplementedError
 
     @contract.traced_pure
-    def make_permute_fn(self):
-        """-> pure fn (grad_state, rel) -> grad_state permuted to the
-        new row order (new position j holds old row rel[j]).  Traced
-        inside the fused reorder step (models/gbdt.py), so two
+    def make_row_state_fn(self):
+        """-> pure fn grad_state -> (rows, rebuild): how grad_state
+        follows a row permutation.  `rows` lists the leaves that are
+        per-row on their LAST axis; the caller moves them (new position
+        j holds old row rel[j]) however is cheapest — models/gbdt.py
+        _resort_rows moves the narrow ones together in one gather
+        — and `rebuild(moved_rows, rel)` returns the permuted
+        grad_state.  Traced inside the fused reorder step, so two
         objectives with equal fused_key must return functions that trace
-        identically.  Default: every state leaf is per-row on its last
-        axis (regression/binary/multiclass).
+        identically.  Default: every leaf is per-row (regression/binary/
+        multiclass) and `rel` is not needed.
 
-        This is also the bag-compaction gather hook: the in-bag-first
+        This is also the bag-compaction hook: the in-bag-first
         arrangement (models/gbdt.py _arrange_for_bag) is a stable row
-        permutation, so grad_state follows it through this same function
-        — objectives whose state carries row indices (lambdarank's
-        doc_idx) remap them here and need nothing extra for compaction."""
-        def permute(gstate, rel):
-            return jax.tree_util.tree_map(
-                lambda a: jnp.take(a, rel, axis=-1), gstate)
-        return permute
+        permutation, so grad_state follows it the same way — objectives
+        whose state carries row indices (lambdarank's doc_idx) remap
+        them in `rebuild` and need nothing extra for compaction."""
+        def row_state(gstate):
+            rows, treedef = jax.tree_util.tree_flatten(gstate)
+            return rows, lambda moved, rel: jax.tree_util.tree_unflatten(
+                treedef, moved)
+        return row_state
 
     def bag_rows_bound(self, bagging_fraction: float) -> int:
         """Deterministic upper bound on the in-bag ROW count of any
@@ -427,12 +432,12 @@ class LambdarankNDCG(Objective):
         # per-row row_slot array (every other state leaf is row-POSITION
         # free), so the ordered-partition mode may permute rows: row_slot
         # rides along and doc_idx remaps through the inverse permutation
-        # (make_permute_fn)
+        # (make_row_state_fn)
         self.row_permutable = self.impl == "device"
         # ... and the data-parallel fused step may shard it: rows shard
         # query-granularly (shard_layout below), each shard's query
         # blocks carry SHARD-LOCAL doc indices, and the same grad_fn /
-        # permute_fn run unchanged per shard inside shard_map
+        # row_state fn run unchanged per shard inside shard_map
         self.row_shardable = self.impl == "device"
 
     # -- device path ---------------------------------------------------
@@ -512,18 +517,20 @@ class LambdarankNDCG(Objective):
         return self._dev_state
 
     @contract.traced_pure
-    def make_permute_fn(self):
+    def make_row_state_fn(self):
         """Row permutation support (ordered-partition mode): row_slot is
         per-row and rides the permutation; doc_idx holds row POSITIONS
         into the score vector, so it remaps through the inverse
         permutation.  Everything else (labels/gains/weights/inv_max_dcg/
         discount) is query-block state, independent of row order."""
-        def permute(gstate, rel):
+        def row_state(gstate):
             di, lab, gain, inv, wts, row_slot, disc = gstate
-            inv_rel = jnp.argsort(rel).astype(jnp.int32)
-            return (inv_rel[di], lab, gain, inv, wts,
-                    jnp.take(row_slot, rel), disc)
-        return permute
+
+            def rebuild(moved, rel):
+                inv_rel = jnp.argsort(rel).astype(jnp.int32)
+                return (inv_rel[di], lab, gain, inv, wts, moved[0], disc)
+            return [row_slot], rebuild
+        return row_state
 
     # -- query-granular sharding (tree_learner=data fused step) --------
     def shard_layout(self, local_shards: int, row_unit: int, mh: bool):
@@ -616,7 +623,7 @@ class LambdarankNDCG(Objective):
         sharded state (load_checkpoint restore): re-sorts are shard-
         local, so each shard's doc_idx remaps through the inverse of its
         own block of the order and row_slot rides the permutation —
-        exactly make_permute_fn per shard, done in numpy before the
+        exactly make_row_state_fn per shard, done in numpy before the
         device put."""
         di, lab, gain, inv, wts, row_slot, disc = host
         S, cap = layout.local_shards, layout.cap

@@ -18,6 +18,7 @@ import re
 
 import jax
 import numpy as np
+import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.models import gbdt
@@ -54,7 +55,16 @@ def _steps_of_a_training_job(monkeypatch):
     return steps
 
 
-def test_no_step_copies_the_bin_matrix(monkeypatch):
+@pytest.fixture
+def traces_forgotten():
+    """Traces made for a pretended TPU backend must not outlive the test:
+    the next test of this process that trains would find grow_tree's
+    uninterpreted kernels in jax's caches."""
+    yield
+    jax.clear_caches()
+
+
+def test_no_step_copies_the_bin_matrix(monkeypatch, traces_forgotten):
     steps = _steps_of_a_training_job(monkeypatch)
     assert len(steps) == 2, len(steps)      # the re-sort step, a K=2 scan
     # trace anew as the chip would: grow_tree asks the backend whether to

@@ -550,7 +550,7 @@ def test_ordered_mode_bagged_matches_default():
 def test_ordered_mode_lambdarank_matches_default():
     """Round 5: lambdarank is row_permutable — its row_slot map rides
     the ordered-partition permutation and doc_idx remaps through the
-    inverse (objectives.LambdarankNDCG.make_permute_fn), so ranking
+    inverse (objectives.LambdarankNDCG.make_row_state_fn), so ranking
     gets the leaf-clustered block sweeps every other family has.  Trees
     must match the never-reordered run exactly."""
     import lightgbm_tpu as lgb

@@ -14,6 +14,7 @@ import re
 import jax
 import numpy as np
 import pytest
+from test_resort_rows import _forget_steps
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.models import gbdt
@@ -63,12 +64,10 @@ def _train(extra, classes, valid, rounds):
     return lgb.train(params, train, num_boost_round=rounds, valid_sets=sets)
 
 
-@pytest.mark.parametrize("path", sorted(PATHS))
-def test_step_carries_its_scopes(path, monkeypatch):
-    """The lowered text of every executable the path dispatches holds
-    the scopes the path must have (a scope is HLO metadata: what is in
-    the lowering is what a device trace shows)."""
-    extra, classes, valid, more = PATHS[path]
+def _lowered_texts(path, monkeypatch):
+    """Three rounds of the path; -> the lowered text of every executable
+    it dispatched."""
+    extra, classes, valid, _ = PATHS[path]
     texts = []
     cached = gbdt._get_fused_step
 
@@ -82,9 +81,46 @@ def test_step_carries_its_scopes(path, monkeypatch):
 
     monkeypatch.setattr(gbdt, "_get_fused_step", lowering_too)
     _train(extra, classes, valid, rounds=3)
-    found = set(SCOPE_RE.findall("\n".join(texts)))
+    return "\n".join(texts)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_step_carries_its_scopes(path, monkeypatch):
+    """The lowered text of every executable the path dispatches holds
+    the scopes the path must have (a scope is HLO metadata: what is in
+    the lowering is what a device trace shows)."""
+    more = PATHS[path][3]
+    found = set(SCOPE_RE.findall(_lowered_texts(path, monkeypatch)))
     assert CORE | more <= found, sorted((CORE | more) - found)
     assert found <= set(spans.DEVICE_SCOPES), found
+
+
+@pytest.mark.parametrize("path,scope", [("reorder", spans.RESORT),
+                                        ("bagged", spans.BAG_ARRANGE)])
+def test_resort_helper_lowers_under_its_scope(path, scope, monkeypatch):
+    """Every operation `_resort_rows` makes (the sort, the gather of the
+    stacked words, the wider arrays' gathers, the window's copies, the
+    objective's rebuild) carries the caller's scope: `resort_tree_s`
+    reads all of the re-sort, and `device_unscoped_pct` none of it.  A
+    probe scope inside the helper marks its operations."""
+    real = gbdt._resort_rows
+
+    def probed(*args):
+        with jax.named_scope("resort_rows_probe"):
+            return real(*args)
+
+    monkeypatch.setattr(gbdt, "_resort_rows", probed)
+    _forget_steps()
+    try:
+        text = _lowered_texts(path, monkeypatch)
+    finally:
+        _forget_steps()
+    marked = [name for name in re.findall(r'loc\("([^"]*)"', text)
+              if "resort_rows_probe" in name]
+    assert any(name.endswith("/sort") for name in marked), marked
+    strays = [name for name in marked
+              if "/%s/resort_rows_probe/" % scope not in name]
+    assert not strays, strays
 
 
 def test_no_name_outside_the_registry():
@@ -145,6 +181,15 @@ def test_spans_count_what_was_trained(traced_and_plain):
     assert {s["kind"] for s in by_name[spans.ENQUEUE]} == {"scan", "resort"}
     assert {s["kind"] for s in by_name[spans.ENQUEUE]} \
         <= set(spans.ENQUEUE_KINDS)
+    # a re-sorting dispatch says what moved in the one gather of words
+    # (scores, bag, row order, the binary objective's two arrays) and
+    # what followed the permutation by a gather of its own (the bins);
+    # no other dispatch has the stats
+    for s in by_name[spans.ENQUEUE]:
+        if s["kind"] == "resort":
+            assert (s["carried"], s["taken"]) == (5, 1), s
+        else:
+            assert "carried" not in s and "taken" not in s, s
     assert sum(s["trees"] for s in by_name[spans.FLUSH]) == ROUNDS
     assert all(s["bytes"] > 0 for s in by_name[spans.FLUSH])
     assert len(by_name[spans.FLUSH_PULL]) == len(by_name[spans.FLUSH])
@@ -152,6 +197,18 @@ def test_spans_count_what_was_trained(traced_and_plain):
         s["iter"] for s in by_name[spans.SEGMENT])
     assert spans.HOST_INPUTS in by_name and spans.EVAL in by_name
     assert len(booster._gbdt.models) == ROUNDS
+
+
+def test_bag_arrangement_says_what_moved_together(tmp_path):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        _train(PATHS["bagged"][0], 1, 0, rounds=3)
+    arranges = [s for name, s in _program_spans(str(tmp_path))
+                if name == spans.ENQUEUE and s["kind"] == "arrange"]
+    assert len(arranges) == 3       # bagging_freq=1: one a round
+    # the [1, N] scores row, the mask, the order, sign and label_weight
+    assert all((s["carried"], s["taken"]) == (5, 1) for s in arranges)
 
 
 def test_profiler_changes_no_bit(traced_and_plain):

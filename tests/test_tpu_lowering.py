@@ -9,9 +9,14 @@ only a chip can say — the Mosaic back end, VMEM, numerics — is
 chip_smoke.py's job.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
+from test_bins_in_place import N as STEP_ROWS
+from test_bins_in_place import (_steps_of_a_training_job,  # noqa: F401
+                                traces_forgotten)
 
 from lightgbm_tpu.models.gbdt import resolve_hist_fused
 from lightgbm_tpu.ops import hist_pallas as hp
@@ -62,6 +67,69 @@ def test_default_path_kernels_lower_for_tpu(f, max_bin):
                              **statics)
         assert "tpu_custom_call" in low.as_text(), fn
         assert not _pads_of_u8(low), (fn, _pads_of_u8(low))
+
+
+def _ops_under(text, scope):
+    """The sorts (as their operand count) and gathers (as the length of
+    their index vector, 0 where it is no vector) of a lowering's
+    StableHLO text under a named scope.  An operation is under the scope
+    if its own location names it, or if it sits in a private function
+    that is called, at any depth, from a site that does."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+
+    def where(line):
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        return names.get(ref.group(1), "") if ref else ""
+
+    ops, calls, func, operands = [], [], None, None
+    for line in text.splitlines():
+        head = re.search(r"func\.func \w+ @(\w+)\(", line)
+        call = re.search(r"call @(\w+)\(", line)
+        if head:
+            func = head.group(1)
+        elif '"stablehlo.sort"(' in line:
+            operands = line.split('"stablehlo.sort"(')[1].split(")")[0]
+        elif operands and line.lstrip().startswith("}) :"):
+            ops.append((func, "sort", operands.count("%"), where(line)))
+            operands = None
+        elif "stablehlo.gather" in line:
+            index = re.search(r"tensor<(\d+)x1xi32>\) ->", line)
+            ops.append((func, "gather", int(index.group(1)) if index else 0,
+                        where(line)))
+        elif call:
+            calls.append((func, call.group(1), where(line)))
+    inside = set()
+    while True:
+        more = {callee for f, callee, loc in calls
+                if scope in loc or f in inside}
+        if more <= inside:
+            break
+        inside |= more
+    return sorted((kind, n) for f, kind, n, loc in ops
+                  if scope in loc or f in inside)
+
+
+def test_resort_step_moves_row_state_in_one_gather(monkeypatch,
+                                                   traces_forgotten):
+    """The re-sort of the default ordered path, lowered for the TPU: ONE
+    sort (the key and the iota) and TWO gathers by its permutation, the
+    bin matrix's and that of the stacked words: the five arrays of the
+    binary objective's step with one row a position (scores, bag, row
+    order, sign, label_weight).  A take of one such array costs 1.7 s at
+    68M rows where the gather of all five costs 1.0 (PERF.md section 6,
+    PR 28), so an array that falls off the stack must show here."""
+    make, shapes = _steps_of_a_training_job(monkeypatch)[0]
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = make().trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert _ops_under(text, "lgbm.resort") == [
+        ("gather", STEP_ROWS), ("gather", STEP_ROWS), ("sort", 2)]
+    stacked = re.findall(r"stablehlo\.gather.*tensor<(\d+)x%dxui32>, "
+                         % STEP_ROWS, text)
+    assert stacked == ["5"], stacked
+    # the reader sees the rest of the step too: the block list's argsort
+    assert ("sort", 2) in _ops_under(text, "lgbm.block_list")
 
 
 @pytest.mark.parametrize("f", [16, 28])
