@@ -218,8 +218,8 @@ def train_one_chip(data: str, trees: int) -> str:
                        "output_model=" + model] + MODEL_ARGS)
     check(rc == 0, "cli.main returned %d" % rc)
     log_text = tee.text()
-    check("hist_impl=pallas hist_fused=off kernels=compiled" in log_text,
-          "start-up line does not say the compiled two-op Pallas path")
+    check("hist_impl=pallas kernels=compiled" in log_text,
+          "start-up line does not say the compiled Pallas path")
     final = check_logloss(logloss_by_iter(log_text), trees)
     print("chip_smoke: train log-loss %.6f -> %.6f over %d trees"
           % (LN2, final, trees), flush=True)

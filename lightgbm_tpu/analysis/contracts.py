@@ -316,10 +316,6 @@ EXPECTED_PARITY_ORACLES: Tuple[str, ...] = (
     "models/gbdt.py::_fused_step_body",
     # the growth kernel under full-length masked bagging
     "ops/grow.py::grow_tree",
-    # the two-op split scan: hist_fused=off reads the materialized
-    # [F, B, 3] histogram through this XLA pass — the bit-parity oracle
-    # the fused Pallas histogram+gain kernel is tested against
-    "ops/split.py::find_best_split",
 )
 
 

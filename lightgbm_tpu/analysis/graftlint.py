@@ -182,10 +182,6 @@ PARITY_MODULES: Set[str] = {
     # loader's bins bit-for-bit (synth.py is OUT on purpose — it
     # generates random benchmark data, not parity artifacts)
     "ingest/manifest.py", "ingest/writer.py", "ingest/shards.py",
-    # the fused histogram+gain kernel: already covered by the ops/
-    # prefix rule, pinned HERE explicitly too — fused-on is bit-parity
-    # with the two-op oracle, so clock/RNG reach would be model drift
-    "ops/hist_pallas.py",
 }
 PARITY_PREFIXES = ("ops/",)
 

@@ -24,8 +24,8 @@ Counted signals:
     probes (jax lru_cache "Cache hit for key" records and the
     compiler's "PERSISTENT COMPILATION CACHE MISS" records).  A fresh
     process of an already-seen shape shows misses == 0: the cross-run
-    zero-compile claim (tests/test_cache_cross_process.py, and
-    bench.py's compile_s cold/cache-warm split).
+    zero-compile claim (tests/test_cache_cross_process.py; the
+    benchmark's setup_compile_s).
   * stats.device_puts / device_gets — explicit jax.device_put /
     jax.device_get calls made through the `jax` module attributes
     (wrapped for the duration).  Implicit transfers are policed by the

@@ -175,14 +175,6 @@ def _t_remove_grow_oracle(src: str) -> str:
                              what="parity_oracle removal from grow_tree")
 
 
-def _t_remove_split_oracle(src: str) -> str:
-    # the SPLIT-path oracle (round 16): hist_fused=off is only an
-    # oracle while find_best_split stays pinned in the registry
-    return _remove_decorator(
-        src, "@contract.parity_oracle(",
-        what="parity_oracle removal from find_best_split")
-
-
 def _t_np_random_in_pack_tree(src: str) -> str:
     return _insert_after(
         src, "def _pack_tree(dev_tree):\n",
@@ -470,13 +462,6 @@ MUTATIONS: Tuple[Mutation, ...] = (
        "removing grow_tree's parity_oracle annotation — the oracle SET "
        "is pinned by EXPECTED_PARITY_ORACLES",
        _t_remove_grow_oracle),
-    _m("split-oracle-annotation-removed", "parity_oracle",
-       "ops/split.py", "GC003", "ops/split.py",
-       "missing its @contract.parity_oracle",
-       "removing find_best_split's parity_oracle annotation — "
-       "hist_fused=off is the fused kernel's bit-parity oracle only "
-       "while the split path stays pinned",
-       _t_remove_split_oracle),
     _m("np-random-in-pack-tree", "parity_oracle", "models/gbdt.py",
        "GC003", "models/gbdt.py", "np.random",
        "np.random inside _pack_tree — reachable from the general-path "

@@ -186,50 +186,12 @@ class Config:
     hist_impl: str = "auto"               # auto | xla | pallas
     hist_agg: str = "psum"                # psum | scatter (tree_learner=data)
     rank_impl: str = "device"             # device | native (lambdarank gradients)
-    hist_compact: str = "off"             # on | off (small-leaf row compaction;
-    #                                       EXPERIMENTAL: measured slower on
-    #                                       current TPUs — XLA gather/scatter
-    #                                       row selection costs more than the
-    #                                       90%-MXU full sweep it avoids)
     hist_ordered: str = "auto"            # auto | off: ordered-partition mode —
     #                                       block-list histogram sweeps + rows
     #                                       re-sorted by the previous tree's
     #                                       leaves every hist_reorder_every
     #                                       trees (serial pallas learner)
     hist_reorder_every: int = 16          # trees between row re-sorts
-    hist_fused: str = "auto"              # auto | on | off: fused Pallas
-    #                                       histogram+gain kernel — the
-    #                                       per-split children sweep runs
-    #                                       the best-split threshold scan
-    #                                       in-register on the VMEM-
-    #                                       resident accumulators instead
-    #                                       of a separate XLA pass over
-    #                                       the [F, B, 3] tensor.  The
-    #                                       fused kernels do not lower
-    #                                       for the TPU under jax 0.9.0,
-    #                                       so auto is the two-op path
-    #                                       and on runs (interpreted) on
-    #                                       the CPU backend only, fatal
-    #                                       elsewhere (models/gbdt.py
-    #                                       resolve_hist_fused); off IS
-    #                                       the two-op oracle — fused on
-    #                                       is bit-parity with it (the
-    #                                       kernel runs the oracle's
-    #                                       exact scan ops)
-    hist_acc: str = "f32"                 # f32 | bf16 | i32: Pallas
-    #                                       histogram accumulator mode.
-    #                                       f32 is the parity default;
-    #                                       bf16 streams gh2/one-hots in
-    #                                       bfloat16 (half the VMEM and
-    #                                       gh2 bandwidth, f32 MXU
-    #                                       accumulate); i32 accumulates
-    #                                       overflow-safe fixed-point
-    #                                       integers (order-independent,
-    #                                       exact counts).  bf16/i32
-    #                                       round the inputs, so both are
-    #                                       OPT-IN behind the f32 parity
-    #                                       gate (serial pallas learner
-    #                                       only)
     bag_compact: str = "auto"             # auto | on | off: bag-compacted fused
     #                                       training — in-bag rows arranged into
     #                                       a contiguous static window at every
@@ -576,11 +538,8 @@ class Config:
         set_str("hist_impl")
         set_str("hist_agg")
         set_str("rank_impl")
-        set_str("hist_compact")
         set_str("hist_ordered")
         set_int("hist_reorder_every")
-        set_str("hist_fused")
-        set_str("hist_acc")
         set_str("bag_compact")
         set_str("iter_batch")
         set_bool("donate_buffers")
@@ -735,24 +694,9 @@ class Config:
         if c.rank_impl not in ("device", "native"):
             log.fatal("Unknown rank_impl %s (expect device|native)"
                       % c.rank_impl)
-        if c.hist_compact not in ("on", "off"):
-            log.fatal("Unknown hist_compact %s (expect on|off)"
-                      % c.hist_compact)
         if c.hist_ordered not in ("auto", "off"):
             log.fatal("Unknown hist_ordered %s (expect auto|off)"
                       % c.hist_ordered)
-        if c.hist_fused not in ("auto", "on", "off"):
-            log.fatal("Unknown hist_fused %s (expect auto|on|off)"
-                      % c.hist_fused)
-        if c.hist_acc not in ("f32", "bf16", "i32"):
-            log.fatal("Unknown hist_acc %s (expect f32|bf16|i32)"
-                      % c.hist_acc)
-        if c.hist_acc != "f32" and c.hist_impl == "xla":
-            log.fatal("hist_acc=%s requires the Pallas histogram kernel "
-                      "(hist_impl=xla was forced)" % c.hist_acc)
-        if c.hist_fused == "on" and c.hist_impl == "xla":
-            log.fatal("hist_fused=on requires the Pallas histogram "
-                      "kernel (hist_impl=xla was forced)")
         if c.ingest_prefetch < 0:
             log.fatal("ingest_prefetch must be >= 0 (0 = synchronous)")
         if c.bag_compact not in ("auto", "on", "off"):

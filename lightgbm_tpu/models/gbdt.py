@@ -196,8 +196,8 @@ def _batch_iters(body, spec, k):
 # inside `with _enqueue(kind, k):`, which counts the dispatch and opens
 # the profiler's lgbm.enqueue host span (utils/spans.py) in one place, so
 # the count and the spans cannot drift.  dispatch_count() is read by the
-# benchmark's trees_per_dispatch and by bench.py.  A host counter, not a
-# guard — analysis/guards.py counts the transfers.
+# benchmark's trees_per_dispatch.  A host counter, not a guard —
+# analysis/guards.py counts the transfers.
 _DISPATCHES = 0
 
 
@@ -829,33 +829,6 @@ def _make_bag_arrange_sharded(row_state, multi, mesh, gstate_specs):
     return jax.jit(fn, donate_argnums=(0, 1, 2, 4))
 
 
-def resolve_hist_fused(setting: str, impl: str, platform: str) -> bool:
-    """config.hist_fused -> whether grow_tree runs the *_fused kernels.
-
-    The fused epilogue does not lower for the TPU under jax 0.9.0: the
-    gain scan uses `rev`, `cumsum`, `scatter` and a gather the Pallas
-    TPU lowering does not implement, and the rank-1 `fmask` block is
-    neither the array's length nor a multiple of 128
-    (tests/test_tpu_lowering.py pins the refusal).  So `auto` is the
-    two-op path everywhere, and `on` is honoured only where the
-    kernels interpret (the CPU backend, for the bit-parity tests).
-    Chosen by rule at config time: no compile is attempted and caught.
-    Whoever makes the fused kernels lower flips `auto` back here."""
-    if setting != "on":
-        return False
-    if impl != "pallas":
-        log.fatal("hist_fused=on requires the Pallas histogram "
-                  "kernel (hist_impl resolved to %s)" % impl)
-    if platform != "cpu":
-        log.fatal("hist_fused=on: the fused histogram+gain kernels do "
-                  "not lower for platform=%s under jax 0.9.0 "
-                  "(unimplemented in the Pallas TPU lowering: rev, "
-                  "cumsum, scatter, gather; rank-1 fmask block); use "
-                  "hist_fused=auto, which runs the two-op path"
-                  % platform)
-    return True
-
-
 class GBDT:
     name = "gbdt"
 
@@ -942,41 +915,8 @@ class GBDT:
             if train_data.bin_dtype != np.uint8:
                 log.fatal("hist_impl=pallas requires uint8 bins")
             row_unit = PALLAS_ROW_BLOCK
-        # fused histogram+gain kernel (config.hist_fused, see
-        # resolve_hist_fused; ops/grow.py additionally gates fusion to
-        # the serial child sweeps — the parallel learners must cross
-        # shards between build and scan) and Pallas accumulator mode
-        # (config.hist_acc).
-        self.hist_acc = config.hist_acc
-        if self.hist_acc != "f32":
-            if impl != "pallas":
-                log.fatal("hist_acc=%s requires the Pallas histogram "
-                          "kernel (hist_impl resolved to %s)"
-                          % (self.hist_acc, impl))
-            if config.tree_learner != "serial":
-                log.fatal("hist_acc=%s is serial-learner only (the "
-                          "mesh growers keep the f32 parity "
-                          "accumulators)" % self.hist_acc)
-        self.hist_fused = resolve_hist_fused(config.hist_fused, impl,
-                                             platform)
-        if config.hist_fused == "on" and config.hist_compact == "on":
-            # same perf-expectation class as the learner warning below:
-            # the compaction path gathers its own rows and keeps the
-            # two-op scan, so forcing fusion next to it does nothing
-            log.warning("hist_fused=on: the small-leaf compaction path "
-                        "(hist_compact=on) gathers its own row buffers "
-                        "and keeps the two-op scan — fusion disengages")
-        if config.hist_fused == "on" and config.tree_learner != "serial":
-            # a warning, not a fatal (unlike hist_acc): the two-op path
-            # the parallel learners keep is BIT-identical to the fused
-            # one — only the perf expectation is wrong, not the numbers
-            log.warning("hist_fused=on: the fused histogram+gain scan "
-                        "is serial-learner only (the parallel learners "
-                        "must cross shards between build and scan); "
-                        "tree_learner=%s keeps the two-op path"
-                        % config.tree_learner)
-        log.info("Histograms: hist_impl=%s hist_fused=%s kernels=%s"
-                 % (impl, "on" if self.hist_fused else "off",
+        log.info("Histograms: hist_impl=%s kernels=%s"
+                 % (impl,
                     "xla" if impl != "pallas"
                     else "interpret" if platform == "cpu"
                     else "compiled"))
@@ -1102,19 +1042,6 @@ class GBDT:
             self.n_pad = ((n_for_pad + row_unit - 1) // row_unit) \
                 * row_unit
 
-        # small-leaf row compaction (ops/grow.py hist_small): serial
-        # learner only, f32 only — the f64 parity configuration keeps the
-        # full-sweep accumulation grouping the golden logs pin.
-        # EXPERIMENTAL opt-in: the XLA gather/scatter row selection
-        # costs more per split than the full sweep it avoids (4.5x
-        # slower at 1Mx28 in the r05-era capture; ROADMAP D3 deletes it)
-        self.hist_compact = 0
-        if (config.hist_compact == "on" and self.grower is None
-                and self.dtype == jnp.float32):
-            half = max(self.n_pad // 2, 1)
-            self.hist_compact = ((half + row_unit - 1)
-                                 // row_unit) * row_unit
-
         # ordered-partition growth (pallas learner, serial or single-host
         # data-parallel): block-list sweeps are always on (bit-identical
         # to full sweeps for a fixed row order — empty blocks contribute
@@ -1127,10 +1054,6 @@ class GBDT:
                             and impl == "pallas"
                             and (self.grower is None
                                  or self._fused_sharded))
-        if config.hist_compact == "on" and self.hist_ranged:
-            log.warning("hist_compact=on disables hist_ordered "
-                        "(mutually exclusive row-selection strategies)")
-            self.hist_ranged = False
         self.reorder_every = max(int(config.hist_reorder_every), 1)
         self._row_order = None        # [n_pad] i32 device; None = identity
         self._inv_order = None        # cached device inverse of the above
@@ -1492,9 +1415,7 @@ class GBDT:
         return dict(max_leaves=max(cfg.num_leaves, 2),
                     max_bin=self.max_bin, params=self.params,
                     max_depth=cfg.max_depth, hist_impl=self.hist_impl,
-                    hist_slots=self.hist_slots, compact=self.hist_compact,
-                    ranged=self.hist_ranged, fused=self.hist_fused,
-                    hist_acc=self.hist_acc)
+                    hist_slots=self.hist_slots, ranged=self.hist_ranged)
 
     def _bag_mask_dev(self, cls: int):
         """Device/sharded bag mask, uploaded only when bagging changed it."""
@@ -1606,8 +1527,7 @@ class GBDT:
         key = ("multi", self.objective.fused_key(), lr, self.dtype,
                self.hist_impl, self.max_bin, max(cfg.num_leaves, 2),
                cfg.max_depth, self.params, len(self.valid_bins_dev),
-               self.hist_slots, self.hist_compact, self.hist_ranged,
-               self.hist_fused, self.hist_acc,
+               self.hist_slots, self.hist_ranged,
                reorder, compact, k_iters,
                (cfg.hist_agg, self.grower.num_shards,
                 id(self.grower.mesh)) if self.grower is not None else None)
@@ -1934,11 +1854,6 @@ class GBDT:
                 or not self._compact_fusible()
                 or not getattr(self.objective, "row_permutable", False)):
             return 0
-        if self.hist_compact:
-            if cfg.bag_compact == "on":
-                log.warning("hist_compact=on disables bag_compact "
-                            "(mutually exclusive row strategies)")
-            return 0
         if cfg.bag_compact == "auto":
             # auto keeps the f64 parity configuration on the masked
             # full-sweep oracle and skips fractions too close to 1
@@ -2103,8 +2018,7 @@ class GBDT:
         key = (self.objective.fused_key(), lr, self.dtype,
                self.hist_impl, self.max_bin, max(cfg.num_leaves, 2),
                cfg.max_depth, self.params, len(self.valid_bins_dev),
-               self.hist_slots, self.hist_compact, self.hist_ranged,
-               self.hist_fused, self.hist_acc,
+               self.hist_slots, self.hist_ranged,
                reorder, compact, k_iters,
                # sharded steps close over the mesh and the aggregation
                # protocol — two data-parallel configs that differ only
@@ -3469,8 +3383,7 @@ class DART(GBDT):
         key = ("dart", self.objective.fused_key(), self.dtype,
                self.hist_impl, self.max_bin, L, cfg.max_depth,
                self.params, len(self.valid_bins_dev), self.hist_slots,
-               self.hist_compact, self.hist_ranged, self.hist_fused,
-               self.hist_acc, dp, compact, k_iters)
+               self.hist_ranged, dp, compact, k_iters)
 
         def make():
             grow_kw = self._grow_kw()

@@ -44,8 +44,8 @@ from ..analysis.contracts import contract
 from ..utils import spans
 from .histogram import leaf_histogram, make_gvals
 from .predict import predict_leaf_binned
-from .split import (BestSplit, SplitParams, find_best_split,
-                    find_best_split_fused, K_MIN_SCORE, per_feature_best)
+from .split import (BestSplit, SplitParams, find_best_split, K_MIN_SCORE,
+                    per_feature_best)
 
 
 class TreeArrays(NamedTuple):
@@ -156,8 +156,7 @@ def _reduce_best_over_features(s: BestSplit, f_offset, feature_axis: str
     static_argnames=("max_leaves", "max_bin", "params", "max_depth",
                      "row_chunk", "psum_axis", "feature_axis",
                      "voting_top_k", "hist_impl", "hist_agg", "num_shards",
-                     "hist_slots", "compact", "ranged", "fused",
-                     "hist_acc"))
+                     "hist_slots", "ranged"))
 def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
               bag_mask: jax.Array, feature_mask: jax.Array, *,
               max_leaves: int, max_bin: int, params: SplitParams,
@@ -166,24 +165,16 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
               feature_axis: Optional[str] = None,
               voting_top_k: int = 0, hist_impl: str = "xla",
               hist_agg: str = "psum", num_shards: int = 0,
-              hist_slots: int = 0, compact: int = 0, ranged: bool = False,
-              fused: bool = False, hist_acc: str = "f32"):
+              hist_slots: int = 0, ranged: bool = False):
     """Grow one leaf-wise tree. Returns (TreeArrays, leaf_id [N] i32).
 
     bins_t [F, N] uint8; grad/hess [N]; bag_mask [N] bool;
     feature_mask [F] bool. All per-split control flow is on-device.
     hist_impl: "xla" (portable one-hot matmul) or "pallas" (TPU radix
     kernel, f32, max_bin<=256, N % 8192 == 0).
-    fused (pallas, serial only — config.hist_fused): per-split child
-    sweeps run the fused histogram+gain kernels, which scan thresholds
-    in-register on the VMEM-resident accumulators and emit per-feature
-    best rows; find_best_split_fused finishes with an O(F) argmax.
-    Bit-parity with fused=False (the retained two-op oracle) in
-    interpret mode — the kernel runs the oracle's exact jnp scan on the
-    exact accumulator values.
-    hist_acc (pallas): "f32" (default, parity), "bf16" (bf16 operands /
-    gh2 stream, f32 accumulate), "i32" (overflow-safe fixed-point
-    integer accumulation, exact counts) — see hist_pallas.make_gh2_acc.
+    ranged (pallas): per split, sweep only the row blocks that hold the
+    target leaf's rows (leaf_histogram_blocklist) instead of every block
+    (leaf_histogram_masked); bit-identical for the same row order.
     psum_axis: mesh axis sharding rows (tree_learner=data).
     hist_slots (>0): bound histogram HBM to hist_slots live [F, B, 3]
     leaf histograms — the reference HistogramPool's role
@@ -310,25 +301,12 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
 
     ranged_on = (ranged and hist_impl == "pallas"
                  and feature_axis is None)
-    # fused histogram+gain path (round 16, config.hist_fused): the
-    # per-split children sweep through the *_fused Pallas kernels, which
-    # run the best-split scan in-register on the VMEM-resident
-    # accumulators and emit per-feature best rows — the two XLA
-    # _split_scan passes per split disappear.  Serial-only: under
-    # psum/scatter/voting/feature the histogram must cross shards BEFORE
-    # the scan, and the small-leaf compaction path gathers its own rows.
-    fused_on = (fused and hist_impl == "pallas" and psum_axis is None
-                and feature_axis is None and not voting and not scatter
-                and compact <= 0)
     if hist_impl == "pallas":
         from .hist_pallas import (PALLAS_ROW_BLOCK, fold_leaf_mask,
                                   leaf_histogram_blocklist,
-                                  leaf_histogram_blocklist_fused,
-                                  leaf_histogram_masked,
-                                  leaf_histogram_masked_fused,
-                                  make_gh2_acc)
+                                  leaf_histogram_masked, make_gh2)
         with jax.named_scope(spans.HIST_SWEEP):
-            gh2, inv_scale = make_gh2_acc(grad, hess, hist_acc)
+            gh2 = make_gh2(grad, hess)
         # TPU runs the compiled kernel; CPU (tests) uses interpret mode
         interpret = jax.default_backend() == "cpu"
     if ranged_on:
@@ -380,7 +358,6 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 def branch(le, bl, na):
                     return leaf_histogram_blocklist(
                         bins_t, gh2, le, target, bl, na, max_bin=max_bin,
-                        hist_acc=hist_acc, inv_scale=inv_scale,
                         grid_blocks=g, interpret=interpret).astype(dtype)
                 return branch
 
@@ -388,45 +365,14 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 h = jax.lax.switch(sel, [mk(g) for g in ladder],
                                    leaf_eff, blist, n_occ)
             return hist_psum(h)
-
-        if fused_on:
-            def hist_best(leaf_id, target, parent_hist, s_stats, l_stats):
-                with jax.named_scope(spans.BLOCK_LIST):
-                    leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-                    blist, n_occ, sel = _block_plan(leaf_eff, target)
-
-                def mk(g):
-                    def branch(le, bl, na):
-                        h, pfs, pfl = leaf_histogram_blocklist_fused(
-                            bins_t, gh2, le, target, bl, na, parent_hist,
-                            feature_mask, s_stats, l_stats, inv_scale,
-                            max_bin=max_bin, params=params,
-                            hist_acc=hist_acc, grid_blocks=g,
-                            interpret=interpret)
-                        return h.astype(dtype), pfs, pfl
-                    return branch
-
-                return jax.lax.switch(sel, [mk(g) for g in ladder],
-                                      leaf_eff, blist, n_occ)
     elif hist_impl == "pallas":
         def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
             with jax.named_scope(scope):
                 leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
                 h = leaf_histogram_masked(
                     bins_t, gh2, leaf_eff, target, max_bin=max_bin,
-                    hist_acc=hist_acc, inv_scale=inv_scale,
                     interpret=interpret).astype(dtype)
             return hist_psum(h)
-
-        if fused_on:
-            def hist_best(leaf_id, target, parent_hist, s_stats, l_stats):
-                leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-                h, pfs, pfl = leaf_histogram_masked_fused(
-                    bins_t, gh2, leaf_eff, target, parent_hist,
-                    feature_mask, s_stats, l_stats, inv_scale,
-                    max_bin=max_bin, params=params, hist_acc=hist_acc,
-                    interpret=interpret)
-                return h.astype(dtype), pfs, pfl
     else:
         def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
             with jax.named_scope(scope):
@@ -435,75 +381,6 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 h = leaf_histogram(bins_t, gv, max_bin=max_bin,
                                    row_chunk=row_chunk)
             return hist_psum(h)
-
-    # -- compacted small-leaf histograms (serial fast path) ------------
-    # Full-row sweeps dominate the fused iteration (~90% in the r05-era
-    # profile), and every split sweeps all N rows for the SMALLER child
-    # (O(N*num_leaves) row-touches per tree vs the reference's O(N*depth)
-    # leaf-row partitions, data_partition.hpp).  Here the smaller child's
-    # in-bag rows are compacted (order-preserving cumsum scatter, so
-    # accumulation order matches the full sweep's row order) into the
-    # smallest of a static capacity ladder [~N/2, /4, /16, /64] and only
-    # that buffer is swept — near-leaf-proportional MXU work with static
-    # shapes via lax.switch.  The top capacity can never overflow: the
-    # smaller-by-bagged-count child has <= floor(bagged_n/2) <= n/2 rows.
-    # Serial-only (a shard-local count could exceed a local capacity and
-    # branch divergence would break SPMD collective pairing).
-    compact_on = (compact > 0 and psum_axis is None
-                  and feature_axis is None and not ranged_on)
-    if compact_on:
-        row_unit = 1
-        if hist_impl == "pallas":
-            from .hist_pallas import PALLAS_ROW_BLOCK
-            row_unit = PALLAS_ROW_BLOCK
-
-        def _round_up(x):
-            return max(1, -(-x // row_unit)) * row_unit
-
-        caps = [_round_up(compact)]
-        while caps[-1] // 4 >= row_unit and len(caps) < 4:
-            caps.append(_round_up(caps[-1] // 4))
-
-        def _compact_idx(mask):
-            pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-            slot = jnp.where(mask & (pos < caps[0]), pos, caps[0])
-            buf = jnp.zeros(caps[0] + 1, jnp.int32).at[slot].set(
-                jnp.arange(n, dtype=jnp.int32))
-            return buf[:caps[0]]
-
-        if hist_impl == "pallas":
-            def _hist_rows(idx, cnt, cap):
-                bins_c = jnp.take(bins_t, idx[:cap], axis=1)
-                gh2_c = jnp.take(gh2, idx[:cap], axis=1)
-                leaf_c = jnp.where(jnp.arange(cap) < cnt, 0, -1) \
-                    .astype(jnp.int32)
-                return leaf_histogram_masked(
-                    bins_c, gh2_c, leaf_c, jnp.int32(0),
-                    max_bin=max_bin, hist_acc=hist_acc,
-                    inv_scale=inv_scale,
-                    interpret=interpret).astype(dtype)
-        else:
-            def _hist_rows(idx, cnt, cap):
-                bins_c = jnp.take(bins_t, idx[:cap], axis=1)
-                gv = make_gvals(jnp.take(grad, idx[:cap]),
-                                jnp.take(hess, idx[:cap]),
-                                jnp.arange(cap) < cnt, dtype)
-                return leaf_histogram(bins_c, gv, max_bin=max_bin,
-                                      row_chunk=row_chunk)
-
-        def hist_small(leaf_id, target, cnt):
-            mask = (leaf_id == target) & bag_mask
-            idx = _compact_idx(mask)
-            # smallest capacity that fits cnt (capacities descend)
-            sel = jnp.int32(0)
-            for b, cap in enumerate(caps[1:], start=1):
-                sel = jnp.where(cnt <= cap, jnp.int32(b), sel)
-            branches = [functools.partial(_hist_rows, cap=cap)
-                        for cap in caps]
-            return jax.lax.switch(sel, branches, idx, cnt)
-    else:
-        def hist_small(leaf_id, target, cnt):
-            return hist_leaf(leaf_id, target)
 
     def packed_best(best, depth):
         """The depth gate (no split at max_depth) and the packing."""
@@ -638,8 +515,6 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         with jax.named_scope(spans.HIST_POOL):
             left_is_smaller = si[BI_LCNT] <= si[BI_RCNT]
             small_leaf = jnp.where(left_is_smaller, bl, right)
-            small_cnt = jnp.where(left_is_smaller, si[BI_LCNT],
-                                  si[BI_RCNT])
             if pooled:
                 # parent histogram from its pool slot, or a full recompute
                 # when it was LRU-evicted (the reference recomputes evicted
@@ -653,21 +528,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
             else:
                 parent_hist = st.hist[bl]
         with jax.named_scope(spans.HIST_SWEEP):
-            if fused_on:
-                # fused sweep + in-register gain scan: the kernel consumes
-                # the parent block, sweeps the small child, and emits both
-                # children's per-feature best rows alongside the histogram
-                s_g = jnp.where(left_is_smaller, sf[BF_LG], sf[BF_RG])
-                s_h = jnp.where(left_is_smaller, sf[BF_LH], sf[BF_RH])
-                l_g = jnp.where(left_is_smaller, sf[BF_RG], sf[BF_LG])
-                l_h = jnp.where(left_is_smaller, sf[BF_RH], sf[BF_LH])
-                large_cnt = jnp.where(left_is_smaller, si[BI_RCNT],
-                                      si[BI_LCNT])
-                small_hist, pf_small, pf_large = hist_best(
-                    leaf_id, small_leaf, parent_hist,
-                    (small_cnt, s_g, s_h), (large_cnt, l_g, l_h))
-            else:
-                small_hist = hist_small(leaf_id, small_leaf, small_cnt)
+            small_hist = hist_leaf(leaf_id, small_leaf)
         with jax.named_scope(spans.HIST_POOL):
             large_hist = parent_hist - small_hist
             left_hist = jnp.where(left_is_smaller, small_hist, large_hist)
@@ -714,20 +575,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         # --- best splits for the two children ---
         with jax.named_scope(spans.GAIN_SCAN):
             child_depth = new_tree.leaf_depth[bl]
-            if fused_on:
-                # finish from the kernel's per-feature rows: a tiny argmax
-                # over [F, 8] instead of two full [F, B, 3] scan passes
-                lpf = jnp.where(left_is_smaller, pf_small, pf_large)
-                rpf = jnp.where(left_is_smaller, pf_large, pf_small)
-                lbest = find_best_split_fused(lpf, sf[BF_LG], sf[BF_LH],
-                                              params)
-                rbest = find_best_split_fused(rpf, sf[BF_RG], sf[BF_RH],
-                                              params)
-            else:
-                lbest = best_of(left_hist, si[BI_LCNT], sf[BF_LG],
-                                sf[BF_LH])
-                rbest = best_of(right_hist, si[BI_RCNT], sf[BF_RG],
-                                sf[BF_RH])
+            lbest = best_of(left_hist, si[BI_LCNT], sf[BF_LG], sf[BF_LH])
+            rbest = best_of(right_hist, si[BI_RCNT], sf[BF_RG], sf[BF_RH])
             lbf, lbi = packed_best(lbest, child_depth)
             rbf, rbi = packed_best(rbest, child_depth)
             best_f = st.best_f.at[wl].set(lbf).at[wr].set(rbf)

@@ -30,19 +30,8 @@ enable_compilation_cache()   # before any jit traces (was a package-import side 
 import jax
 import jax.numpy as jnp
 
-from ..analysis.contracts import contract
-
 K_EPSILON = 1e-15
 K_MIN_SCORE = -jnp.inf
-
-# column layout of the per-feature best rows the FUSED Pallas
-# histogram+gain kernel emits (ops/hist_pallas.py): everything
-# find_best_split_fused needs to finish the cross-feature reduction
-# without re-reading the [F, B, 3] histogram tensor.  Counts travel as
-# f32 — exact below 2^24 rows, the same bound the f32 histogram count
-# component already imposes.
-PF_GAIN, PF_T, PF_LG, PF_LH, PF_LCNT, PF_RCNT = range(6)
-PF_COLS = 8   # padded to 8 for a uniform [F, 8] row
 
 
 class SplitParams(NamedTuple):
@@ -148,77 +137,6 @@ def per_feature_best(hist: jax.Array, leaf_count, sum_g, sum_h,
     return _per_feature_argmax(masked_gains)
 
 
-def per_feature_split_rows(hist: jax.Array, leaf_count, sum_g, sum_h,
-                           feature_mask: jax.Array,
-                           params: SplitParams) -> jax.Array:
-    """[F, PF_COLS] per-feature best rows (PF_* layout): the whole
-    threshold scan reduced to one row per feature, so only O(F) scalars
-    leave the histogram buffer.  This is the body the fused Pallas
-    kernel runs in-register on its VMEM-resident accumulators
-    (ops/hist_pallas.py) — the SAME jnp ops as `find_best_split`'s scan,
-    so interpret-mode results are bit-identical to the two-op oracle."""
-    (masked_gains, left_g, left_h, left_cnt, _rg, _rh, right_cnt,
-     _shift) = _split_scan(hist, leaf_count, sum_g, sum_h,
-                           feature_mask, params)
-    best_gain_f, best_t = _per_feature_argmax(masked_gains)
-    tcol = best_t[:, None]
-    f32 = jnp.float32
-    rows = jnp.stack([
-        best_gain_f.astype(f32),
-        best_t.astype(f32),
-        jnp.take_along_axis(left_g, tcol, axis=1)[:, 0].astype(f32),
-        jnp.take_along_axis(left_h, tcol, axis=1)[:, 0].astype(f32),
-        jnp.take_along_axis(left_cnt, tcol, axis=1)[:, 0].astype(f32),
-        jnp.take_along_axis(right_cnt, tcol, axis=1)[:, 0].astype(f32),
-        jnp.zeros_like(best_gain_f, dtype=f32),
-        jnp.zeros_like(best_gain_f, dtype=f32),
-    ], axis=-1)
-    return rows
-
-
-def find_best_split_fused(pf: jax.Array, sum_g: jax.Array,
-                          sum_h: jax.Array,
-                          params: SplitParams) -> BestSplit:
-    """Finish `find_best_split` from the fused kernel's per-feature best
-    rows: a small XLA argmax over features (first max = smaller index,
-    the MaxReducer tie-break) plus the scalar re-derivations the oracle
-    performs on its winner — identical values, so fused-on trees are
-    bit-parity with the two-op oracle."""
-    dt = pf.dtype
-    l1, l2 = params.lambda_l1, params.lambda_l2
-    best_f = jnp.argmax(pf[:, PF_GAIN]).astype(jnp.int32)
-    row = pf[best_f]
-    gain = row[PF_GAIN]
-    t = row[PF_T].astype(jnp.int32)
-    bl_g = row[PF_LG]
-    bl_h = row[PF_LH]
-    bl_c = row[PF_LCNT].astype(jnp.int32)
-    br_c = row[PF_RCNT].astype(jnp.int32)
-    # right sums re-derived from parent totals, exactly the oracle's
-    # bit-parity rule (reference hpp:164-168)
-    br_g = sum_g - bl_g
-    br_h = sum_h - bl_h
-    gain_shift = leaf_split_gain(sum_g, sum_h, l1, l2)
-    return BestSplit(
-        gain=gain - gain_shift,
-        feature=best_f,
-        threshold=t - 1,
-        left_count=bl_c,
-        right_count=br_c,
-        left_sum_g=bl_g.astype(dt),
-        left_sum_h=bl_h.astype(dt),
-        right_sum_g=br_g.astype(dt),
-        right_sum_h=br_h.astype(dt),
-        left_output=leaf_output(bl_g, bl_h, l1, l2).astype(dt),
-        right_output=leaf_output(br_g, br_h, l1, l2).astype(dt),
-    )
-
-
-@contract.parity_oracle("the two-op split scan: hist_fused=off reads "
-                        "the materialized [F, B, 3] histogram through "
-                        "this XLA pass — the bit-parity oracle the "
-                        "fused Pallas histogram+gain kernel is tested "
-                        "against (PARITY.md §2.2)")
 def find_best_split(hist: jax.Array, leaf_count: jax.Array,
                     sum_g: jax.Array, sum_h: jax.Array,
                     feature_mask: jax.Array, params: SplitParams) -> BestSplit:

@@ -33,7 +33,7 @@ jax.config.update("jax_enable_x64", True)
 from lightgbm_tpu.analysis.guards import (xla_guard,  # noqa: E402,F401
                                           collective_trace)  # noqa: F401
 
-REFERENCE_DIR = "/root/reference"
+REFERENCE_DIR = os.environ.get("LGT_REFERENCE_DIR", "/root/reference")
 GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden")
 
 # -- thread-leak gate --------------------------------------------------------
@@ -84,3 +84,70 @@ def no_leaked_threads():
         "frontend the test started must be shut down (daemon worker "
         "threads included for the gated pools)"
         % ", ".join("%s(daemon=%s)" % (t.name, t.daemon) for t in rest))
+
+
+# -- example files -----------------------------------------------------------
+# Tests that need *a* file of the reference's formats and shapes, not the
+# reference's own bytes, read the `examples` fixture: the mounted
+# <reference>/examples where there is one, else files written once a
+# session from a fixed seed, in the same layout.  Tests that compare
+# with the reference's own output (tests/golden/) read REFERENCE_DIR and
+# skip without it.
+
+import numpy as np  # noqa: E402
+
+EXAMPLES_SEED = 20161017
+
+
+def write_tsv(path, y, x):
+    """Label first, tab-separated, three decimals: the example files'."""
+    with open(path, "w") as f:
+        for label, row in zip(y, x):
+            f.write("\t".join(["%d" % label] + ["%.3f" % v for v in row])
+                    + "\n")
+
+
+def write_examples(root, seed=EXAMPLES_SEED):
+    """binary_classification/binary.{train,test} (7,000 and 500 x 28,
+    label first, TSV), regression/regression.test (500 x 28, TSV) and
+    lambdarank/rank.test (+ .query; LibSVM, zeros left out) under root."""
+    rng = np.random.RandomState(seed)
+
+    def rows(n):
+        x = rng.randn(n, 28)
+        x[:, 20:] = np.abs(x[:, 20:]) * (rng.rand(n, 8) < 0.6)   # sparse-ish
+        score = (1.4 * x[:, 0] - 1.1 * x[:, 1] + x[:, 2] * x[:, 3]
+                 + 0.8 * (np.abs(x[:, 4]) - 0.8) + 0.6 * x[:, 20])
+        return x, score
+
+    for sub in ("binary_classification", "regression", "lambdarank"):
+        os.makedirs(os.path.join(root, sub), exist_ok=True)
+    for name, n in (("binary.train", 7000), ("binary.test", 500)):
+        x, score = rows(n)
+        write_tsv(os.path.join(root, "binary_classification", name),
+                  score + rng.logistic(size=n) > 0, x)
+    x, score = rows(500)
+    write_tsv(os.path.join(root, "regression", "regression.test"),
+              score + rng.logistic(size=500) > 0, x)
+    sizes = rng.randint(5, 30, size=50)
+    x, score = rows(int(sizes.sum()))
+    rel = np.clip(np.round(score + rng.randn(len(score)) + 1.0), 0, 4)
+    path = os.path.join(root, "lambdarank", "rank.test")
+    with open(path, "w") as f:
+        for label, row in zip(rel, x):
+            f.write(" ".join(["%d" % label] + [
+                "%d:%.3f" % (j + 1, v) for j, v in enumerate(row)
+                if "%.3f" % v not in ("0.000", "-0.000")]) + "\n")
+    with open(path + ".query", "w") as f:
+        f.write("".join("%d\n" % q for q in sizes))
+
+
+@pytest.fixture(scope="session")
+def examples(tmp_path_factory):
+    """The directory of example files (see above)."""
+    mounted = os.path.join(REFERENCE_DIR, "examples")
+    if os.path.isdir(mounted):
+        return mounted
+    root = str(tmp_path_factory.mktemp("examples"))
+    write_examples(root)
+    return root

@@ -13,21 +13,73 @@ import pytest
 
 import lightgbm_tpu as lgb
 
-from conftest import REFERENCE_DIR
-
-BINARY_DIR = os.path.join(REFERENCE_DIR, "examples", "binary_classification")
-TRAIN_FILE = os.path.join(BINARY_DIR, "binary.train")
-TEST_FILE = os.path.join(BINARY_DIR, "binary.test")
-
-
-def read_tsv(path):
-    raw = np.loadtxt(path, delimiter="\t")
-    return raw[:, 1:], raw[:, 0].astype(np.float32)
+from conftest import EXAMPLES_SEED, write_examples
 
 
 @pytest.fixture(scope="module")
-def train_ds():
-    return lgb.Dataset(TRAIN_FILE, params={"max_bin": 15})
+def binary_train(examples):
+    return os.path.join(examples, "binary_classification", "binary.train")
+
+
+@pytest.fixture(scope="module")
+def binary_test(examples):
+    return os.path.join(examples, "binary_classification", "binary.test")
+
+
+def read_tsv(path):
+    """(x, y) with the doubles the file loader sees: the reference's Atof
+    (io/parser.py) is a few ulp off correct rounding on some tokens, and
+    a value that np.loadtxt rounds to the other side of a bin boundary is
+    not the "identical raw value" these tests feed both ways."""
+    from lightgbm_tpu.io.parser import parse_dense
+    with open(path) as f:
+        y, x = parse_dense(f.read().splitlines(), "\t", 0)
+    return x, y.astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def train_ds(binary_train):
+    return lgb.Dataset(binary_train, params={"max_bin": 15})
+
+
+def test_generated_examples_are_deterministic(tmp_path):
+    """The files the `examples` fixture writes where no reference is
+    mounted: two builds of one seed are byte-equal, in the reference's
+    layout."""
+    names = ["binary_classification/binary.train",
+             "binary_classification/binary.test",
+             "regression/regression.test", "lambdarank/rank.test",
+             "lambdarank/rank.test.query"]
+    def build(tag, seed):
+        write_examples(str(tmp_path / tag), seed)
+        return [(tmp_path / tag / n).read_bytes() for n in names]
+
+    first = build("a", EXAMPLES_SEED)
+    assert build("b", EXAMPLES_SEED) == first
+    assert all(first)
+    assert first[0].count(b"\n") == 7000
+    assert first[1].count(b"\n") == 500
+    assert build("c", EXAMPLES_SEED + 1)[0] != first[0]
+
+
+@pytest.mark.parametrize("name,rows,labels", [
+    ("binary_classification/binary.test", 500, {0, 1}),
+    ("regression/regression.test", 500, {0, 1}),
+    ("lambdarank/rank.test", None, {0, 1, 2, 3, 4})])
+def test_example_files_load(examples, name, rows, labels):
+    """Each example file goes through the loader in its own format (TSV;
+    LibSVM with its .query side file), whoever wrote it."""
+    ds = lgb.Dataset(os.path.join(examples, name), params={"max_bin": 15})
+    if rows is not None:
+        assert ds.num_data() == rows
+    assert 20 <= ds.num_feature() <= 300
+    assert set(np.unique(ds.get_label())) <= labels
+    group = ds.get_field("group")
+    if name.endswith("rank.test"):
+        assert group[0] == 0 and group[-1] == ds.num_data()
+        assert len(group) > 10 and np.all(np.diff(group) > 0)
+    else:
+        assert group is None
 
 
 def test_dataset_from_file(train_ds):
@@ -36,20 +88,20 @@ def test_dataset_from_file(train_ds):
     assert len(train_ds.get_label()) == 7000
 
 
-def test_dataset_from_mat_aligns_bins(train_ds):
-    x, y = read_tsv(TEST_FILE)
+def test_dataset_from_mat_aligns_bins(train_ds, binary_test):
+    x, y = read_tsv(binary_test)
     ds = lgb.Dataset(x, label=y, reference=train_ds)
     assert ds.num_data() == 500
     assert ds.num_feature() == train_ds.num_feature()
     # identical raw values must land in identical bins as a from-file load
-    ds_file = lgb.Dataset(TEST_FILE, reference=train_ds,
+    ds_file = lgb.Dataset(binary_test, reference=train_ds,
                           params={"max_bin": 15})
     np.testing.assert_array_equal(ds.inner.bins, ds_file.inner.bins)
 
 
-def test_dataset_from_csr_csc(train_ds):
+def test_dataset_from_csr_csc(train_ds, binary_test):
     sp = pytest.importorskip("scipy.sparse")
-    x, y = read_tsv(TEST_FILE)
+    x, y = read_tsv(binary_test)
     d_csr = lgb.Dataset(sp.csr_matrix(x), label=y, reference=train_ds)
     d_csc = lgb.Dataset(sp.csc_matrix(x), label=y, reference=train_ds)
     d_mat = lgb.Dataset(x, label=y, reference=train_ds)
@@ -82,12 +134,12 @@ def test_dataset_fields():
 
 
 @pytest.fixture(scope="module")
-def booster(train_ds):
+def booster(train_ds, binary_test):
     b = lgb.Booster(params={"objective": "binary", "metric": "auc",
                             "num_leaves": 31, "min_data_in_leaf": 50,
                             "learning_rate": 0.05},
                     train_set=train_ds)
-    b.add_valid(lgb.Dataset(TEST_FILE, reference=train_ds,
+    b.add_valid(lgb.Dataset(binary_test, reference=train_ds,
                             params={"max_bin": 15}), "test")
     for _ in range(20):
         b.update()
@@ -103,8 +155,8 @@ def test_booster_train_auc(booster):
     assert valid_auc > 0.72
 
 
-def test_booster_predict_modes(booster):
-    x, _ = read_tsv(TEST_FILE)
+def test_booster_predict_modes(booster, binary_test):
+    x, _ = read_tsv(binary_test)
     p = booster.predict(x)
     raw = booster.predict(x, raw_score=True)
     assert p.shape == (500,) and raw.shape == (500,)
@@ -119,8 +171,8 @@ def test_booster_predict_modes(booster):
     assert not np.allclose(p, p5)
 
 
-def test_booster_model_roundtrip(booster, tmp_path):
-    x, _ = read_tsv(TEST_FILE)
+def test_booster_model_roundtrip(booster, binary_test, tmp_path):
+    x, _ = read_tsv(binary_test)
     path = str(tmp_path / "model.txt")
     booster.save_model(path)
     reloaded = lgb.Booster(model_file=path)
@@ -139,7 +191,7 @@ def test_feature_importance(booster):
     assert all(v > 0 for v in imp.values())
 
 
-def test_custom_objective(train_ds):
+def test_custom_objective(train_ds, binary_test):
     """LGBM_BoosterUpdateOneIterCustom: external grad/hess must reproduce
     the built-in binary objective's trees exactly when fed the same math
     (sigmoid=1, unweighted; binary_objective.hpp:23-86)."""
@@ -158,14 +210,14 @@ def test_custom_objective(train_ds):
     for _ in range(5):
         b_ref.update()
         b_cus.update(fobj=fobj)
-    x, _ = read_tsv(TEST_FILE)
+    x, _ = read_tsv(binary_test)
     np.testing.assert_allclose(b_ref.predict(x, raw_score=True),
                                b_cus.predict(x, raw_score=True),
                                rtol=1e-4, atol=1e-6)
 
 
-def test_train_convenience_early_stopping(train_ds):
-    valid = lgb.Dataset(TEST_FILE, reference=train_ds,
+def test_train_convenience_early_stopping(train_ds, binary_test):
+    valid = lgb.Dataset(binary_test, reference=train_ds,
                         params={"max_bin": 15})
     booster = lgb.train(
         {"objective": "binary", "metric": "binary_logloss",

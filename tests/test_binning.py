@@ -75,12 +75,11 @@ class TestTwoRoundLoading:
         p.update(extra or {})
         return Config.from_params(p)
 
-    def test_matches_one_round_on_example(self):
+    def test_matches_one_round_on_example(self, examples):
         import os
-        from conftest import REFERENCE_DIR
         from lightgbm_tpu.io.dataset import load_dataset
-        path = os.path.join(REFERENCE_DIR,
-                            "examples/binary_classification/binary.train")
+        path = os.path.join(examples,
+                            "binary_classification/binary.train")
         one = load_dataset(path, self._cfg())
         two = load_dataset(path, self._cfg({"use_two_round_loading": "true"}))
         np.testing.assert_array_equal(one.bins, two.bins)

@@ -265,6 +265,28 @@ def test_iter_batched_zero_recompiles_across_rebag_boundaries(
         jax.block_until_ready(g.scores)
 
 
+def test_pallas_step_zero_recompiles_across_rebag_boundary(xla_guard):
+    """The Pallas step (masked sweep kernel + XLA gain scan) keeps the
+    zero-recompile invariant: after warm-up (incl. one re-bagging
+    boundary), further iterations across another re-bag trigger ZERO
+    XLA compiles."""
+    import jax
+
+    booster = _batched_booster(
+        {"max_bin": 63, "min_data_in_leaf": 20, "metric": "",
+         "hist_impl": "pallas", "hist_ordered": "off",
+         "bagging_fraction": 0.5, "bagging_freq": 2,
+         "bag_compact": "off", "num_iterations": 16})
+    for _ in range(3):   # warm-up crosses the first re-bag (freq=2)
+        booster.train_one_iter(None, None, False)
+    jax.block_until_ready(booster.scores)
+    with xla_guard(0, what="Pallas-kernel steady state across a "
+                           "further re-bagging boundary"):
+        for _ in range(2):   # iterations 3..4: re-bag at 4
+            booster.train_one_iter(None, None, False)
+        jax.block_until_ready(booster.scores)
+
+
 def test_iter_batched_model_matches_oracle_bytes():
     from lightgbm_tpu.api import Dataset, train
 

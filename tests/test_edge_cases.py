@@ -226,3 +226,26 @@ def test_predict_empty_input_preserves_output(rng, tmp_path):
                "input_model=%s" % model_p, "output_result=%s" % out_p])
     assert rc != 0
     assert out_p.read_text() == "precious previous result\n"
+
+
+def test_readme_names_only_config_keys_that_exist():
+    """Every backticked `name=value` of README.md whose name carries a
+    prefix of the TPU-native keys names a field of Config.  No upstream
+    document vouches for these keys, so the README is their only manual,
+    and a key that was deleted must leave it too."""
+    import dataclasses
+    import re
+
+    from lightgbm_tpu.config import Config
+
+    fields = {f.name for f in dataclasses.fields(Config)}
+    prefixes = ("hist_", "bag_", "iter_", "serve_", "ingest_", "snapshot_",
+                "refresh_")
+    readme = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                          "README.md")
+    with open(readme) as f:
+        named = {m.group(1) for m in re.finditer(
+            r"`([a-z][a-z0-9_]*)=[^`]*`", f.read())
+            if m.group(1).startswith(prefixes)}
+    assert len(named) >= 10, named      # the pattern still finds them
+    assert named <= fields, sorted(named - fields)

@@ -10,9 +10,7 @@ from lightgbm_tpu.ops.histogram import leaf_histogram, make_gvals
 from lightgbm_tpu.ops.hist_pallas import (PALLAS_ROW_BLOCK, _feat_grid,
                                           fold_leaf_mask,
                                           leaf_histogram_blocklist,
-                                          leaf_histogram_masked,
-                                          leaf_histogram_pallas,
-                                          leaf_histogram_ranged, make_gh2)
+                                          leaf_histogram_masked, make_gh2)
 
 
 def _data(n, f, b, seed=0):
@@ -29,8 +27,10 @@ def test_pallas_matches_xla_oracle(f, b):
     n = 512  # small row_block keeps interpret mode fast
     bins_t, grad, hess, mask = _data(n, f, b)
     gh2 = make_gh2(jnp.asarray(grad), jnp.asarray(hess))
-    got = leaf_histogram_pallas(jnp.asarray(bins_t), gh2, jnp.asarray(mask),
-                                max_bin=b, row_block=128, interpret=True)
+    leaf_eff = fold_leaf_mask(jnp.zeros(n, jnp.int32), jnp.asarray(mask))
+    got = leaf_histogram_masked(jnp.asarray(bins_t), gh2, leaf_eff,
+                                jnp.int32(0), max_bin=b, row_block=128,
+                                interpret=True)
     gv = make_gvals(jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask),
                     jnp.float32)
     want = leaf_histogram(jnp.asarray(bins_t), gv, max_bin=b)
@@ -59,29 +59,27 @@ def test_masked_kernel_matches_xla_oracle():
 
 
 def _sweep(kernel, bins, gh2, leaf_eff, target, nblocks, b):
-    """One of the three two-op kernels over blocks [1, nblocks - 1)."""
+    """One of the two kernels: every block, or blocks [1, nblocks - 1)."""
     kw = dict(max_bin=b, row_block=128, interpret=True)
     if kernel == "masked":
         return leaf_histogram_masked(bins, gh2, leaf_eff, target, **kw)
-    if kernel == "ranged":
-        return leaf_histogram_ranged(bins, gh2, leaf_eff, target,
-                                     jnp.int32(1), jnp.int32(nblocks - 2),
-                                     **kw)
     blist = jnp.concatenate([jnp.arange(1, nblocks - 1, dtype=jnp.int32),
                              jnp.zeros(2, jnp.int32)])
     return leaf_histogram_blocklist(bins, gh2, leaf_eff, target, blist,
                                     jnp.int32(nblocks - 2), **kw)
 
 
-@pytest.mark.parametrize("kernel", ["masked", "ranged", "blocklist"])
+@pytest.mark.parametrize("kernel", ["masked", "blocklist"])
 @pytest.mark.parametrize("b", [63, 255])
-@pytest.mark.parametrize("f", [13, 28, 39, 47])
+@pytest.mark.parametrize("f", [8, 13, 28, 39, 47, 136])
 def test_ragged_feature_block_reads_bins_in_place(f, b, kernel):
     """F that its feature block does not divide: the kernel reads the
     [F, N] matrix as it is (the last block runs past the array) and gives
     the same BITS as on a matrix the caller padded to whole blocks —
     with zeros, as the wrappers did, or with any other bytes: the rows
-    past F reach only their own slices of the output, which are cut."""
+    past F reach only their own slices of the output, which are cut.
+    F = 8 is the control (one block that fits, nothing to pad); F = 136
+    is nine blocks with a ragged ninth."""
     n, nblocks, target = 1024, 8, 3
     bins_t, grad, hess, _ = _data(n, f, b, seed=f + b)
     rng = np.random.RandomState(f)
@@ -92,13 +90,14 @@ def test_ragged_feature_block_reads_bins_in_place(f, b, kernel):
     bag = rng.rand(n) < 0.8
     gh2 = make_gh2(jnp.asarray(grad), jnp.asarray(hess))
     leaf_eff = fold_leaf_mask(jnp.asarray(leaf_id), jnp.asarray(bag))
-    _, fpad, _ = _feat_grid(f)
-    assert fpad > f
+    fb, fpad, _ = _feat_grid(f)
+    assert (fpad > f) == (f % fb != 0) == (f != 8)
     got = _sweep(kernel, jnp.asarray(bins_t), gh2, leaf_eff,
                  jnp.int32(target), nblocks, b)
     assert got.shape == (f, b, 3)
     for tail in (np.zeros((fpad - f, n), np.uint8),
-                 rng.randint(0, 256, size=(fpad - f, n)).astype(np.uint8)):
+                 rng.randint(0, 256, size=(fpad - f, n)).astype(np.uint8)
+                 ) if fpad > f else ():
         padded = _sweep(kernel, jnp.asarray(np.vstack([bins_t, tail])), gh2,
                         leaf_eff, jnp.int32(target), nblocks, b)
         assert padded.shape == (fpad, b, 3)
@@ -209,3 +208,80 @@ def test_grow_tree_ranged_bit_identical():
                 "leaf_count"):
         np.testing.assert_array_equal(np.asarray(getattr(t0, fld)),
                                       np.asarray(getattr(t1, fld)))
+
+
+def test_every_pallas_wrapper_has_a_grower():
+    """Every public leaf_histogram_* of ops/hist_pallas.py is imported by
+    ops/grow.py: a kernel no grower reaches has never run in a cell, and
+    still costs every edit of the layer (PERF.md section 6, PR 29)."""
+    import ast
+
+    from lightgbm_tpu.ops import grow, hist_pallas
+
+    with open(grow.__file__) as fh:
+        imported = {a.name for node in ast.walk(ast.parse(fh.read()))
+                    if isinstance(node, ast.ImportFrom)
+                    and node.module == "hist_pallas" for a in node.names}
+    wrappers = sorted(n for n in dir(hist_pallas)
+                      if n.startswith("leaf_histogram"))
+    assert wrappers == ["leaf_histogram_blocklist", "leaf_histogram_masked"]
+    assert set(wrappers) <= imported, set(wrappers) - imported
+
+
+@pytest.mark.parametrize("source", ["command_line", "config_file",
+                                    "python_api"])
+@pytest.mark.parametrize("key,value", [
+    ("hist_fused", "on"), ("hist_acc", "bf16"), ("hist_compact", "on")])
+def test_removed_kernel_keys_are_unknown(key, value, source, tmp_path):
+    """hist_fused, hist_acc and hist_compact chose among kernel forks
+    that are gone (PR 29).  They are no fields of Config, get what any
+    key the program does not know gets (read, then ignored: no alias, no
+    check of the value, so `hist_impl=xla hist_acc=bf16` is no fatal any
+    more), and a job that still names one trains the default path's model
+    to the byte."""
+    import dataclasses
+
+    import lightgbm_tpu as lgb
+    from conftest import write_tsv
+    from lightgbm_tpu import cli
+    from lightgbm_tpu.config import ALIAS_TABLE, Config, load_parameters
+
+    assert key not in {f.name for f in dataclasses.fields(Config)}
+    assert key not in ALIAS_TABLE and key not in ALIAS_TABLE.values()
+    data = str(tmp_path / "train.tsv")
+    rng = np.random.RandomState(11)
+    x = rng.randn(300, 4)
+    write_tsv(data, x[:, 0] + x[:, 1] * x[:, 2] + 0.3 * rng.randn(300) > 0,
+              x)
+    base = ["task=train", "data=" + data, "objective=binary",
+            "num_trees=3", "num_leaves=7", "min_data_in_leaf=5",
+            "hist_impl=xla", "device_type=cpu", "verbose=-1"]
+
+    def train(tag, said=None):
+        if source == "python_api":
+            params = dict(kv.split("=") for kv in base[2:])
+            if said is not None:
+                params[key] = said
+            return lgb.train(params, lgb.Dataset(data, params=params),
+                             verbose_eval=False).model_to_string()
+        out = tmp_path / (tag + ".txt")
+        argv = base + ["output_model=%s" % out]
+        if said is None:
+            pass
+        elif source == "config_file":
+            conf = tmp_path / (tag + ".conf")
+            conf.write_text("# a job from before PR 29\n%s = %s\n"
+                            % (key, said))
+            argv.append("config=%s" % conf)
+        else:
+            argv.append("%s=%s" % (key, said))
+        params = load_parameters(argv)
+        assert (key in params) == (said is not None)
+        assert Config.from_params(params) == Config.from_params(
+            {k: v for k, v in params.items() if k != key})
+        assert cli.main(argv) == 0
+        return out.read_text()
+
+    want = train("plain")
+    assert train("named", value) == want
+    assert train("garbled", "maybe") == want

@@ -723,3 +723,84 @@ def test_cli_task_ingest_roundtrip(tmp_path):
     a = _train_model(p, tmp_path, "cli_text")
     b = _train_model(out, tmp_path, "cli_shard")
     assert a == b
+
+
+# ---------------------------------------------------------------------------
+# IO/compute-overlapped shard streaming (config.ingest_prefetch)
+# ---------------------------------------------------------------------------
+
+def test_config_rejects_negative_ingest_prefetch():
+    from lightgbm_tpu.utils.log import LightGBMError
+
+    with pytest.raises(LightGBMError, match="ingest_prefetch"):
+        Config.from_params({"ingest_prefetch": "-1"})
+
+
+@pytest.mark.usefixtures("no_leaked_threads")
+def test_prefetch_windows_preserves_order_and_bytes():
+    from lightgbm_tpu.ingest.shards import prefetch_windows
+
+    rng = np.random.RandomState(0)
+    src = [rng.randint(0, 255, size=(4, k)).astype(np.uint8)
+           for k in (96, 96, 17)]
+    want = [w.copy() for w in src]
+    for depth in (0, 1, 3, 16):
+        got = list(prefetch_windows(iter(src), depth))
+        assert len(got) == len(want)
+        for w, g in zip(want, got):
+            np.testing.assert_array_equal(w, g)
+            assert g.flags["C_CONTIGUOUS"]
+
+
+@pytest.mark.usefixtures("no_leaked_threads")
+def test_prefetch_windows_propagates_exceptions_and_aborts_clean():
+    import threading
+
+    from lightgbm_tpu.ingest.shards import prefetch_windows
+
+    def bad():
+        yield np.zeros((2, 8), np.uint8)
+        raise IOError("shard vanished")
+
+    it = prefetch_windows(bad(), 2)
+    next(it)
+    with pytest.raises(IOError, match="shard vanished"):
+        next(it)
+
+    # early consumer abandonment must not leave a producer thread
+    # blocked on the bounded queue
+    before = threading.active_count()
+
+    def many():
+        for _ in range(64):
+            yield np.zeros((2, 8), np.uint8)
+
+    it2 = prefetch_windows(many(), 1)
+    next(it2)
+    it2.close()
+    deadline = 50
+    while threading.active_count() > before and deadline:
+        import time
+        time.sleep(0.05)
+        deadline -= 1
+    assert threading.active_count() <= before, \
+        "prefetch producer thread leaked after consumer close"
+
+
+@pytest.mark.usefixtures("no_leaked_threads")
+def test_shard_fed_training_byte_identical_with_prefetch(tmp_path):
+    """The acceptance gate: shard-fed models are byte-identical to the
+    in-memory text path with overlap ON (ingest_prefetch=3), and to the
+    synchronous shard feed (ingest_prefetch=0) — the prefetcher may
+    change timing, never bytes."""
+    p = _write_tsv(tmp_path)
+    out = str(tmp_path / "shards")
+    ingest([p], out, Config.from_params(
+        {"ingest_workers": "1", "ingest_shard_rows": "96"}))
+    text = _train_model(p, tmp_path, "text")
+    sync = _train_model(out, tmp_path, "sync",
+                        extra={"ingest_prefetch": "0"})
+    overlapped = _train_model(out, tmp_path, "pref",
+                              extra={"ingest_prefetch": "3"})
+    assert sync == text
+    assert overlapped == text

@@ -10,8 +10,6 @@ import pytest
 from lightgbm_tpu import native
 from lightgbm_tpu.io import parser as pyparser
 
-from conftest import REFERENCE_DIR
-
 
 pytestmark = pytest.mark.skipif(native.get_lib() is None,
                                 reason="native toolchain unavailable")
@@ -27,9 +25,8 @@ def read_lines(path):
     ("regression", "regression.test"),
     ("lambdarank", "rank.test"),
 ])
-def test_native_matches_python_on_examples(example, fname):
-    lines = read_lines(os.path.join(REFERENCE_DIR, "examples", example,
-                                    fname))
+def test_native_matches_python_on_examples(examples, example, fname):
+    lines = read_lines(os.path.join(examples, example, fname))
     fmt = pyparser.detect_format(lines)
     nat = pyparser._native_parse(lines, 0, fmt)
     assert nat is not None, "native parse declined"

@@ -361,41 +361,6 @@ def test_predict_extreme_values_match_host_traversal():
                                   booster.predict_raw(wide))
 
 
-@pytest.mark.parametrize("impl,n", [("xla", 3000), ("pallas", 16384)])
-def test_hist_compact_tree_identity(impl, n):
-    """EXPERIMENTAL hist_compact path: compacted small-leaf sweeps must
-    reproduce the full-sweep tree exactly in structure and row routing
-    (leaf values may differ in f32 accumulation grouping ulps)."""
-    from lightgbm_tpu.ops.split import SplitParams
-
-    params = SplitParams(min_data_in_leaf=5, min_sum_hessian_in_leaf=1e-3,
-                         lambda_l1=0.0, lambda_l2=0.0, min_gain_to_split=0.0)
-    rng = np.random.RandomState(7)
-    f = 6
-    bins = rng.randint(0, 32, size=(f, n)).astype(np.uint8)
-    grad = rng.randn(n).astype(np.float32)
-    hess = (rng.rand(n) + 0.5).astype(np.float32)
-    bag = rng.rand(n) < 0.85
-    args = (jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
-            jnp.asarray(bag), jnp.ones(f, dtype=bool))
-    kw = dict(max_leaves=15, max_bin=32, params=params, hist_impl=impl)
-    t0, l0 = grow_tree(*args, **kw)
-    cap = ((n // 2 + 8191) // 8192) * 8192 if impl == "pallas" else n // 2
-    t1, l1 = grow_tree(*args, **kw, compact=cap)
-    nl = int(t0.num_leaves)
-    assert int(t1.num_leaves) == nl
-    np.testing.assert_array_equal(np.asarray(t0.split_feature)[:nl - 1],
-                                  np.asarray(t1.split_feature)[:nl - 1])
-    np.testing.assert_array_equal(np.asarray(t0.threshold_bin)[:nl - 1],
-                                  np.asarray(t1.threshold_bin)[:nl - 1])
-    # f32 accumulation GROUPING differs between the compacted and full
-    # sweeps (fewer row blocks), so values agree only to f32 sum noise
-    np.testing.assert_allclose(np.asarray(t0.leaf_value)[:nl],
-                               np.asarray(t1.leaf_value)[:nl],
-                               rtol=1e-4, atol=1e-6)
-    np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
-
-
 def test_matmul_predictor_matches_descent():
     """The gather-free matmul predictor (selection matmul + path-score
     argmax over host rank codes) must agree with the while-loop descent

@@ -18,15 +18,11 @@ from test_bins_in_place import N as STEP_ROWS
 from test_bins_in_place import (_steps_of_a_training_job,  # noqa: F401
                                 traces_forgotten)
 
-from lightgbm_tpu.models.gbdt import resolve_hist_fused
 from lightgbm_tpu.ops import hist_pallas as hp
-from lightgbm_tpu.ops.split import SplitParams
-from lightgbm_tpu.utils.log import LightGBMError
 
 S = jax.ShapeDtypeStruct
 N = 8 * hp.PALLAS_ROW_BLOCK
 I32 = S((), jnp.int32)
-F32 = S((), jnp.float32)
 
 
 def _lower_for_tpu(fn, args, **statics):
@@ -45,10 +41,8 @@ def _pads_of_u8(low):
             if "stablehlo.pad" in line and "xui8>" in line.split("->")[0]]
 
 
-# every Pallas kernel the default training path reaches (hist_fused=auto
-# -> two-op, hist_acc=f32): the masked full sweep and the ordered-
-# partition block-list sweep (and the ranged sweep, the maintained
-# contiguous-range API), at one / two / three / nine feature blocks.
+# both Pallas kernels: the masked full sweep and the ordered-partition
+# block-list sweep, at one / two / three / nine feature blocks.
 # F = 13, 28 and 39 do not divide their feature block (16): the last
 # block runs past the array (13: ONE block larger than the array; 39: a
 # ragged third), and the kernels read the matrix in place — no wrapper
@@ -60,7 +54,6 @@ def test_default_path_kernels_lower_for_tpu(f, max_bin):
     nblocks = N // hp.PALLAS_ROW_BLOCK
     for fn, more, statics in (
             (hp.leaf_histogram_masked, (), {}),
-            (hp.leaf_histogram_ranged, (I32, I32), {}),
             (hp.leaf_histogram_blocklist, (S((nblocks,), jnp.int32), I32),
              {"grid_blocks": 8})):
         low = _lower_for_tpu(fn, _sweep_args(f) + more, max_bin=max_bin,
@@ -130,38 +123,3 @@ def test_resort_step_moves_row_state_in_one_gather(monkeypatch,
     assert stacked == ["5"], stacked
     # the reader sees the rest of the step too: the block list's argsort
     assert ("sort", 2) in _ops_under(text, "lgbm.block_list")
-
-
-@pytest.mark.parametrize("f", [16, 28])
-def test_fused_kernels_are_refused_today(f):
-    """Pins the refusal that makes hist_fused=auto resolve to the two-op
-    path (models/gbdt.py resolve_hist_fused).  If this test starts
-    failing the fused kernels lower again: flip `auto` back on for
-    non-CPU platforms there, and extend the test above to cover them."""
-    params = SplitParams(100, 1e-3, 0.0, 0.0, 0.0)
-    stats = (I32, F32, F32)
-    tail = (S((f, 255, 3), jnp.float32), S((f,), jnp.bool_), stats, stats)
-    with pytest.raises((NotImplementedError, ValueError)):
-        _lower_for_tpu(hp.leaf_histogram_masked_fused,
-                       _sweep_args(f) + tail, max_bin=255, params=params)
-    with pytest.raises((NotImplementedError, ValueError)):
-        _lower_for_tpu(
-            hp.leaf_histogram_blocklist_fused,
-            _sweep_args(f) + (S((N // hp.PALLAS_ROW_BLOCK,), jnp.int32),
-                              I32) + tail,
-            max_bin=255, params=params, grid_blocks=8)
-
-
-def test_hist_fused_rule():
-    """auto is the two-op path everywhere; on runs only where the
-    kernels interpret (CPU) and is fatal on any other platform."""
-    for platform in ("cpu", "tpu"):
-        assert resolve_hist_fused("auto", "pallas", platform) is False
-        assert resolve_hist_fused("off", "pallas", platform) is False
-        assert resolve_hist_fused("auto", "xla", platform) is False
-    assert resolve_hist_fused("on", "pallas", "cpu") is True
-    with pytest.raises(LightGBMError, match="do not lower for "
-                                            "platform=tpu"):
-        resolve_hist_fused("on", "pallas", "tpu")
-    with pytest.raises(LightGBMError, match="requires the Pallas"):
-        resolve_hist_fused("on", "xla", "cpu")
