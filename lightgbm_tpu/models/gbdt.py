@@ -69,12 +69,15 @@ def _pack_tree(dev_tree):
     """TreeArrays -> (int32 buffer, float buffer): two flat arrays so a
     whole tree ships device->host in two async copies instead of eleven.
     The trailing dummy slots (grow.py TreeArrays) are trimmed here, so the
-    wire layout stays [1 + 4*(L-1) + 3*L | (L-1) + L + (L-1)]."""
+    wire layout stays [1 + 4*(L-1) + 3*L + 2 | (L-1) + L + (L-1)]: the
+    int row ends with the tree's two sweep counters (blocks_swept,
+    grid_rows), behind everything _unpack_tree and _dart_layout slice."""
     ints = jnp.concatenate([
         dev_tree.num_leaves.reshape(1), dev_tree.split_feature[:-1],
         dev_tree.threshold_bin[:-1], dev_tree.left_child[:-1],
         dev_tree.right_child[:-1], dev_tree.leaf_parent[:-1],
         dev_tree.leaf_depth[:-1], dev_tree.leaf_count[:-1],
+        dev_tree.blocks_swept.reshape(1), dev_tree.grid_rows.reshape(1),
     ]).astype(jnp.int32)
     floats = jnp.concatenate([dev_tree.split_gain[:-1],
                               dev_tree.leaf_value[:-1],
@@ -259,8 +262,8 @@ def _fused_step_body(grad_fn, grow_kw, lr, dtype, compact_rows=0):
 
 
 @contract.traced_pure
-@contract.fused_body(collectives=("all_gather", "axis_index", "pmax",
-                                  "psum", "psum_scatter"))
+@contract.fused_body(collectives=("all_gather", "axis_index", "psum",
+                                  "psum_scatter"))
 def _make_fused_step(grad_fn, grow_kw, lr, dtype, compact_rows=0,
                      k_iters=1):
     body = _batch_iters(_fused_step_body(grad_fn, grow_kw, lr, dtype,
@@ -416,8 +419,8 @@ def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype, row_state,
 
 @contract.traced_pure
 @contract.fused_body(extras=("order",),
-                     collectives=("all_gather", "axis_index", "pmax",
-                                  "psum", "psum_scatter"))
+                     collectives=("all_gather", "axis_index", "psum",
+                                  "psum_scatter"))
 def _make_fused_step_reorder(grad_fn, grow_kw, lr, dtype, row_state,
                              compact_rows=0, k_iters=1):
     # gstate is NOT donated: on the first re-sort it aliases the
@@ -444,8 +447,8 @@ def _dart_layout(L):
 
 @contract.traced_pure
 @contract.fused_body(extras=("bank", "dart"),
-                     collectives=("all_gather", "axis_index", "pmax",
-                                  "psum", "psum_scatter"))
+                     collectives=("all_gather", "axis_index", "psum",
+                                  "psum_scatter"))
 def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
                           compact_rows=0, k_iters=1):
     """Fused DART iteration over a DEVICE-RESIDENT tree bank (VERDICT r3
@@ -671,8 +674,8 @@ def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder,
 
 @contract.traced_pure
 @contract.fused_body(extras=("order",),
-                     collectives=("all_gather", "axis_index", "pmax",
-                                  "psum", "psum_scatter"))
+                     collectives=("all_gather", "axis_index", "psum",
+                                  "psum_scatter"))
 def _make_fused_step_multi(grad_fn, grow_kw, lr, dtype, reorder,
                            row_state, compact_rows=0, k_iters=1):
     # gstate is NOT donated: on the first re-sort it aliases the
@@ -688,8 +691,8 @@ def _make_fused_step_multi(grad_fn, grow_kw, lr, dtype, reorder,
 
 @contract.traced_pure
 @contract.fused_body(extras=("order",),
-                     collectives=("all_gather", "axis_index", "pmax",
-                                  "psum", "psum_scatter"))
+                     collectives=("all_gather", "axis_index", "psum",
+                                  "psum_scatter"))
 def _make_fused_step_multi_sharded(grad_fn, grow_kw, lr, dtype, mesh,
                                    n_valid, gstate_specs, reorder,
                                    row_state, compact_rows=0,
@@ -734,8 +737,8 @@ def _make_fused_step_multi_sharded(grad_fn, grow_kw, lr, dtype, mesh,
 
 @contract.traced_pure
 @contract.fused_body(extras=("order",),
-                     collectives=("all_gather", "axis_index", "pmax",
-                                  "psum", "psum_scatter"))
+                     collectives=("all_gather", "axis_index", "psum",
+                                  "psum_scatter"))
 def _make_fused_step_sharded(grad_fn, grow_kw, lr, dtype, mesh,
                              n_valid, gstate_specs, reorder,
                              row_state, compact_rows=0,
@@ -2243,11 +2246,15 @@ class GBDT:
                          jnp.stack([m.floats for m in pend])))
                 for m, ih, fh in zip(pend, ints_all, floats_all):
                     m.ints, m.floats = ih, fh
-            # a packed tree's first int is its leaf count
+            # a packed tree's first int is its leaf count, its last two
+            # what its block-list sweeps cost (ops/grow.py TreeArrays)
+            stats = {"blocks_swept": sum(int(m.ints[-2]) for m in pending),
+                     "grid_rows": sum(int(m.ints[-1]) for m in pending)}
             wire = self._exchange_bytes(sum(int(m.ints[0])
                                             for m in pending))
             if wire is not None:
-                flush_span.set_metadata(exchange_bytes=wire)
+                stats["exchange_bytes"] = wire
+            flush_span.set_metadata(**stats)
             with TraceAnnotation(spans.FLUSH_UNPACK):
                 self._unpack_pending()
         return self._stopped
@@ -3304,7 +3311,7 @@ class DART(GBDT):
         leaf_dt = np.uint8 if L <= 256 else np.int32
         if self._bank is None:
             T = max(cfg.num_iterations, k_iters) + 1  # + dummy row
-            li = 1 + 4 * (L - 1) + 3 * L
+            li = 1 + 4 * (L - 1) + 3 * L + 2
             lf = 3 * L - 2
             bi = np.zeros((T, li), np.int32)
             # untouched rows must TERMINATE traversal: child slots -1

@@ -65,6 +65,11 @@ class TreeArrays(NamedTuple):
     leaf_depth: jax.Array       # [L+1] i32
     leaf_count: jax.Array       # [L+1] i32
     num_leaves: jax.Array       # scalar i32
+    # what the tree's block-list sweeps cost, root included and summed
+    # over shards (0 in the other sweep modes): occupied row blocks, and
+    # the row steps the kernels' grids ran
+    blocks_swept: jax.Array     # scalar i32
+    grid_rows: jax.Array        # scalar i32
 
 
 class GrowState(NamedTuple):
@@ -76,6 +81,7 @@ class GrowState(NamedTuple):
     leaf_sum_h: jax.Array       # [L+1]
     best_f: jax.Array           # [L+1, 8] float best-split fields
     best_i: jax.Array           # [L+1, 4] i32 best-split fields
+    swept: jax.Array            # [2] i32 (occupied blocks, grid rows) so far
     # histogram-pool bookkeeping (HistogramPool, reference
     # feature_histogram.hpp:275-398, re-designed as on-device LRU): only
     # carried when hist_slots bounds the pool; zero-size arrays otherwise
@@ -115,6 +121,7 @@ def _empty_tree(max_leaves: int, dtype) -> TreeArrays:
         leaf_depth=jnp.ones(L + 1, dtype=jnp.int32),
         leaf_count=z_i(L + 1),
         num_leaves=jnp.int32(1),
+        blocks_swept=jnp.int32(0), grid_rows=jnp.int32(0),
     )
 
 
@@ -301,6 +308,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
 
     ranged_on = (ranged and hist_impl == "pallas"
                  and feature_axis is None)
+    no_blocks = jnp.zeros(2, dtype=jnp.int32)   # hist_leaf's count elsewhere
     if hist_impl == "pallas":
         from .hist_pallas import (PALLAS_ROW_BLOCK, fold_leaf_mask,
                                   leaf_histogram_blocklist,
@@ -317,54 +325,33 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         # the result is BIT-identical to it for the same row order.
         # Pays off when rows are leaf-clustered (the ordered-partition
         # mode in models/gbdt.py re-sorts rows by the previous tree's
-        # leaves every few trees); never sweeps more than the full grid.
+        # leaves every few trees).  The kernel's grid ends at the leaf's
+        # last occupied block (a run-time bound), so a sweep costs its
+        # own blocks and one compiled kernel serves every leaf size.
         # Under tree_learner=data (psum_axis set) everything here is
-        # shard-LOCAL — blocks, occupancy, block list, re-sorts — except
-        # the ladder-rung choice below and the histogram reduction the
-        # other impls share (hist_psum).
+        # shard-LOCAL — blocks, occupancy, block list, grid, re-sorts —
+        # except the histogram reduction the other impls share
+        # (hist_psum): each shard's kernel runs to its own count.
         nblocks = n // PALLAS_ROW_BLOCK
-        # static grid-size ladder: the per-call floor is ~grid_blocks x
-        # the per-step bookkeeping, so deep (small) leaves dispatch to a
-        # small-grid variant
-        ladder = [g for g in (8, 32) if g < nblocks] + [nblocks]
-
-        def _block_plan(leaf_eff, target):
-            occ = (leaf_eff == target).reshape(
-                nblocks, PALLAS_ROW_BLOCK).any(axis=1)
-            n_occ = jnp.sum(occ).astype(jnp.int32)
-            # occupied block ids first, ascending (stable argsort of the
-            # complement keeps file order => full-sweep association)
-            blist = jnp.argsort(jnp.where(occ, 0, 1).astype(jnp.int32),
-                                stable=True).astype(jnp.int32)
-            # SPMD-uniform rung (VERDICT r3 #2): the rung is picked from
-            # the MAX occupancy over shards so every shard dispatches the
-            # same compiled branch; each shard still sweeps only its OWN
-            # occupied blocks (blist / n_occ stay shard-local)
-            n_sel = n_occ
-            if psum_axis:
-                with jax.named_scope(spans.HIST_EXCHANGE):
-                    n_sel = jax.lax.pmax(n_occ, psum_axis)
-            sel = jnp.int32(len(ladder) - 1)
-            for i in range(len(ladder) - 2, -1, -1):
-                sel = jnp.where(n_sel <= ladder[i], jnp.int32(i), sel)
-            return blist, n_occ, sel
 
         def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
             with jax.named_scope(spans.BLOCK_LIST):
                 leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-                blist, n_occ, sel = _block_plan(leaf_eff, target)
-
-            def mk(g):
-                def branch(le, bl, na):
-                    return leaf_histogram_blocklist(
-                        bins_t, gh2, le, target, bl, na, max_bin=max_bin,
-                        grid_blocks=g, interpret=interpret).astype(dtype)
-                return branch
-
+                occ = (leaf_eff == target).reshape(
+                    nblocks, PALLAS_ROW_BLOCK).any(axis=1)
+                n_occ = jnp.sum(occ).astype(jnp.int32)
+                # occupied block ids first, ascending (stable argsort of
+                # the complement keeps file order => full-sweep
+                # association)
+                blist = jnp.argsort(jnp.where(occ, 0, 1).astype(jnp.int32),
+                                    stable=True).astype(jnp.int32)
             with jax.named_scope(scope):
-                h = jax.lax.switch(sel, [mk(g) for g in ladder],
-                                   leaf_eff, blist, n_occ)
-            return hist_psum(h)
+                h = leaf_histogram_blocklist(
+                    bins_t, gh2, leaf_eff, target, blist, n_occ,
+                    max_bin=max_bin, interpret=interpret).astype(dtype)
+            # (occupied blocks, row steps the kernel ran): an empty leaf
+            # still runs one step
+            return hist_psum(h), jnp.stack([n_occ, jnp.maximum(n_occ, 1)])
     elif hist_impl == "pallas":
         def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
             with jax.named_scope(scope):
@@ -372,7 +359,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 h = leaf_histogram_masked(
                     bins_t, gh2, leaf_eff, target, max_bin=max_bin,
                     interpret=interpret).astype(dtype)
-            return hist_psum(h)
+            return hist_psum(h), no_blocks
     else:
         def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
             with jax.named_scope(scope):
@@ -380,7 +367,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                                 dtype)
                 h = leaf_histogram(bins_t, gv, max_bin=max_bin,
                                    row_chunk=row_chunk)
-            return hist_psum(h)
+            return hist_psum(h), no_blocks
 
     def packed_best(best, depth):
         """The depth gate (no split at max_depth) and the packing."""
@@ -390,8 +377,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         return _pack_best(best, dtype)
 
     # ---- root ----
-    root_hist = hist_leaf(jnp.zeros(n, dtype=jnp.int32), jnp.int32(0),
-                          scope=spans.HIST_ROOT)
+    root_hist, root_swept = hist_leaf(jnp.zeros(n, dtype=jnp.int32),
+                                      jnp.int32(0), scope=spans.HIST_ROOT)
     # every row lands in exactly one bin of feature 0, so its histogram sums
     # are the root totals (LeafSplits::Init root sumup, leaf_splits.hpp:36-117);
     # in voting mode the hist is local, so all-reduce the three scalars
@@ -432,7 +419,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         hist=hist0,
         leaf_sum_g=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_g),
         leaf_sum_h=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_h),
-        best_f=best_f0, best_i=best_i0,
+        best_f=best_f0, best_i=best_i0, swept=root_swept,
         leaf_slot=leaf_slot0, slot_leaf=slot_leaf0, slot_used=slot_used0,
     )
 
@@ -481,7 +468,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
             lc = lc.at[wn].set(jnp.where(keep, ~bl, lc[wn]))
             rc = rc.at[wn].set(jnp.where(keep, ~right, rc[wn]))
 
-            new_tree = TreeArrays(
+            new_tree = tree._replace(
                 split_feature=tree.split_feature.at[wn].set(
                     jnp.where(keep, s_feature, tree.split_feature[wn])),
                 threshold_bin=tree.threshold_bin.at[wn].set(
@@ -521,14 +508,15 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 # leaves the same way, feature_histogram.hpp:275-398 +
                 # serial_tree_learner.cpp BeforeFindBestSplit)
                 parent_slot = st.leaf_slot[bl]
-                parent_hist = jax.lax.cond(
+                parent_hist, reswept = jax.lax.cond(
                     parent_slot >= 0,
-                    lambda: st.hist[jnp.clip(parent_slot, 0, K - 1)],
+                    lambda: (st.hist[jnp.clip(parent_slot, 0, K - 1)],
+                             no_blocks),
                     lambda: hist_leaf(st.leaf_id, bl))
             else:
-                parent_hist = st.hist[bl]
+                parent_hist, reswept = st.hist[bl], no_blocks
         with jax.named_scope(spans.HIST_SWEEP):
-            small_hist = hist_leaf(leaf_id, small_leaf)
+            small_hist, swept = hist_leaf(leaf_id, small_leaf)
         with jax.named_scope(spans.HIST_POOL):
             large_hist = parent_hist - small_hist
             left_hist = jnp.where(left_is_smaller, small_hist, large_hist)
@@ -585,12 +573,15 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         return GrowState(tree=new_tree, leaf_id=leaf_id, hist=hist,
                          leaf_sum_g=leaf_sum_g, leaf_sum_h=leaf_sum_h,
                          best_f=best_f, best_i=best_i,
+                         swept=st.swept + reswept + swept,
                          leaf_slot=leaf_slot, slot_leaf=slot_leaf,
                          slot_used=slot_used), None
 
     final, _ = jax.lax.scan(step, state,
                             jnp.arange(1, max_leaves, dtype=jnp.int32))
-    return final.tree, final.leaf_id
+    swept = psum(final.swept)
+    return final.tree._replace(blocks_swept=swept[0],
+                               grid_rows=swept[1]), final.leaf_id
 
 
 @contract.traced_pure

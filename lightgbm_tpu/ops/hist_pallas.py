@@ -99,12 +99,12 @@ def _feat_grid(f: int):
     The kernels read the matrix IN PLACE: where fb does not divide F the
     last feature block runs past the array (39 features: rows 32-47 of a
     39-row array), and no wrapper pads it — a pad here is a copy of the
-    whole resident matrix at every split, which XLA hoists out of neither
-    the ladder's switch nor the grow scan (16% of a tree at 68M x 39:
-    PERF.md, PR 26).  What the rows past the array hold is unspecified,
-    and cannot reach a result: a bin is a byte that _accumulate turns
-    into one-hot operands by integer compares (hi == iota, lo == iota),
-    so ANY byte gives zeros and ones, never a NaN; in the block-diagonal
+    whole resident matrix at every split, which XLA does not hoist out of
+    the grow scan (16% of a tree at 68M x 39: PERF.md, PR 26).  What the
+    rows past the array hold is unspecified, and cannot reach a result:
+    a bin is a byte that the kernel turns into one-hot operands by
+    integer compares (hi == iota, lo == iota), so ANY byte gives zeros
+    and ones, never a NaN; in the block-diagonal
     product a feature's rows and columns meet only its own diagonal
     block, the one _diag_hist_xla extracts, so the rows past F fill only
     their own slices of the [fpad, ...] output, which both wrappers cut
@@ -124,11 +124,11 @@ def fold_leaf_mask(leaf_id: jax.Array, mask: jax.Array) -> jax.Array:
     return jnp.where(mask, leaf_id.astype(jnp.int32), jnp.int32(-1))
 
 
-def _accumulate(target, bins_ref, gh_ref, leaf_ref, out_ref, r, active):
-    """The radix matmul accumulation both kernels share: r == 0
-    initializes the block accumulators, later ACTIVE steps add.
-    Inactive steps (a blocklist grid past n_active) skip their matmuls —
-    their cost is grid bookkeeping only."""
+def _hist_kernel(target_ref, bins_ref, gh_ref, leaf_ref, out_ref):
+    """The radix matmul accumulation both sweeps share: the first row
+    step initializes the block accumulators, later steps add."""
+    target = target_ref[0]
+    r = pl.program_id(1)
     feat_block, blk = bins_ref.shape
 
     def emit(init):
@@ -166,7 +166,7 @@ def _accumulate(target, bins_ref, gh_ref, leaf_ref, out_ref, r, active):
     def _init():
         emit(True)
 
-    @pl.when((r != 0) & active)
+    @pl.when(r != 0)
     def _acc():
         emit(False)
 
@@ -178,12 +178,6 @@ def _diag_hist_xla(out: jax.Array, fpad: int):
     diag = jnp.einsum("gfchfl->gfchl", part)
     return diag.transpose(0, 1, 3, 4, 2).reshape(fpad, N_HI * N_LO,
                                                  N_COMP)
-
-
-def _hist_kernel(target_ref, bins_ref, gh_ref, leaf_ref, out_ref):
-    r = pl.program_id(1)
-    _accumulate(target_ref[0], bins_ref, gh_ref, leaf_ref, out_ref, r,
-                True)
 
 
 @functools.partial(jax.jit,
@@ -231,30 +225,18 @@ def leaf_histogram_masked(bins_t: jax.Array, gh2: jax.Array,
     return _diag_hist_xla(out, fpad)[:f, :max_bin, :]
 
 
-def _hist_kernel_blocklist(info_ref, blist_ref, bins_ref, gh_ref,
-                           leaf_ref, out_ref):
-    """info = [target, 0, n_active] (SMEM; slot 1 is spare — the layout
-    is part of the compiled kernel and so of the compile cache's key);
-    blist_ref is consumed by the index maps.
-
-    The grid's row dimension is the static worst case; steps past
-    n_active revisit the last active block (index maps clamp), so the
-    pipeline skips their DMA, and pl.when skips their matmuls — the cost
-    of an inactive step is grid bookkeeping only.  This is what makes
-    sweep time proportional to the leaf's block count instead of N.
-    """
-    r = pl.program_id(1)
-    _accumulate(info_ref[0], bins_ref, gh_ref, leaf_ref, out_ref, r,
-                r < info_ref[2])
+def _hist_kernel_blocklist(target_ref, blist_ref, *refs):
+    """target_ref and blist_ref are the scalar-prefetch operands (SMEM);
+    blist_ref is consumed by the index maps."""
+    _hist_kernel(target_ref, *refs)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("max_bin", "grid_blocks", "row_block",
-                                    "interpret"))
+                   static_argnames=("max_bin", "row_block", "interpret"))
 def leaf_histogram_blocklist(bins_t: jax.Array, gh2: jax.Array,
                              leaf_eff: jax.Array, target_leaf,
                              block_list: jax.Array, n_active, *,
-                             max_bin: int, grid_blocks: int = 0,
+                             max_bin: int,
                              row_block: int = PALLAS_ROW_BLOCK,
                              interpret: bool = False) -> jax.Array:
     """leaf_histogram_masked restricted to the row blocks named by
@@ -264,40 +246,32 @@ def leaf_histogram_blocklist(bins_t: jax.Array, gh2: jax.Array,
     row with leaf_eff == target_leaf lies in a listed block; rows of
     other leaves in listed blocks are masked as usual.
 
-    grid_blocks statically bounds the grid (and therefore the per-call
-    floor cost); callers dispatch over a ladder of compiled variants and
-    pick the smallest with grid_blocks >= n_active.  Steps past n_active
-    revisit the last listed block (no DMA) and skip their matmuls.
+    The grid's row extent is n_active itself, read at RUN time (a traced
+    int32 grid dimension): one compiled kernel whose cost is the leaf's
+    own block count, no worst-case grid and no step that finds nothing
+    to do (928 of a period's 990 sweeps ran 8,342 row steps whatever the
+    leaf held: PERF.md, PR 30).  An empty list (n_active 0) runs one
+    step over block_list[0], which the leaf mask zeroes.
     """
     f, n = bins_t.shape
     assert n % row_block == 0, (n, row_block)
     assert max_bin <= N_HI * N_LO, max_bin
     fb, fpad, groups = _feat_grid(f)
     nblocks = n // row_block
-    if grid_blocks <= 0 or grid_blocks > nblocks:
-        grid_blocks = nblocks
-    info = jnp.stack([jnp.asarray(target_leaf, jnp.int32),
-                      jnp.int32(0),
-                      jnp.clip(jnp.asarray(n_active, jnp.int32), 1,
-                               grid_blocks)])
+    target = jnp.asarray(target_leaf, dtype=jnp.int32).reshape(1)
     blist = jnp.clip(block_list.astype(jnp.int32), 0, nblocks - 1)
-
-    def _rb(r, info_ref, blist_ref):
-        return blist_ref[jnp.minimum(r, info_ref[2] - 1)]
+    rows = jnp.clip(jnp.asarray(n_active, jnp.int32), 1, nblocks)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(groups, grid_blocks),
+        grid=(groups, rows),   # row dim minor: out block stays in VMEM
         in_specs=[
-            pl.BlockSpec((fb, row_block),
-                         lambda i, r, s, bl: (i, _rb(r, s, bl))),
-            pl.BlockSpec((2, row_block),
-                         lambda i, r, s, bl: (0, _rb(r, s, bl))),
-            pl.BlockSpec((row_block,),
-                         lambda i, r, s, bl: (_rb(r, s, bl),)),
+            pl.BlockSpec((fb, row_block), lambda i, r, t, bl: (i, bl[r])),
+            pl.BlockSpec((2, row_block), lambda i, r, t, bl: (0, bl[r])),
+            pl.BlockSpec((row_block,), lambda i, r, t, bl: (bl[r],)),
         ],
         out_specs=pl.BlockSpec((1, fb // MM_FEATS, M_ROWS, N_COLS),
-                               lambda i, r, s, bl: (i, 0, 0, 0)),
+                               lambda i, r, t, bl: (i, 0, 0, 0)),
     )
     out = pl.pallas_call(
         _hist_kernel_blocklist,
@@ -306,5 +280,5 @@ def leaf_histogram_blocklist(bins_t: jax.Array, gh2: jax.Array,
             (groups, fb // MM_FEATS, M_ROWS, N_COLS), jnp.float32),
         interpret=interpret,
         name="leaf_histogram_blocklist",
-    )(info, blist, bins_t, gh2, leaf_eff)
+    )(target, blist, bins_t, gh2, leaf_eff)
     return _diag_hist_xla(out, fpad)[:f, :max_bin, :]

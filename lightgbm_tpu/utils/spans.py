@@ -47,7 +47,8 @@ DEVICE_SCOPES = (
 SEGMENT = "lgbm.segment"              # iter, k: one train_segment / iteration
 HOST_INPUTS = "lgbm.host_inputs"      # plan, bagging, masks and their upload
 ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call
-FLUSH = "lgbm.flush"                  # trees, bytes: _flush_pending
+FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
+#                                       grid_rows: _flush_pending
 FLUSH_PULL = "lgbm.flush_pull"        # the device_get (host waits for device)
 FLUSH_UNPACK = "lgbm.flush_unpack"    # _unpack_tree loop, stump truncation
 EVAL = "lgbm.eval"                    # iter: metrics and early stopping

@@ -31,7 +31,7 @@ PARAMS = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
           "hist_reorder_every": 3, "iter_batch": 2}
 
 
-def _steps_of_a_training_job(monkeypatch):
+def _steps_of_a_training_job(monkeypatch, n=N, f=F, **extra):
     """(make, argument shapes) of every fused executable that three
     rounds of the ordered path dispatch on this host."""
     steps = []
@@ -47,10 +47,10 @@ def _steps_of_a_training_job(monkeypatch):
         return call
 
     rng = np.random.RandomState(7)
-    x = rng.randn(N, F).astype(np.float32)
-    y = (x[:, 0] + x[:, 1] * x[:, 2] + 0.3 * rng.randn(N) > 0)
+    x = rng.randn(n, f).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] * x[:, 2] + 0.3 * rng.randn(n) > 0)
     monkeypatch.setattr(gbdt, "_get_fused_step", recording)
-    lgb.train(PARAMS, lgb.Dataset(x, label=y.astype(np.float32)),
+    lgb.train({**PARAMS, **extra}, lgb.Dataset(x, label=y.astype(np.float32)),
               num_boost_round=3)
     return steps
 

@@ -1156,10 +1156,12 @@ def test_multihost_feature_parallel_two_process(tmp_path):
 
 def test_ordered_mode_data_parallel_matches_serial():
     """Ordered-partition growth under tree_learner=data (VERDICT r3 #2):
-    the fused shard_map step with SHARD-LOCAL row re-sorts and the
-    pmax-uniform ladder rung must grow the same trees as the serial
-    ordered learner, for both histogram aggregation protocols, with
-    bagging + feature_fraction composed."""
+    the fused shard_map step with SHARD-LOCAL row re-sorts and block
+    lists, each shard's sweep kernel running to its OWN occupied-block
+    count with no collective before it (tests/test_sweep_counter.py
+    recounts them), must grow the same trees as the serial ordered
+    learner, for both histogram aggregation protocols, with bagging +
+    feature_fraction composed."""
     import lightgbm_tpu as lgb
     n = 8192 * 2
     rng = np.random.RandomState(4)

@@ -52,14 +52,47 @@ def _pads_of_u8(low):
 @pytest.mark.parametrize("f", [8, 13, 28, 39, 48, 136])
 def test_default_path_kernels_lower_for_tpu(f, max_bin):
     nblocks = N // hp.PALLAS_ROW_BLOCK
-    for fn, more, statics in (
-            (hp.leaf_histogram_masked, (), {}),
-            (hp.leaf_histogram_blocklist, (S((nblocks,), jnp.int32), I32),
-             {"grid_blocks": 8})):
-        low = _lower_for_tpu(fn, _sweep_args(f) + more, max_bin=max_bin,
-                             **statics)
+    for fn, more in (
+            (hp.leaf_histogram_masked, ()),
+            # the block list and its length: the grid's row bound, traced
+            (hp.leaf_histogram_blocklist, (S((nblocks,), jnp.int32), I32))):
+        low = _lower_for_tpu(fn, _sweep_args(f) + more, max_bin=max_bin)
         assert "tpu_custom_call" in low.as_text(), fn
         assert not _pads_of_u8(low), (fn, _pads_of_u8(low))
+
+
+@pytest.mark.parametrize("learner", ["serial", "data"])
+def test_one_blocklist_kernel_a_step(learner, monkeypatch, traces_forgotten):
+    """The re-sort step and the K scan of the ordered path, lowered for
+    the TPU at 9 row blocks a shard: ONE leaf_histogram_blocklist kernel
+    an executable, called from the root's sweep and the per-split sweep,
+    its grid bounded at run time by the leaf's own block count.  (A
+    ladder of compiled grid sizes held a kernel a rung, two at 9 blocks
+    and three from 33 on, under a `lax.switch` at both call sites.)
+    Under tree_learner=data no collective stands before a sweep: each
+    shard's kernel runs to its own count, so `lgbm.block_list` holds no
+    `pmax` (the rung's agreement) and the histogram `psum` is the
+    exchange's only kind.  The run-time bound shipped, so both halves
+    are checked."""
+    shards = {"data": 4, "serial": 1}[learner]
+    extra = ({"tree_learner": "data", "num_shards": shards}
+             if shards > 1 else {})
+    steps = _steps_of_a_training_job(
+        monkeypatch, n=shards * 9 * hp.PALLAS_ROW_BLOCK, f=6, **extra)
+    assert len(steps) == 2, len(steps)      # the re-sort step, a K=2 scan
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for make, shapes in steps:
+        text = make().trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+        assert text.count("@tpu_custom_call") == 1
+        assert len(re.findall(
+            r"func\.func private @leaf_histogram_blocklist", text)) == 1
+        assert text.count("call @leaf_histogram_blocklist") == 2
+        exchanged = {name.rsplit("/", 1)[-1]
+                     for name in re.findall(r'loc\("([^"]*)"', text)
+                     if "lgbm.hist_exchange/" in name}
+        assert exchanged == ({"psum", "add"} if shards > 1 else set())
 
 
 def _ops_under(text, scope):
