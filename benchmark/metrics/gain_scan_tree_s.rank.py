@@ -1,0 +1,10 @@
+"""`gain_scan_tree_s` of a ranking cell: device seconds a tree of the traced
+window spent under `lgbm.gain_scan`.
+Grouped in harness/scopes_ranked.json; nothing where the trace has
+nothing of it to read (harness/scopes_ranked.py)."""
+
+from harness import scopes_ranked
+
+
+def read(record: dict):
+    return scopes_ranked.tree_seconds(record, "gain_scan_tree_s.rank")

@@ -2254,6 +2254,9 @@ class GBDT:
                                             for m in pending))
             if wire is not None:
                 stats["exchange_bytes"] = wire
+            if self.objective is not None:
+                # what one tree's gradients cost (lambdarank's pair pass)
+                stats.update(self.objective.trace_counters())
             flush_span.set_metadata(**stats)
             with TraceAnnotation(spans.FLUSH_UNPACK):
                 self._unpack_pending()

@@ -30,7 +30,7 @@ from . import native
 from .analysis.contracts import contract
 from .config import Config
 from .io.dataset import Metadata
-from .utils import log
+from .utils import log, spans
 
 K_EPSILON = 1e-15
 K_MIN_SCORE = -np.inf
@@ -56,6 +56,9 @@ class Objective:
     row_shardable = False
     name = "none"
     num_class = 1
+    # integer counts of what ONE tree's gradients cost, set when the state
+    # is built (lambdarank's pair pass); nothing for elementwise objectives
+    _counters: dict = {}
 
     def init(self, metadata: Metadata, num_data: int) -> None:
         self.metadata = metadata
@@ -157,6 +160,12 @@ class Objective:
         shard-major for `layout` plus one PartitionSpec per leaf.  Only
         called when shard_layout returned a layout."""
         raise NotImplementedError
+
+    def trace_counters(self) -> dict:
+        """What ONE tree's gradients cost, as integer counts;
+        models/gbdt.py _flush_pending carries them as stats of its
+        lgbm.flush span."""
+        return dict(self._counters)
 
     def convert_output(self, score: np.ndarray) -> np.ndarray:
         """Final transform for human-facing predictions."""
@@ -485,6 +494,7 @@ class LambdarankNDCG(Objective):
             a, ln = int(qb[q]), int(qlen[q])
             row_slot[a:a + ln] = q * lmax + np.arange(ln)
 
+        self._counters = _pair_counters(qlen, nb * q_block, lmax)
         shp = (nb, q_block)
         self._dev_state = (
             jnp.asarray(doc_idx.reshape(shp + (lmax,))),
@@ -604,6 +614,7 @@ class LambdarankNDCG(Objective):
                 row_slot[s, a - base:a - base + ln] = (
                     qi * lmax + np.arange(ln, dtype=np.int64))
 
+        self._counters = _pair_counters(qlen, S * nq_pad, lmax)
         shp = (S * nb, q_block)
         host = (doc_idx.reshape(shp + (lmax,)),
                 lab.reshape(shp + (lmax,)),
@@ -650,41 +661,45 @@ class LambdarankNDCG(Objective):
             def block(_, xs):
                 di, lb, gn, iv, wb = xs
                 valid = lb >= 0
-                s = score[di]                           # [QB, L]
-                s_sort = jnp.where(valid, s, -jnp.inf)
-                # stable descending sort: first-by-score, ties by index
-                # (reference uses non-stable std::sort — PARITY.md)
-                order = jnp.argsort(-s_sort, axis=-1)
-                rank_of = jnp.argsort(order, axis=-1)
-                dsc = disc_table[jnp.minimum(rank_of, n_disc - 1)]
-                dsc = jnp.where(valid, dsc, 0.0)
-                best = jnp.max(s_sort, axis=-1)
-                worst = jnp.min(jnp.where(valid, s, jnp.inf), axis=-1)
-                norm = (best != worst)[:, None, None]
-                ds = s[:, :, None] - s[:, None, :]      # [QB, L, L]
-                vp = ((lb[:, :, None] > lb[:, None, :])
-                      & valid[:, :, None] & valid[:, None, :])
-                delta = ((gn[:, :, None] - gn[:, None, :])
-                         * jnp.abs(dsc[:, :, None] - dsc[:, None, :])
-                         * iv[:, None, None])
-                delta = jnp.where(
-                    norm, delta / (jnp.float32(0.01) + jnp.abs(ds)), delta)
-                # direct sigmoid: the reference's 1M-entry lookup table
-                # (rank_objective.hpp:175-189) is a CPU-era optimization;
-                # a random gather of [QB, L, L] indices serializes on TPU
-                # while the VPU computes exp at full rate.  Values differ
-                # from the table path only by its quantization (~2.5e-5).
-                p_lam = (jnp.float32(2.0)
-                         / (jnp.float32(1.0)
-                            + jnp.exp(jnp.float32(2.0 * sigmoid) * ds)))
-                p_hess = p_lam * (jnp.float32(2.0) - p_lam)
-                p_lam = jnp.where(vp, p_lam * -delta, 0.0)
-                p_hess = jnp.where(vp, p_hess * jnp.float32(2.0) * delta,
-                                   0.0)
-                lam_doc = p_lam.sum(axis=2) - p_lam.sum(axis=1)
-                hess_doc = p_hess.sum(axis=2) + p_hess.sum(axis=1)
-                lam_doc = jnp.where(valid, lam_doc * wb, 0.0)
-                hess_doc = jnp.where(valid, hess_doc * wb, 0.0)
+                with jax.named_scope(spans.RANK_GATHER):
+                    s = score[di]                       # [QB, L]
+                with jax.named_scope(spans.RANK_SORT):
+                    s_sort = jnp.where(valid, s, -jnp.inf)
+                    # stable descending sort: first-by-score, ties by
+                    # index (reference uses non-stable std::sort —
+                    # PARITY.md)
+                    order = jnp.argsort(-s_sort, axis=-1)
+                    rank_of = jnp.argsort(order, axis=-1)
+                    dsc = disc_table[jnp.minimum(rank_of, n_disc - 1)]
+                    dsc = jnp.where(valid, dsc, 0.0)
+                with jax.named_scope(spans.RANK_PAIRS):
+                    best = jnp.max(s_sort, axis=-1)
+                    worst = jnp.min(jnp.where(valid, s, jnp.inf), axis=-1)
+                    norm = (best != worst)[:, None, None]
+                    ds = s[:, :, None] - s[:, None, :]      # [QB, L, L]
+                    vp = ((lb[:, :, None] > lb[:, None, :])
+                          & valid[:, :, None] & valid[:, None, :])
+                    delta = ((gn[:, :, None] - gn[:, None, :])
+                             * jnp.abs(dsc[:, :, None] - dsc[:, None, :])
+                             * iv[:, None, None])
+                    delta = jnp.where(
+                        norm, delta / (jnp.float32(0.01) + jnp.abs(ds)), delta)
+                    # direct sigmoid: the reference's 1M-entry lookup table
+                    # (rank_objective.hpp:175-189) is a CPU-era optimization;
+                    # a random gather of [QB, L, L] indices serializes on TPU
+                    # while the VPU computes exp at full rate.  Values differ
+                    # from the table path only by its quantization (~2.5e-5).
+                    p_lam = (jnp.float32(2.0)
+                             / (jnp.float32(1.0)
+                                + jnp.exp(jnp.float32(2.0 * sigmoid) * ds)))
+                    p_hess = p_lam * (jnp.float32(2.0) - p_lam)
+                    p_lam = jnp.where(vp, p_lam * -delta, 0.0)
+                    p_hess = jnp.where(vp, p_hess * jnp.float32(2.0) * delta,
+                                       0.0)
+                    lam_doc = p_lam.sum(axis=2) - p_lam.sum(axis=1)
+                    hess_doc = p_hess.sum(axis=2) + p_hess.sum(axis=1)
+                    lam_doc = jnp.where(valid, lam_doc * wb, 0.0)
+                    hess_doc = jnp.where(valid, hess_doc * wb, 0.0)
                 return None, (lam_doc, hess_doc)
 
             _, (lam_b, hes_b) = jax.lax.scan(
@@ -694,10 +709,11 @@ class LambdarankNDCG(Objective):
             # Padded rows carry the DEAD slot (pad_to) and read the
             # appended zero cell — no positional live-row assumption, so
             # the mapping survives ordered-partition row permutations.
-            zero = jnp.zeros((1,), dtype=jnp.float32)
-            lam_flat = jnp.concatenate([lam_b.reshape(-1), zero])
-            hes_flat = jnp.concatenate([hes_b.reshape(-1), zero])
-            return lam_flat[row_slot], hes_flat[row_slot]
+            with jax.named_scope(spans.RANK_GATHER):
+                zero = jnp.zeros((1,), dtype=jnp.float32)
+                lam_flat = jnp.concatenate([lam_b.reshape(-1), zero])
+                hes_flat = jnp.concatenate([hes_b.reshape(-1), zero])
+                return lam_flat[row_slot], hes_flat[row_slot]
 
         return grad_fn
 
@@ -776,6 +792,15 @@ class LambdarankNDCG(Objective):
         p_hess = np.where(valid, p_hess, 0.0).astype(np.float32)
         lambdas += p_lambda.sum(axis=1) - p_lambda.sum(axis=0)
         hessians += p_hess.sum(axis=1) + p_hess.sum(axis=0)
+
+
+def _pair_counters(qlen: np.ndarray, padded_queries: int, lmax: int) -> dict:
+    """What the device path's pair pass costs a tree: the [L, L] cells it
+    evaluates over the padded query blocks (all shards) against the cells
+    the queries hold, sum L_q^2."""
+    return {"pairs_padded": int(padded_queries) * lmax * lmax,
+            "pairs_real": int((qlen.astype(np.int64) ** 2).sum()),
+            "queries": int(len(qlen)), "lmax": int(lmax)}
 
 
 def default_label_gain():

@@ -10,8 +10,8 @@ flag check with the profiler off); their keyword arguments become the
 event's stats, so each count travels with its span.  Both land in the one
 `.xplane.pb` of `jax.profiler.trace(dir)`, on one clock;
 `benchmark/phase_table.py <dir>` prints the table (the benchmark keeps its
-own copy of these names, `benchmark/harness/scopes.json`;
-tests/test_spans.py holds the two equal).
+own copy of these names, `benchmark/harness/scopes.json` and
+`scopes_ranked.json`; tests/test_spans.py holds their union equal).
 
 Use the constants at the sites, never a string literal: the test greps
 for `lgbm.` names outside this registry.
@@ -37,18 +37,29 @@ PACK_TREE = "lgbm.pack_tree"          # _pack_tree
 RESORT = "lgbm.resort"                # ordered-partition row re-sort
 BAG_ARRANGE = "lgbm.bag_arrange"      # in-bag-first arrangement
 DART_BANK = "lgbm.dart_bank"          # DART drop, normalise, bank write
+# lambdarank's gradients (objectives.py), nested INSIDE lgbm.objective: a
+# reader that takes the last component sees them apart, and what stays
+# under lgbm.objective alone is the casts and the loop's plumbing
+RANK_GATHER = "lgbm.rank_gather"      # score[doc_idx], the two row_slot gathers
+RANK_SORT = "lgbm.rank_sort"          # the two argsorts, the discount look-up
+RANK_PAIRS = "lgbm.rank_pairs"        # everything [QB, L, L] and its sums
 
 DEVICE_SCOPES = (
     OBJECTIVE, GROW, HIST_ROOT, BLOCK_LIST, HIST_SWEEP, HIST_POOL,
     HIST_EXCHANGE, GAIN_SCAN, PARTITION, TREE_UPDATE, OOB_DESCENT,
-    SCORE_UPDATE, VALID_UPDATE, PACK_TREE, RESORT, BAG_ARRANGE, DART_BANK)
+    SCORE_UPDATE, VALID_UPDATE, PACK_TREE, RESORT, BAG_ARRANGE, DART_BANK,
+    RANK_GATHER, RANK_SORT, RANK_PAIRS)
 
 # -- host spans (models/gbdt.py segment loop), with their stats ------------
 SEGMENT = "lgbm.segment"              # iter, k: one train_segment / iteration
 HOST_INPUTS = "lgbm.host_inputs"      # plan, bagging, masks and their upload
 ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call
 FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
-#                                       grid_rows: _flush_pending
+#                                       grid_rows, and the objective's own
+#                                       counters (Objective.trace_counters:
+#                                       lambdarank's pairs_padded, pairs_real,
+#                                       queries, lmax, each what ONE tree
+#                                       costs): _flush_pending
 FLUSH_PULL = "lgbm.flush_pull"        # the device_get (host waits for device)
 FLUSH_UNPACK = "lgbm.flush_unpack"    # _unpack_tree loop, stump truncation
 EVAL = "lgbm.eval"                    # iter: metrics and early stopping

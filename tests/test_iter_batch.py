@@ -16,6 +16,7 @@ stopping at the same iteration.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -64,6 +65,38 @@ def _model_text(params, x, y, group=None, rounds=10):
 # the parity matrix: objectives x K, plain and bagged
 # ---------------------------------------------------------------------------
 
+# float32 ulps by which a float of a LAMBDARANK model may differ between
+# K=1 and K>1.  A document's lambda is `p_lam.sum(axis=2) -
+# p_lam.sum(axis=1)` over the query's [L, L] pair terms
+# (objectives.py make_grad_fn).  Outside a loop XLA:CPU fuses the two
+# reductions and their shared producer into ONE loop nest; in the body of
+# the K-scan's `while` it emits each reduction as a fusion of its own,
+# and the axis-1 sum then adds in another order.  Each sum moves by a few
+# ulps, their DIFFERENCE (the two nearly cancel) by up to 7e-5 of itself
+# at L = 16, and leaf values and gains, ratios of sums of those over >= 20
+# rows, by up to 2.5e-5 (424 ulps) in these jobs.  Every K > 1 agrees with
+# every other to the byte; splits, thresholds and counts agree with K=1's.
+# Pinning the order would mean materialising the pair tensors
+# (`optimization_barrier`), 0.1 s a tree of HBM traffic at the benchmark's
+# ranking cell: not free, so the comparison states its width (PERF.md
+# section 6, PR 31; ROADMAP D9).
+RANK_MODEL_ULPS = 1024
+
+
+def _assert_model_matches(got, oracle, objective, what):
+    if objective != "lambdarank":
+        assert got == oracle, what
+        return
+    a, b = (re.split(r"[\s=]+", t) for t in (got, oracle))
+    assert len(a) == len(b), what
+    width = RANK_MODEL_ULPS * float(np.finfo(np.float32).eps)
+    for u, v in zip(a, b):
+        if u != v:      # every other token, names and counts, is equal
+            fu, fv = float(u), float(v)
+            assert abs(fu - fv) <= width * max(abs(fu), abs(fv)), (
+                what, u, v)
+
+
 @pytest.mark.parametrize("objective",
                          ["binary", "regression", "multiclass",
                           "lambdarank"])
@@ -77,8 +110,8 @@ def test_batched_matches_oracle(objective):
     oracle = _model_text({**base, "iter_batch": "1"}, x, y, group)
     for k in ("2", "8", "3"):
         got = _model_text({**base, "iter_batch": k}, x, y, group)
-        assert got == oracle, "iter_batch=%s diverged (%s)" % (
-            k, objective)
+        _assert_model_matches(got, oracle, objective,
+                              "iter_batch=%s diverged (%s)" % (k, objective))
 
 
 @pytest.mark.parametrize("objective", ["binary", "multiclass"])
@@ -134,7 +167,7 @@ def test_batched_data_parallel_matches_oracle(objective):
                          rounds=6)
     got = _model_text({**base, "iter_batch": "4"}, x, y, group,
                       rounds=6)
-    assert got == oracle
+    _assert_model_matches(got, oracle, objective, "data-parallel K=4")
 
 
 def test_batched_ordered_reorder_scan_matches_oracle():

@@ -29,6 +29,7 @@ SCOPE_RE = re.compile(r"lgbm\.[a-z_]+")
 CORE = {spans.OBJECTIVE, spans.GROW, spans.HIST_ROOT, spans.HIST_SWEEP,
         spans.HIST_POOL, spans.GAIN_SCAN, spans.PARTITION,
         spans.TREE_UPDATE, spans.SCORE_UPDATE, spans.PACK_TREE}
+RANK = (spans.RANK_GATHER, spans.RANK_SORT, spans.RANK_PAIRS)
 PATHS = {
     # name: (params, classes, with a validation set, scopes beyond CORE)
     "plain": ({}, 1, True, {spans.VALID_UPDATE}),
@@ -43,6 +44,10 @@ PATHS = {
     "bagged": ({"bagging_fraction": 0.5, "bagging_freq": 1,
                 "bag_compact": "on"}, 1, False,
                {spans.OOB_DESCENT, spans.BAG_ARRANGE}),
+    # graded labels in queries of 16; the ordered path, as on the chip
+    "lambdarank": ({"objective": "lambdarank", "hist_impl": "pallas",
+                    "hist_reorder_every": 2}, 3, False,
+                   {spans.BLOCK_LIST, spans.RESORT} | set(RANK)),
 }
 
 
@@ -59,14 +64,18 @@ def _train(extra, classes, valid, rounds):
     x, y = _data(classes)
     params = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
               "verbose": -1, "device_type": "cpu", **extra}
-    train = lgb.Dataset(x, label=y)
+    group = (np.full(len(y) // 16, 16, np.int32)
+             if params["objective"] == "lambdarank" else None)
+    train = lgb.Dataset(x, label=y, group=group)
     sets = [lgb.Dataset(x[:512], label=y[:512], reference=train)] * valid
     return lgb.train(params, train, num_boost_round=rounds, valid_sets=sets)
 
 
-def _lowered_texts(path, monkeypatch):
+def _lowered_texts(path, monkeypatch, compiled=False):
     """Three rounds of the path; -> the lowered text of every executable
-    it dispatched."""
+    it dispatched (`compiled`: the optimised HLO's, whose `op_name`s hold
+    the whole name stack where a lowering's private functions name their
+    operations from their own start)."""
     extra, classes, valid, _ = PATHS[path]
     texts = []
     cached = gbdt._get_fused_step
@@ -75,7 +84,9 @@ def _lowered_texts(path, monkeypatch):
         fn = cached(key, make)
 
         def call(*args):
-            texts.append(fn.lower(*args).as_text(debug_info=True))
+            low = fn.lower(*args)
+            texts.append(low.compile().as_text() if compiled
+                         else low.as_text(debug_info=True))
             return fn(*args)
         return call
 
@@ -93,6 +104,35 @@ def test_step_carries_its_scopes(path, monkeypatch):
     found = set(SCOPE_RE.findall(_lowered_texts(path, monkeypatch)))
     assert CORE | more <= found, sorted((CORE | more) - found)
     assert found <= set(spans.DEVICE_SCOPES), found
+    # no other objective enters lambdarank's scopes
+    assert not (found & set(RANK)) - more, found
+
+
+def test_rank_scopes_nest_inside_the_objective(monkeypatch):
+    """lambdarank's three scopes lower under their names INSIDE
+    `lgbm.objective`, in the re-sort step and in the scan alike: a reader
+    that takes the last `lgbm.*` component sees the gathers, the sorts and
+    the pair pass apart, and one that asks for `lgbm.objective` anywhere in
+    the stack still finds all of the objective.  Each holds the operations
+    it is named for."""
+    text = _lowered_texts("lambdarank", monkeypatch, compiled=True)
+    # (a reducer's own body names its one `add` from the reduce's start)
+    names = {n for n in re.findall(r'op_name="([^"]*)"', text)
+             if n.startswith("jit(")}
+    for scope in RANK:
+        inside = [n for n in names if scope in n]
+        assert inside, scope
+        strays = [n for n in inside
+                  if "/%s/" % spans.OBJECTIVE not in n.split(scope)[0]]
+        assert not strays, strays
+
+    def ops(scope):
+        return {n.rsplit("/", 1)[-1] for n in names
+                if "/%s/" % scope in n}
+    assert "gather" in ops(spans.RANK_GATHER)
+    assert {"sort", "gather"} <= ops(spans.RANK_SORT)
+    assert {"exp", "reduce_sum"} <= ops(spans.RANK_PAIRS)
+    assert "sort" not in ops(spans.RANK_PAIRS) | ops(spans.RANK_GATHER)
 
 
 @pytest.mark.parametrize("path,scope", [("reorder", spans.RESORT),
@@ -242,15 +282,25 @@ def test_kernel_is_named_after_its_wrapper(wrapper):
 
 
 # -- the benchmark's copy --------------------------------------------------
+def _benchmark_names(name):
+    with open(os.path.join(ROOT, "benchmark", "harness", name)) as fh:
+        return json.load(fh)
+
+
 @pytest.mark.parametrize("key,ours", [
     ("device_scopes", spans.DEVICE_SCOPES),
     ("host_spans", spans.HOST_SPANS),
     ("enqueue_kinds", spans.ENQUEUE_KINDS)])
 def test_benchmark_copy_is_equal(key, ours):
-    with open(os.path.join(ROOT, "benchmark", "harness",
-                           "scopes.json")) as fh:
-        theirs = json.load(fh)
-    assert tuple(theirs[key]) == ours
-    if key == "device_scopes":      # every scope feeds exactly one metric
-        grouped = [s for g in theirs["device_groups"].values() for s in g]
+    """The program's lists equal the UNION of the benchmark's scope files
+    (`scopes.json`, accepted and not edited, and `scopes_ranked.json`,
+    what the ranking cell added), and in each file's grouping every scope
+    a cell of that file can show feeds exactly one of its metrics."""
+    base = _benchmark_names("scopes.json")
+    ranked = _benchmark_names("scopes_ranked.json")
+    assert tuple(base[key]) + tuple(ranked.get(key, ())) == ours
+    if key == "device_scopes":
+        grouped = [s for g in base["device_groups"].values() for s in g]
+        assert sorted(grouped) == sorted(base[key])
+        grouped = [s for g in ranked["device_groups"].values() for s in g]
         assert sorted(grouped) == sorted(ours)
