@@ -2204,6 +2204,20 @@ class GBDT:
             f = -(-f // self.grower.num_shards) * self.grower.num_shards
         return leaves * f * self.max_bin * 3 * np.dtype(self.dtype).itemsize
 
+    def _sweep_grid(self) -> Tuple[int, int]:
+        """(feature groups, block-diagonal matmuls) of ONE row step of a
+        Pallas sweep over the bin matrix a shard holds (lgbm.flush):
+        grid_rows x the first is the grid steps the flushed trees'
+        block-list sweeps ran, x the second their matmuls.  Static, from
+        F alone (ops/hist_pallas.py row_step); zeros off the kernels."""
+        if self.hist_impl != "pallas":
+            return 0, 0
+        from ..ops.hist_pallas import row_step
+        f = self.train_data.num_features
+        if self.config.tree_learner == "feature":
+            f = self.grower.padded_features(f) // self.grower.num_shards
+        return row_step(f)
+
     @contract.counted_flush
     def _flush_pending(self) -> bool:
         """Unpack pending device trees; truncate at the first 1-leaf stump
@@ -2250,6 +2264,7 @@ class GBDT:
             # what its block-list sweeps cost (ops/grow.py TreeArrays)
             stats = {"blocks_swept": sum(int(m.ints[-2]) for m in pending),
                      "grid_rows": sum(int(m.ints[-1]) for m in pending)}
+            stats["feat_groups"], stats["block_matmuls"] = self._sweep_grid()
             wire = self._exchange_bytes(sum(int(m.ints[0])
                                             for m in pending))
             if wire is not None:

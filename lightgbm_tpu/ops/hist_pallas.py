@@ -4,7 +4,8 @@ The XLA formulation (ops/histogram.py) materializes per-feature one-hot
 matrices in HBM (~N*B bytes per feature per split), which dominates at
 scale.  This kernel uses a radix decomposition bin = hi*32 + lo and packs
 MM_FEATS=4 features into ONE block-diagonal MXU matmul (a grid step
-covers _feat_block(F) <= MAX_FEAT_BLOCK features, several matmuls):
+covers _feat_block(F) <= FEAT_BLOCK_CAP features, a quarter as many
+matmuls, the block chosen from F: 39 features are ONE block of 40):
 
     lhs[(f, c, hi), r] = gh3[c, r] * (bins_hi[f, r] == hi)   [96, blk]
     rhs[r, (f, lo)]    = (bins_lo[f, r] == lo)               [blk, 128]
@@ -23,12 +24,13 @@ never equal a target leaf).  The (leaf_eff == target) mask is computed
 in-kernel, so per-split traffic is bins + gh2 + leaf_eff only — no [N]
 per-split gvals materialization.  The bin matrix is read as it lies in
 HBM: F need not divide the feature block, the last block then runs past
-the array (39 features: rows 32-47 of 39), and no wrapper copies the
-matrix to whole blocks.  What the rows past the array hold is
+the array (39 features: row 39 of a block of 40), and no wrapper copies
+the matrix to whole blocks.  What the rows past the array hold is
 unspecified and cannot reach a result (_feat_grid says why); the
 compiled kernels agree to the bit with the same kernels on a matrix
 padded by the caller, with zeros or with random bytes (TPU v5e, F = 13,
-28, 39, 47: PERF.md, PR 26).
+28, 39, 47: PERF.md, PR 26), and a sweep at any feature block with the
+sweep at blocks of 16 (F = 28, 39, 220, 2000: PERF.md, PR 32).
 
 What the chip does (TPU v5e, jax 0.9.0, measured in PR 21 — PERF.md
 Findings): the f32 dot runs at Mosaic's default matmul precision, which
@@ -62,11 +64,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-MAX_FEAT_BLOCK = 16   # features per grid step (gh2/leaf_eff stream from
-                      # HBM once per row block per GRID STEP, so wide
-                      # feature blocks amortize that traffic; sublane
-                      # tiling wants a multiple of 8)
 MM_FEATS = 4      # features per block-diagonal matmul
+# A grid step's feature block is chosen from F (_feat_block) by what a
+# step costs on the chip: a [96, 8192] x [8192, 128] matmul with its
+# one-hot operands, and what a step costs besides (the leaf mask, gh3,
+# the int32 bins, three block DMAs: gh2 and leaf_eff stream from HBM
+# once per row block per GRID STEP).  Read from full sweeps of F = 28,
+# 39, 220 and 2000 at feature blocks of 8 to 80: 20 readings, least
+# squares, the worst 0.14 us off the line (TPU v5e; PERF.md, PR 32).
+# Nanoseconds, whole numbers, so that equal costs ARE equal.
+T_MM_NS = 1051
+T_STEP_NS = 227
+# The widest block a step may take (a multiple of 8: sublane tiling).
+# Not what VMEM holds: Mosaic takes 256 features a step under the 16 MiB
+# scoped default, since the unrolled matmuls share their operand
+# buffers.  It is where the matmuls stop costing T_MM_NS: 1.06 us each
+# at 80 features a step, 1.95-1.99 at 88, 96 and 104 and 1.90 at 112,
+# at 64 and 100 MiB of vmem_limit_bytes too; as a fori_loop they cost
+# 1.14-1.18 at every width (same chip, same PR).
+FEAT_BLOCK_CAP = 80
 N_HI = 8
 N_LO = 32
 N_COMP = 3    # grad, hess, count
@@ -88,8 +104,20 @@ PALLAS_ROW_BLOCK = 8192   # rows per grid step; N must be a multiple —
 # scopes, being metadata, are not (tests/test_spans.py; PERF.md, PR 25).
 
 
+def _block_cost_ns(f: int, fb: int) -> int:
+    """What ONE row block of an [F, N] matrix costs swept fb features a
+    grid step: cdiv(F, fb) steps of fb / MM_FEATS matmuls each."""
+    return -(-f // fb) * (fb // MM_FEATS * T_MM_NS + T_STEP_NS)
+
+
 def _feat_block(f: int) -> int:
-    return min(MAX_FEAT_BLOCK, ((f + 7) // 8) * 8)
+    """The multiple of 8 up to FEAT_BLOCK_CAP that sweeps a row block of
+    F features cheapest, of equals the widest: 39 -> 40 (ten matmuls in
+    one step, where three blocks of 16 ran twelve in three), 220 -> 56
+    (the 56 matmuls of 14 blocks of 16, in four steps; 80 would run 60
+    in three)."""
+    return min(range(8, FEAT_BLOCK_CAP + 1, 8),
+               key=lambda fb: (_block_cost_ns(f, fb), -fb))
 
 
 def _feat_grid(f: int):
@@ -97,11 +125,12 @@ def _feat_grid(f: int):
     rounded up to it, and the grid's feature extent cdiv(F, fb).
 
     The kernels read the matrix IN PLACE: where fb does not divide F the
-    last feature block runs past the array (39 features: rows 32-47 of a
-    39-row array), and no wrapper pads it — a pad here is a copy of the
-    whole resident matrix at every split, which XLA does not hoist out of
-    the grow scan (16% of a tree at 68M x 39: PERF.md, PR 26).  What the
-    rows past the array hold is unspecified, and cannot reach a result:
+    last feature block runs past the array (39 features: row 39 of the
+    block of 40; 220: rows 220-223 of the fourth block of 56), and no
+    wrapper pads it — a pad here is a copy of the whole resident matrix
+    at every split, which XLA does not hoist out of the grow scan (16%
+    of a tree at 68M x 39: PERF.md, PR 26).  What the rows past the
+    array hold is unspecified, and cannot reach a result:
     a bin is a byte that the kernel turns into one-hot operands by
     integer compares (hi == iota, lo == iota), so ANY byte gives zeros
     and ones, never a NaN; in the block-diagonal
@@ -112,6 +141,14 @@ def _feat_grid(f: int):
     fb = _feat_block(f)
     groups = (f + fb - 1) // fb
     return fb, groups * fb, groups
+
+
+def row_step(f: int):
+    """(feature groups, block-diagonal matmuls) ONE row step of a sweep
+    over F features runs: the lgbm.flush stats feat_groups and
+    block_matmuls, by which grid_rows counts grid steps and matmuls."""
+    fb, _, groups = _feat_grid(f)
+    return groups, groups * fb // MM_FEATS
 
 
 def make_gh2(grad: jax.Array, hess: jax.Array) -> jax.Array:

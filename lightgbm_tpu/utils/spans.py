@@ -55,7 +55,9 @@ SEGMENT = "lgbm.segment"              # iter, k: one train_segment / iteration
 HOST_INPUTS = "lgbm.host_inputs"      # plan, bagging, masks and their upload
 ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call
 FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
-#                                       grid_rows, and the objective's own
+#                                       grid_rows, feat_groups, block_matmuls
+#                                       (a row step's feature groups and
+#                                       matmuls), and the objective's own
 #                                       counters (Objective.trace_counters:
 #                                       lambdarank's pairs_padded, pairs_real,
 #                                       queries, lmax, each what ONE tree

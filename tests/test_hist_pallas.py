@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 
 from lightgbm_tpu.ops.histogram import leaf_histogram, make_gvals
-from lightgbm_tpu.ops.hist_pallas import (PALLAS_ROW_BLOCK, _feat_grid,
+from lightgbm_tpu.ops.hist_pallas import (FEAT_BLOCK_CAP, MM_FEATS,
+                                          PALLAS_ROW_BLOCK, T_MM_NS,
+                                          T_STEP_NS, _feat_grid,
                                           fold_leaf_mask,
                                           leaf_histogram_blocklist,
                                           leaf_histogram_masked, make_gh2)
@@ -78,8 +80,9 @@ def test_ragged_feature_block_reads_bins_in_place(f, b, kernel):
     the same BITS as on a matrix the caller padded to whole blocks —
     with zeros, as the wrappers did, or with any other bytes: the rows
     past F reach only their own slices of the output, which are cut.
-    F = 8 is the control (one block that fits, nothing to pad); F = 136
-    is nine blocks with a ragged ninth."""
+    F = 8 is the control (one block that fits, nothing to pad); F = 13,
+    28, 39 and 47 are ONE block a little larger than the array; F = 136
+    is two blocks of 72 with a ragged second."""
     n, nblocks, target = 1024, 8, 3
     bins_t, grad, hess, _ = _data(n, f, b, seed=f + b)
     rng = np.random.RandomState(f)
@@ -90,8 +93,11 @@ def test_ragged_feature_block_reads_bins_in_place(f, b, kernel):
     bag = rng.rand(n) < 0.8
     gh2 = make_gh2(jnp.asarray(grad), jnp.asarray(hess))
     leaf_eff = fold_leaf_mask(jnp.asarray(leaf_id), jnp.asarray(bag))
-    fb, fpad, _ = _feat_grid(f)
+    fb, fpad, groups = _feat_grid(f)
+    # the block is chosen from F: one block wherever the cap holds F
+    # rounded up to 8, and never a block that lies wholly past the array
     assert (fpad > f) == (f % fb != 0) == (f != 8)
+    assert (groups == 1) == (f <= FEAT_BLOCK_CAP) and fpad - f < fb
     got = _sweep(kernel, jnp.asarray(bins_t), gh2, leaf_eff,
                  jnp.int32(target), nblocks, b)
     assert got.shape == (f, b, 3)
@@ -107,6 +113,56 @@ def test_ragged_feature_block_reads_bins_in_place(f, b, kernel):
     want = leaf_histogram(jnp.asarray(bins_t), gv, max_bin=b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("f", [8, 13, 28, 39, 47, 136, 220, 2000])
+def test_feature_block_is_chosen_from_f(f):
+    """_feat_grid takes, of the multiples of 8 up to the cap, the block
+    whose modelled cost of a row block (cdiv(F, fb) steps of fb / 4
+    matmuls) is least, and of equals the widest."""
+    fb, fpad, groups = _feat_grid(f)
+    assert fb % 8 == 0 and 8 <= fb <= FEAT_BLOCK_CAP
+    assert groups == -(-f // fb) and fpad == groups * fb
+    assert fpad - fb < f <= fpad        # covers F, no block wholly past it
+
+    def cost(b):
+        return -(-f // b) * (b // MM_FEATS * T_MM_NS + T_STEP_NS)
+
+    admissible = range(8, FEAT_BLOCK_CAP + 1, 8)
+    least = min(cost(b) for b in admissible)
+    assert cost(fb) == least
+    assert fb == max(b for b in admissible if cost(b) == least)
+    if f == 39:     # one group of 40: ten matmuls, one step a row block
+        assert (fb, fpad, groups) == (40, 40, 1)
+    if f == 220:    # no more matmuls or steps than 14 blocks of 16 ran
+        assert fpad // MM_FEATS <= 56 and groups <= 14
+
+
+@pytest.mark.parametrize("kernel", ["masked", "blocklist"])
+@pytest.mark.parametrize("f", [39, 47])
+def test_wide_block_gives_the_bits_of_sixteen_feature_slices(f, kernel):
+    """One wide feature block gives, bit for bit, what blocks of 16 gave
+    (the grouping before the block was chosen from F), here as sweeps of
+    the matrix's 16-feature slices: a feature's histogram is the diagonal
+    block of ITS matmul f // 4, slot f % 4, whatever else the step
+    holds."""
+    n, nblocks, b, target = 1024, 8, 255, 3
+    bins_t, grad, hess, _ = _data(n, f, b, seed=f)
+    rng = np.random.RandomState(f)
+    leaf_id = rng.randint(2, 5, size=n).astype(np.int32)
+    leaf_id[:128] = 0
+    leaf_id[-128:] = 0
+    gh2 = make_gh2(jnp.asarray(grad), jnp.asarray(hess))
+    leaf_eff = fold_leaf_mask(jnp.asarray(leaf_id),
+                              jnp.asarray(rng.rand(n) < 0.8))
+    assert _feat_grid(f)[2] == 1 and _feat_grid(f)[0] > 16
+    whole = _sweep(kernel, jnp.asarray(bins_t), gh2, leaf_eff,
+                   jnp.int32(target), nblocks, b)
+    slices = [_sweep(kernel, jnp.asarray(bins_t[i:i + 16]), gh2, leaf_eff,
+                     jnp.int32(target), nblocks, b)
+              for i in range(0, f, 16)]
+    assert float(jnp.abs(whole).max()) > 0.0
+    assert jnp.array_equal(whole, jnp.concatenate(slices))
 
 
 def test_masked_kernel_empty_leaf():

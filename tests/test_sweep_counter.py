@@ -9,7 +9,10 @@ trees 0, R, 2R, ..., shard by shard under tree_learner=data), and counts
 per sweep (the root's, then each split's smaller child) the row blocks
 of each shard that hold a row of the swept leaf.  The kernel's grid runs
 that many row steps, and one where a shard holds none: a run-time bound,
-no compiled worst case (PERF.md section 6, PR 30).
+no compiled worst case (PERF.md section 6, PR 30).  Beside them stand
+`feat_groups` and `block_matmuls`: what ONE row step costs in feature
+groups (grid steps) and block-diagonal matmuls, static, from F alone
+(ops/hist_pallas.py row_step; PR 32).
 """
 
 import jax
@@ -18,7 +21,7 @@ import pytest
 from test_spans import _program_spans
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.ops.hist_pallas import PALLAS_ROW_BLOCK
+from lightgbm_tpu.ops.hist_pallas import PALLAS_ROW_BLOCK, row_step
 from lightgbm_tpu.utils import spans
 
 LEAVES = 7
@@ -100,6 +103,10 @@ def test_flush_counts_the_blocks_the_sweeps_ran(shards, blocks, tmp_path):
     occupied, grid = _recount(gbdt.models, gbdt.train_data.bins, shards)
     assert sum(s["blocks_swept"] for s in flushes) == occupied.sum()
     assert sum(s["grid_rows"] for s in flushes) == grid.sum()
+    # what a row step costs: 6 features are one group of 8, two matmuls,
+    # the same on every shard (rows are sharded, features are not)
+    assert {(s["feat_groups"], s["block_matmuls"]) for s in flushes} \
+        == {row_step(x.shape[1])} == {(1, 2)}
     # clustered rows: far fewer than every block at every sweep
     assert occupied.sum() < ROUNDS * LEAVES * shards * blocks
     if shards == 1:
@@ -133,3 +140,7 @@ def test_counters_read_zero_off_the_block_list(extra, tmp_path):
     assert not booster._gbdt.hist_ranged
     assert sum(s["trees"] for s in flushes) == ROUNDS
     assert all(s["blocks_swept"] == s["grid_rows"] == 0 for s in flushes)
+    # the masked kernel runs the same feature grid (over every row
+    # block); the XLA sweep runs no kernel and counts none
+    want = (0, 0) if extra.get("hist_impl") == "xla" else (1, 2)
+    assert {(s["feat_groups"], s["block_matmuls"]) for s in flushes} == {want}

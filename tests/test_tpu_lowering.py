@@ -42,11 +42,12 @@ def _pads_of_u8(low):
 
 
 # both Pallas kernels: the masked full sweep and the ordered-partition
-# block-list sweep, at one / two / three / nine feature blocks.
-# F = 13, 28 and 39 do not divide their feature block (16): the last
-# block runs past the array (13: ONE block larger than the array; 39: a
-# ragged third), and the kernels read the matrix in place — no wrapper
-# pads it.  F = 8 and 48 divide their block and never had a pad: the
+# block-list sweep, at one feature block (F = 8 ... 48: blocks of 8, 16,
+# 32, 40 and 48, chosen from F) and at two (136: blocks of 72).
+# F = 13, 28, 39 and 136 do not fill their blocks: the last block runs
+# past the array (13, 28, 39: ONE block larger than the array; 136: a
+# ragged second), and the kernels read the matrix in place — no wrapper
+# pads it.  F = 8 and 48 fill their block and never had a pad: the
 # cases nothing changes for.
 @pytest.mark.parametrize("max_bin", [63, 255])
 @pytest.mark.parametrize("f", [8, 13, 28, 39, 48, 136])
