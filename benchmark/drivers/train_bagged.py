@@ -1,0 +1,168 @@
+"""Driver of kind `train_bagged`: `train.py`'s steady boosting on a booster
+that samples rows and features (`bagging_fraction`, `bagging_freq`,
+`feature_fraction` in the configuration's parameters).
+
+What differs from `train.py`: after each dispatch that opened a bagging
+epoch the driver keeps the program's bag (`GBDT.bag_mask()`, file order),
+packed to bits, and what the window produced goes with those bags to
+`harness/reference_bagged.py`, which checks them against upstream's stream
+and reads the comparison under sampling.  `in_bag_rows` of the record is
+the bag's size, so that the work of a tree is counted over in-bag rows.  The
+loop, the warm period, the window and the record are `train.py`'s, by
+import where a function stands alone there.
+"""
+
+from __future__ import annotations
+
+import gc
+import os
+import shutil
+import time
+from typing import Dict, List
+
+import numpy as np
+
+from drivers.train import (HOST_SPANS, WARM_PERIODS, CompileMeter,
+                           build_booster, peak_bytes, reduce_trace,
+                           tree_dict)
+from harness import reference, reference_bagged, trace as trace_mod
+from harness.data import make_rows
+# The cell needs a program that names its draws (and, with the span, draws a
+# bag of the cell's size in a second: the one before it took 30 s a bag, and
+# a run would outlast its limit).  A program without fails here, at import.
+from lightgbm_tpu.utils.spans import BAG_DRAW  # noqa: F401
+
+
+def drive(booster, trees: int, annotate, freq: int,
+          bags: Dict[int, np.ndarray]) -> List[int]:
+    """`train.drive`, keeping the bag of every epoch a dispatch opened (a
+    dispatch never crosses an epoch: the program's plan ends it there);
+    -> the trees of each dispatch."""
+    sizes: List[int] = []
+    while sum(sizes) < trees:
+        epoch = booster.iter // freq
+        with annotate("dispatch"):
+            stop, k = booster.train_segment(trees - sum(sizes), is_eval=False)
+        sizes.append(k)
+        if epoch not in bags:
+            bags[epoch] = np.packbits(booster.bag_mask())
+        if stop:
+            break
+    return sizes
+
+
+def run(cell, seed: int, seconds: float, trace: bool, t_process: float,
+        root: str, on_tpu: bool, break_booster=None,
+        control: bool = False) -> dict:
+    """-> the run's record, `train.run`'s.  `break_booster(booster)` is for
+    tests that plant a fault under the timed path; `control` has the
+    reference also compute the float8 control and judge it in the program's
+    place."""
+    import jax
+    from jax.profiler import TraceAnnotation
+    from lightgbm_tpu.models.gbdt import dispatch_count
+
+    config = cell.config
+    params = config["params"]
+    period = int(params["hist_reorder_every"])
+    freq = int(params["bagging_freq"])
+    meter = CompileMeter()
+    devices = jax.devices()[:cell.chips]
+
+    rows = make_rows(config["data"], int(config["num_data"]),
+                     int(params["max_bin"]), seed)
+    booster = build_booster(config, rows, on_tpu)
+    if break_booster is not None:
+        break_booster(booster)
+    flush = booster._flush_pending
+
+    def flush_span():
+        with TraceAnnotation("flush"):
+            return flush()
+    booster._flush_pending = flush_span
+
+    # warm-up, on the booster that is then timed
+    bags: Dict[int, np.ndarray] = {}
+    warm_trees = WARM_PERIODS * period
+    drive(booster, warm_trees, TraceAnnotation, freq, bags)
+    jax.block_until_ready(booster.scores)
+    setup_s = time.time() - t_process
+    setup_compile_s, compiles_before = meter.seconds, meter.count
+
+    trace_dir = os.path.join(root, ".bench_trace")
+    if trace:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        jax.profiler.start_trace(trace_dir)
+    d0 = dispatch_count()
+    periods = []
+    window_asked = 0
+    t0 = time.perf_counter()
+    while True:
+        p0 = time.perf_counter()
+        sizes = drive(booster, period, TraceAnnotation, freq, bags)
+        done = sum(sizes)
+        with TraceAnnotation("sync"):
+            jax.block_until_ready(booster.scores)
+        p1 = time.perf_counter()
+        window_asked += period
+        periods.append((p0, p1, done))
+        if done < period or p1 - t0 >= seconds or trace:
+            break
+    window_s = time.perf_counter() - t0
+    if trace:
+        jax.profiler.stop_trace()
+    dispatches = dispatch_count() - d0
+    if meter.count != compiles_before:
+        raise RuntimeError(
+            "%d backend compile(s) or cache load(s) inside the measured "
+            "window: the warm-up did not cover it"
+            % (meter.count - compiles_before))
+    peak = peak_bytes(devices)
+
+    # what the timed path produced
+    trees = [tree_dict(t) for t in booster.models]
+    scores = np.asarray(booster._training_score(), np.float32).reshape(-1)
+    produced = reference.Produced(trees=trees, scores=scores,
+                                  trees_asked=warm_trees + window_asked)
+    window_trees = trees[warm_trees:]
+    del booster, flush
+    gc.collect()
+
+    # one tree from each executable of the window's last period (the re-sort
+    # step, K=4, K=5, K=5): the last tree of the last dispatch of each size,
+    # the window's last tree among them
+    ends = len(trees) - sum(sizes) + np.cumsum(sizes) - 1
+    checked = sorted({int(e) for e in dict(zip(sizes, ends)).values()})
+    t_ref = time.perf_counter()
+    numbers = reference_bagged.compare(
+        rows.bins, rows.label, params, produced,
+        [t for t in checked if t >= 0], bags, control)
+    correct, compared = reference.judge(numbers, cell.limits)
+
+    num_data = int(config["num_data"])
+    record = {
+        "correct": correct, "compared": compared, "numbers": numbers,
+        "checked_trees": checked,
+        "reference_s": time.perf_counter() - t_ref,
+        "attempted": window_asked,
+        "failed": window_asked - len(window_trees),
+        "measures": {"train_tree_s": window_s / max(len(window_trees), 1),
+                     "setup_s": setup_s},
+        "window_trees": window_trees,
+        "window_tree_count": len(window_trees),
+        "periods": [(b - a, n) for a, b, n in periods],
+        "dispatches": dispatches, "setup_compile_s": setup_compile_s,
+        "peak_bytes": peak,
+        "in_bag_rows": int(float(params["bagging_fraction"]) * num_data),
+        "features": int(rows.bins.shape[0]),
+        "bag_epochs": len(bags),
+        "device_kind": devices[0].device_kind,
+    }
+    if control:
+        record["control_correct"], record["control_compared"] = (
+            reference.judge(reference.as_control(numbers), cell.limits))
+    if trace:
+        dev, host = trace_mod.read_xplane(trace_mod.newest_xplane(trace_dir),
+                                          HOST_SPANS)
+        record["trace"] = reduce_trace(dev, host)
+    return record

@@ -1,0 +1,7 @@
+"""`memory_stats()["peak_bytes_in_use"]` of the device after the bagged
+cell's window, before the reference runs, in GiB."""
+
+def read(record: dict):
+    if record.get("peak_bytes") is None:
+        return None
+    return record["peak_bytes"] / 2.0 ** 30

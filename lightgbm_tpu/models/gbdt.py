@@ -128,8 +128,11 @@ _unpack_bag_jit = jax.jit(_unpack_bag, static_argnums=1)
 
 @jax.jit
 def _permute_packed_bag(packed: jax.Array, row_order: jax.Array):
-    """File-order packed bag bits -> ordered-space bool mask."""
-    return jnp.take(_unpack_bag(packed, row_order.shape[0]), row_order)
+    """File-order packed bag bits -> ordered-space bool mask (a gather of
+    every row: part of what a redraw costs the device, so it carries the
+    arrangement's scope)."""
+    with jax.named_scope(spans.BAG_ARRANGE):
+        return jnp.take(_unpack_bag(packed, row_order.shape[0]), row_order)
 
 
 # -- iteration batching (config.iter_batch) ---------------------------------
@@ -1061,7 +1064,7 @@ class GBDT:
         self._row_order = None        # [n_pad] i32 device; None = identity
         self._inv_order = None        # cached device inverse of the above
         self._gstate_override = None
-        self._trees_since_reorder = 0
+        self._trees_since_reorder = self._unsorted_interval()
 
         # out-of-core ingest (ingest/ShardedDataset): feed the device
         # one shard window at a time — the full [F, N] matrix never
@@ -1177,6 +1180,10 @@ class GBDT:
         self._bag_dev = [None] * self.num_class
         self._bag_dev_packed = [None] * self.num_class
         self._bag_stacked = None    # [K, n_pad] stack (multiclass fused)
+        # what lgbm.flush says of the sampling: the newest bag's rows and
+        # the draws since the last flush
+        self._bag_in_bag = 0
+        self._bag_draws = 0
         # per-class feature-fraction RNG, all seeded feature_fraction_seed
         # (one TreeLearner per class in the reference, gbdt.cpp:38-45)
         self.feat_rngs = [Mt19937Random(config.feature_fraction_seed)
@@ -1298,25 +1305,33 @@ class GBDT:
     # ------------------------------------------------------------------
     def _bagging(self, it: int, cls: int) -> None:
         """GBDT::Bagging (gbdt.cpp:109-160): row- or query-granular
-        reservoir selection, drawing from the shared bagging stream."""
+        reservoir selection, drawing from the shared bagging stream.
+
+        What a redraw costs the host: one pass of the native walk over
+        2 x rows words of the stream (utils/mt19937.py; 0.69 s at 68.3M
+        rows on the chip's host where the numpy twist took 19.9 s, PERF.md
+        section 6, PR 33) into ONE new bool[n_pad], the mask itself; no
+        array of draws, no second copy."""
         cfg = self.config
         if not self.bagging_enabled or it % cfg.bagging_freq != 0:
             return
         md = self.train_data.metadata
         n = self.num_data
-        if md.query_boundaries is None:
-            bag_cnt = int(cfg.bagging_fraction * n)
-            mask = self.bag_rng.split_mask(n, bag_cnt)
-        else:
-            qb = md.query_boundaries
-            nq = len(qb) - 1
-            bag_query_cnt = int(nq * cfg.bagging_fraction)
-            qmask = self.bag_rng.split_mask(nq, bag_query_cnt)
-            mask = np.zeros(n, dtype=bool)
-            for q in np.nonzero(qmask)[0]:
-                mask[qb[q]:qb[q + 1]] = True
-        padded = np.zeros(self.n_pad, dtype=bool)
-        padded[:n] = mask
+        with TraceAnnotation(spans.BAG_DRAW, iter=it, rows=n) as draw_span:
+            padded = np.zeros(self.n_pad, dtype=bool)
+            if md.query_boundaries is None:
+                bag_cnt = int(cfg.bagging_fraction * n)
+                self.bag_rng.split_mask(n, bag_cnt, out=padded[:n])
+            else:
+                qb = md.query_boundaries
+                nq = len(qb) - 1
+                bag_query_cnt = int(nq * cfg.bagging_fraction)
+                qmask = self.bag_rng.split_mask(nq, bag_query_cnt)
+                for q in np.nonzero(qmask)[0]:
+                    padded[qb[q]:qb[q + 1]] = True
+            self._bag_in_bag = int(np.count_nonzero(padded))
+            self._bag_draws += 1
+            draw_span.set_metadata(in_bag=self._bag_in_bag)
         self.bag_masks[cls] = padded
         self._bag_dev[cls] = None
         self._bag_dev_packed[cls] = None
@@ -1324,7 +1339,12 @@ class GBDT:
         # a redraw invalidates the in-bag-first arrangement; the next
         # fused dispatch re-arranges (_ensure_bag_arranged)
         self._bag_arranged = False
-        log.debug("Re-bagging, using %d data to train" % int(mask.sum()))
+        log.debug("Re-bagging, using %d data to train" % self._bag_in_bag)
+
+    def bag_mask(self, cls: int = 0) -> np.ndarray:
+        """The current bag of class `cls` in FILE order, [num_data] bool:
+        a copy (all True until a first draw)."""
+        return self.bag_masks[cls][:self.num_data].copy()
 
     def _feature_mask(self, cls: int) -> np.ndarray:
         f = self.train_data.num_features
@@ -1661,10 +1681,18 @@ class GBDT:
     def _reorder_due(self) -> bool:
         """Does the NEXT iteration hit the re-sort cadence?  (First tree
         re-sorts — clustering pays from tree 2 on — then every
-        reorder_every trees.)"""
-        return (self._trees_since_reorder
-                >= (0 if self._row_order is None
-                    else self.reorder_every - 1))
+        reorder_every trees: rows in file order count as a whole
+        interval behind, _unsorted_interval.)"""
+        return self._trees_since_reorder >= self.reorder_every - 1
+
+    def _unsorted_interval(self) -> int:
+        """_trees_since_reorder of rows no tree has sorted: the next
+        fused tree re-sorts.  (Until PR 33 `_row_order is None` stood for
+        this; but a bag's arrangement makes a row order BEFORE the first
+        tree, so a job that bags never sorted at its first tree, grew
+        its first interval on unclustered rows and compiled a K=1 scan
+        for that one tree.)"""
+        return self.reorder_every - 1
 
     def _reorder_now(self) -> bool:
         return self._reorder_enabled() and self._reorder_due()
@@ -1882,6 +1910,14 @@ class GBDT:
         if self.grower is not None:
             return 0   # feature/voting growers keep the masked path
         w = -(-max(bound, 1) // unit) * unit
+        # whole groups of 8 row blocks, where that still leaves a tail: the
+        # steps of a window of 6,674 blocks took 30-36 s each to compile
+        # for the chip, of 6,672 or 6,680 blocks 9-10 s (PERF.md section 6,
+        # PR 33: every count that is no multiple of 8 tried below 8,192
+        # blocks was slow), for 0.1% more rows swept
+        w8 = -(-w // (8 * unit)) * (8 * unit)
+        if w8 < self.n_pad:
+            w = w8
         return w if w < self.n_pad else 0
 
     @contract.rank_uniform
@@ -1984,6 +2020,7 @@ class GBDT:
         if bank is not None:
             args += (bank,)
         with _enqueue("arrange", 0, self._shards,
+                      window=self._bag_window, in_bag=self._bag_in_bag,
                       **_resort_counts(args[:3] + args[4:], gstate,
                                        row_state)):
             out = fn(*args)
@@ -2230,11 +2267,14 @@ class GBDT:
         (models_ keeps partials, gbdt.cpp:186-197; prediction floors
         num_used_model_ = size/num_class, gbdt.cpp:455,489).  Returns True
         when training must stop."""
-        # ONE device->host pull for every pending tree: each
-        # small-array transfer is a synchronizing round-trip, so the
-        # flush stacks all pending ints/floats on device (this also
-        # fuses multiclass batch-row slices) and materializes them in
-        # two transfers, amortized over _flush_every iterations
+        # ONE device_get for every pending tree, amortized over
+        # _flush_every iterations: the copies of all their small buffers
+        # start together and are awaited together.  (Until PR 33 the
+        # buffers were stacked on the device first, two transfers in all;
+        # but a stack compiles anew for every COUNT of pending trees, and a
+        # job whose segments cross the flush boundary at varying places,
+        # bagging epochs against the re-sort cadence, compiled one in its
+        # steady state: inside a benchmark's window, which refuses it.)
         pending = [m for m in self._models if isinstance(m, _PendingTree)]
         if not pending:      # nothing to do, and no span: `models` asks
             return self._stopped                        # on every read
@@ -2244,20 +2284,13 @@ class GBDT:
                 bytes=sum(m.ints.nbytes + m.floats.nbytes for m in pend)
                 ) as flush_span:
             if pend:
-                # _pack_tree pads every tree to the config-fixed leaf
-                # count (see _PendingTree); a future variable-size packing
-                # change must group by shape before stacking
-                assert len({m.ints.shape for m in pend}) == 1 \
-                    and len({m.floats.shape for m in pend}) == 1, \
-                    "pending tree buffers must share one packed shape"
-                # explicit device_get: ONE counted transfer for the whole
+                # explicit device_get: ONE counted call for the whole
                 # batch (analysis/guards.py device_get accounting — bench
                 # reports it as the per-tree sync metric)
                 faultpoint("flush.device_get")
                 with TraceAnnotation(spans.FLUSH_PULL):
                     ints_all, floats_all = jax.device_get(
-                        (jnp.stack([m.ints for m in pend]),
-                         jnp.stack([m.floats for m in pend])))
+                        ([m.ints for m in pend], [m.floats for m in pend]))
                 for m, ih, fh in zip(pend, ints_all, floats_all):
                     m.ints, m.floats = ih, fh
             # a packed tree's first int is its leaf count, its last two
@@ -2272,10 +2305,27 @@ class GBDT:
             if self.objective is not None:
                 # what one tree's gradients cost (lambdarank's pair pass)
                 stats.update(self.objective.trace_counters())
+            stats.update(self._sampling_counters())
             flush_span.set_metadata(**stats)
             with TraceAnnotation(spans.FLUSH_UNPACK):
                 self._unpack_pending()
         return self._stopped
+
+    def _sampling_counters(self) -> dict:
+        """lgbm.flush's account of row and feature sampling, host-side
+        and static or counted: the compacted window's rows (0 on the
+        masked path), the newest bag's rows, the draws since the last
+        flush, the features a tree may split on; each 0 where its
+        sampling is off."""
+        bagging = self.bagging_enabled
+        frac = self.config.feature_fraction
+        out = {"bag_window": self._bag_compact_rows() if bagging else 0,
+               "bag_in_bag": self._bag_in_bag,
+               "bag_draws": self._bag_draws,
+               "feat_used": (int(self.train_data.num_features * frac)
+                             if frac < 1.0 else 0)}
+        self._bag_draws = 0
+        return out
 
     def _unpack_pending(self) -> None:
         """_flush_pending's host half: pulled buffers -> host Trees,
@@ -2484,7 +2534,7 @@ class GBDT:
             self._row_order = None
             self._inv_order = None
             self._gstate_override = None
-            self._trees_since_reorder = 0
+            self._trees_since_reorder = self._unsorted_interval()
             self._bag_arranged = False
             return
         if self._row_order is None and not self._layout_active:
@@ -2510,7 +2560,7 @@ class GBDT:
         self._row_order = None
         self._inv_order = None
         self._gstate_override = None
-        self._trees_since_reorder = 0
+        self._trees_since_reorder = self._unsorted_interval()
         self._bag_arranged = False
 
     def _mh_local_base_scores(self) -> np.ndarray:
@@ -3100,7 +3150,7 @@ class GBDT:
                                  if self._mh_fused or lay is not None
                                  else jnp.asarray(bins))
             self._row_order = None
-            self._trees_since_reorder = 0
+            self._trees_since_reorder = self._unsorted_interval()
             self._gstate_override = None
             z_scores = z_base
             bag_restored = False
@@ -3114,6 +3164,8 @@ class GBDT:
                 self.scores = jax.device_put(
                     self.scores, self.grower.row_sharding_2d())
         self.bag_masks = [m.copy() for m in z["bag_masks"]]
+        self._bag_in_bag = (int(np.count_nonzero(self.bag_masks[-1]))
+                            if self.bagging_enabled else 0)
         self._bag_dev = [None] * self.num_class
         self._bag_dev_packed = [None] * self.num_class
         self._bag_stacked = None

@@ -132,6 +132,9 @@ def get_lib() -> Optional[ctypes.CDLL]:
     lib.lgt_parse_dense_mt.restype = i64
     lib.lgt_selection_mask.argtypes = [pd, i64, i64, pu8]
     lib.lgt_selection_mask.restype = None
+    lib.lgt_mt_selection_mask.argtypes = [
+        ctypes.POINTER(ctypes.c_uint32), pi64, i64, i64, pu8]
+    lib.lgt_mt_selection_mask.restype = None
     lib.lgt_format_g.argtypes = [pd, i64, i64, ctypes.c_char_p]
     lib.lgt_format_g.restype = i64
     lib.lgt_predict_dense_mt.argtypes = [
@@ -478,6 +481,26 @@ def selection_walk(draws: np.ndarray, k: int) -> np.ndarray:
             mask[i] = True
             taken += 1
     return mask
+
+
+def mt_selection_mask(key: np.ndarray, pos: int, n: int, k: int,
+                      out: np.ndarray) -> Optional[int]:
+    """selection_walk over n NextDouble draws made natively from a
+    std::mt19937's raw state: `key` (uint32[624], contiguous, advanced IN
+    PLACE) and `pos` (the next word to temper), as numpy's MT19937 holds
+    them; the mask is written into `out` (bool[n], contiguous).  -> the
+    new pos, or None without native (nothing touched)."""
+    lib = get_lib()
+    if lib is None:
+        return None
+    assert key.dtype == np.uint32 and key.flags.c_contiguous
+    assert out.dtype == np.bool_ and out.flags.c_contiguous and len(out) == n
+    new_pos = ctypes.c_int64(int(pos))
+    lib.lgt_mt_selection_mask(
+        key.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+        ctypes.byref(new_pos), int(n), int(k),
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)))
+    return int(new_pos.value)
 
 
 class ShardLottery:

@@ -946,6 +946,58 @@ void lgt_selection_mask(const double* draws, int64_t n, int64_t k,
   }
 }
 
+// The same walk with its draws made here: n NextDouble draws of a
+// std::mt19937 continued from its raw state (key: the 624 words after the
+// last twist; *pos: the next word to temper, 624 = twist first; both as
+// numpy's MT19937 bit generator holds them, and both advanced in place).
+// NextDouble is libstdc++'s generate_canonical<double, 53> over two words,
+// (x1 + x2 * 2^32) / 2^64 (utils/mt19937.py).  One pass, no array of
+// draws: a bag of 68M rows costs the host under a second.
+void lgt_mt_selection_mask(uint32_t* key, int64_t* pos, int64_t n, int64_t k,
+                           uint8_t* mask) {
+  constexpr int N = 624, M = 397;
+  constexpr uint32_t A = 0x9908B0DFu, UPPER = 0x80000000u,
+                     LOWER = 0x7FFFFFFFu;
+  int p = static_cast<int>(*pos);
+  auto next = [&]() -> uint32_t {
+    if (p >= N) {
+      int i = 0;
+      for (; i < N - M; ++i) {
+        uint32_t y = (key[i] & UPPER) | (key[i + 1] & LOWER);
+        key[i] = key[i + M] ^ (y >> 1) ^ ((y & 1u) ? A : 0u);
+      }
+      for (; i < N - 1; ++i) {
+        uint32_t y = (key[i] & UPPER) | (key[i + 1] & LOWER);
+        key[i] = key[i + M - N] ^ (y >> 1) ^ ((y & 1u) ? A : 0u);
+      }
+      uint32_t y = (key[N - 1] & UPPER) | (key[0] & LOWER);
+      key[N - 1] = key[M - 1] ^ (y >> 1) ^ ((y & 1u) ? A : 0u);
+      p = 0;
+    }
+    uint32_t y = key[p++];
+    y ^= y >> 11;
+    y ^= (y << 7) & 0x9D2C5680u;
+    y ^= (y << 15) & 0xEFC60000u;
+    y ^= y >> 18;
+    return y;
+  };
+  constexpr double TWO32 = 4294967296.0;
+  int64_t taken = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    double x1 = static_cast<double>(next());
+    double x2 = static_cast<double>(next());
+    double draw = (x1 + x2 * TWO32) / (TWO32 * TWO32);
+    double prob = static_cast<double>(k - taken) / static_cast<double>(n - i);
+    if (draw < prob) {
+      mask[i] = 1;
+      ++taken;
+    } else {
+      mask[i] = 0;
+    }
+  }
+  *pos = p;
+}
+
 // ---------------------------------------------------------------------------
 // Multi-machine row lottery + bin-sample reservoir.
 //

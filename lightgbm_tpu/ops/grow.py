@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from ..analysis.contracts import contract
 from ..utils import spans
 from .histogram import leaf_histogram, make_gvals
-from .predict import predict_leaf_binned
+from .predict import replay_leaf_binned
 from .split import (BestSplit, SplitParams, find_best_split, K_MIN_SCORE,
                     per_feature_best)
 
@@ -600,12 +600,13 @@ def grow_tree_bagged(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
     re-bagging epochs and the executable never retraces.
 
     Out-of-bag tail rows no longer ride leaf_id through the scan: their
-    leaf assignment comes from one vectorized binned descent over the
-    complement — a cheap O(tail * depth) traversal traded for the
-    dominant O(N * leaves) histogram cost, exactly the reference's
-    two-path score update (partition fast path + OOB traversal,
+    leaf assignment comes from a replay of the finished tree's splits
+    over the complement (ops/predict.py replay_leaf_binned: one pass over
+    a bin row and the ids a split, 49 ms a tree at 13.6M rows where the
+    gather descent took 2.50 s), as the reference updates scores by two
+    paths (partition fast path + OOB traversal,
     src/boosting/gbdt.cpp:162-167).  The returned leaf_id still covers
-    ALL rows (window ids from the scan, tail ids from the descent; the
+    ALL rows (window ids from the scan, tail ids from the replay; the
     two agree bit-for-bit with a full-row scan, which routes rows by
     the same compares).
 
@@ -623,12 +624,14 @@ def grow_tree_bagged(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                              hess[:bag_rows], bag_mask[:bag_rows],
                              feature_mask, **grow_kw)
     with jax.named_scope(spans.OOB_DESCENT):
-        oob = predict_leaf_binned(tree.split_feature, tree.threshold_bin,
-                                  tree.left_child, tree.right_child,
-                                  bins_t[:, bag_rows:])
-        # a 1-leaf stump's all-zero child arrays make the bounded descent
-        # return the dummy ~0 = -1; the scan's leaf_id keeps such rows at
-        # leaf 0 (whose value drives the score update), so mirror it — the
-        # two paths must agree row-for-row with the masked full sweep
-        oob = jnp.maximum(oob, 0)
-        return tree, jnp.concatenate([leaf_w, oob.astype(leaf_w.dtype)])
+        # (an unsplit stump replays no split: its rows stay at leaf 0, whose
+        # value drives the score update, as the scan's leaf_id has them)
+        oob = replay_leaf_binned(tree.split_feature, tree.threshold_bin,
+                                 tree.left_child, tree.num_leaves,
+                                 bins_t[:, bag_rows:])
+        # the barrier keeps the compiler from sinking the concatenation
+        # into the score update's look-up, which it then made in pieces
+        # (0.18 s a tree at 68M rows where the whole look-up takes 0.003,
+        # and 10 s more to compile; PERF.md section 6, PR 33)
+        return tree, jax.lax.optimization_barrier(
+            jnp.concatenate([leaf_w, oob.astype(leaf_w.dtype)]))

@@ -61,6 +61,39 @@ def predict_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
 
 
 @jax.jit
+def replay_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
+                       left_child: jax.Array, num_leaves: jax.Array,
+                       bins_t: jax.Array) -> jax.Array:
+    """predict_leaf_binned's answer for a tree the grow scan made, by
+    replaying its splits in the order they were made: step k read ONE bin
+    row and sent the rows of the leaf it split that lie over the threshold
+    to the new leaf k + 1 (ops/grow.py `step`; node k's left child keeps the
+    split leaf's id, so that leaf is where going left from node k ends).
+    The same compares as the scan's own partition, so the ids are its ids.
+
+    For rows in BULK: a pass streams one bin row and the ids (5 bytes a
+    row read, 4 written) where the descent gathers a byte per row and
+    LEVEL at 25 ns an index (PERF.md section 6, PR 33: 13.6M out-of-bag rows
+    took 2.50 s a tree by descent, 0.049 s by replay).  Returns [N] i32 leaf
+    ids."""
+    nodes = split_feature.shape[0]
+
+    def go_left(_, at):
+        return jnp.where(at >= 0, left_child[jnp.maximum(at, 0)], at)
+    source = ~jax.lax.fori_loop(0, nodes, go_left,
+                                jnp.arange(nodes, dtype=jnp.int32))
+
+    def split(k, leaf):
+        row = jax.lax.dynamic_index_in_dim(bins_t, split_feature[k], 0,
+                                           keepdims=False)
+        go_right = ((k < num_leaves - 1) & (leaf == source[k])
+                    & (row.astype(jnp.int32) > threshold_bin[k]))
+        return jnp.where(go_right, k + 1, leaf)
+    return jax.lax.fori_loop(0, nodes - 1, split,
+                             jnp.zeros(bins_t.shape[1], dtype=jnp.int32))
+
+
+@jax.jit
 def predict_leaf_raw(split_feature_real: jax.Array, threshold: jax.Array,
                      left_child: jax.Array, right_child: jax.Array,
                      x: jax.Array) -> jax.Array:

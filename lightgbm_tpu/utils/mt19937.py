@@ -9,7 +9,10 @@ tree-identity / trajectory-parity tests run with bagging enabled.
 
 Verified against a g++ probe: NextDouble == (x1 + x2*2^32) / 2^64 with two
 raw 32-bit draws x1, x2 (libstdc++ generate_canonical<double, 53> with
-mt19937).  Blocks of 624 outputs are generated vectorised with numpy.
+mt19937).  The words are numpy's own MT19937 bit generator's, seeded as
+std::mt19937(seed) seeds itself (init_genrand): the same recurrence in C,
+11 ms for 2M words where the twist in numpy expressions (until PR 33) took
+0.4 s.
 """
 
 from __future__ import annotations
@@ -19,10 +22,6 @@ __jax_free__ = True
 import numpy as np
 
 _N = 624
-_M = 397
-_MATRIX_A = np.uint32(0x9908B0DF)
-_UPPER = np.uint32(0x80000000)
-_LOWER = np.uint32(0x7FFFFFFF)
 _TWO32 = 4294967296.0
 
 
@@ -35,78 +34,64 @@ def _seed_state(seed: int) -> np.ndarray:
     return s.astype(np.uint32)
 
 
-def _next_block(state: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Advance one full twist; returns (new_state, 624 tempered outputs)."""
-    s = state
-    new = np.empty(_N, dtype=np.uint32)
-    # the recurrence references new values for i >= N - M, and the in-place
-    # algorithm's last element reads the *new* s[0]; two vectorised stages +
-    # a scalar tail reproduce that exactly.
-    y = (s & _UPPER) | (np.roll(s, -1) & _LOWER)
-    mag = np.where((y & np.uint32(1)).astype(bool), _MATRIX_A, np.uint32(0))
-    # stage 1: i in [0, N-M): uses s[i+M] (old state)
-    new[: _N - _M] = s[_M:] ^ (y[: _N - _M] >> np.uint32(1)) ^ mag[: _N - _M]
-    # stage 2: i in [N-M, N-1): uses new[i+M-N], itself produced at most
-    # N-M steps earlier — chunks of N-M keep the dependency satisfied.
-    step = _N - _M
-    for lo in range(_N - _M, _N - 1, step):
-        hi = min(lo + step, _N - 1)
-        new[lo:hi] = new[lo - step : hi - step] ^ (y[lo:hi] >> np.uint32(1)) ^ mag[lo:hi]
-    # last element: y built from old s[N-1] and NEW s[0]
-    y_last = (s[_N - 1] & _UPPER) | (new[0] & _LOWER)
-    mag_last = _MATRIX_A if (y_last & np.uint32(1)) else np.uint32(0)
-    new[_N - 1] = new[_M - 1] ^ (y_last >> np.uint32(1)) ^ mag_last
-    out = new.copy()
+def _temper(words: np.ndarray) -> np.ndarray:
+    """The output transform of MT19937 over raw state words."""
+    out = words.astype(np.uint32)
     out ^= out >> np.uint32(11)
     out ^= (out << np.uint32(7)) & np.uint32(0x9D2C5680)
     out ^= (out << np.uint32(15)) & np.uint32(0xEFC60000)
     out ^= out >> np.uint32(18)
-    return new, out
+    return out
 
 
 class Mt19937Random:
     """Replica of LightGBM::Random (reference include/LightGBM/utils/random.h:14-75)."""
 
+    # doubles made a chunk at a time: 16 MB of raw words, never 2 x count
+    _CHUNK = 1 << 20
+
     def __init__(self, seed: int):
-        self._state = _seed_state(seed)
-        self._buf = np.empty(0, dtype=np.uint32)
-        self._pos = 0
+        self._bg = np.random.MT19937()  # graftlint: disable=GL005 -- the bit generator alone, its state set to the reference's seeding: this IS the reference's stream
+        self._load(_seed_state(seed), _N)
+
+    def _load(self, key: np.ndarray, pos: int) -> None:
+        """key: the 624 words after the last twist; pos: the next one to
+        temper (624 = twist first), as numpy and libstdc++ hold them."""
+        self._bg.state = {"bit_generator": "MT19937",
+                          "state": {"key": key, "pos": int(pos)}}
 
     def _raw(self, count: int) -> np.ndarray:
-        have = len(self._buf) - self._pos
-        if have < count:
-            # generate all missing twist blocks up front: one concatenate
-            # total, not one per 624-word block (quadratic for big draws)
-            blocks = [self._buf[self._pos:]]
-            while have < count:
-                self._state, out = _next_block(self._state)
-                blocks.append(out)
-                have += _N
-            self._buf = np.concatenate(blocks)
-            self._pos = 0
-        res = self._buf[self._pos : self._pos + count]
-        self._pos += count
-        return res
+        return self._bg.random_raw(count).astype(np.uint32)
 
     def get_state(self) -> np.ndarray:
-        """Serializable stream state: generator state + undrawn buffer
-        (checkpointing; see GBDT.save_checkpoint)."""
-        return np.concatenate([
-            np.asarray([len(self._state)], dtype=np.uint32),
-            self._state.astype(np.uint32),
-            self._buf[self._pos:].astype(np.uint32)])
+        """Serializable stream state: generator state + the undrawn words
+        of its last twist (checkpointing; see GBDT.save_checkpoint)."""
+        st = self._bg.state["state"]
+        key = st["key"].astype(np.uint32)
+        return np.concatenate([np.asarray([_N], dtype=np.uint32), key,
+                               _temper(key[int(st["pos"]):])])
 
     def set_state(self, packed: np.ndarray) -> None:
         packed = np.asarray(packed, dtype=np.uint32)
         n = int(packed[0])
-        self._state = packed[1:1 + n].copy()
-        self._buf = packed[1 + n:].copy()
-        self._pos = 0
+        undrawn = len(packed) - 1 - n
+        if n != _N or undrawn > _N:
+            raise ValueError("not a Mt19937Random state: %d state words, "
+                             "%d undrawn" % (n, undrawn))
+        self._load(packed[1:1 + n].copy(), _N - undrawn)
 
     def next_doubles(self, count: int) -> np.ndarray:
-        """count draws of uniform_real_distribution<double>(0,1): 2 raws each."""
-        raw = self._raw(2 * count).astype(np.float64)
-        return (raw[0::2] + raw[1::2] * _TWO32) / (_TWO32 * _TWO32)
+        """count draws of uniform_real_distribution<double>(0,1): 2 raws
+        each, (x1 + x2 * 2^32) / 2^64 rounded once to a double."""
+        out = np.empty(count, dtype=np.float64)
+        for lo in range(0, count, self._CHUNK):
+            raw = self._bg.random_raw(2 * min(self._CHUNK, count - lo))
+            # x1 + x2 * 2^32 is exact in 64 bits; the cast rounds it to
+            # nearest even as the reference's float64 add does
+            out[lo:lo + len(raw) // 2] = raw[0::2] | (raw[1::2]
+                                                      << np.uint64(32))
+        out *= 1.0 / (_TWO32 * _TWO32)
+        return out
 
     def next_double(self) -> float:
         return float(self.next_doubles(1)[0])
@@ -166,17 +151,30 @@ class Mt19937Random:
             filled += int(np.count_nonzero(good))
         return out
 
-    def _selection_mask(self, n: int, k: int) -> np.ndarray:
+    def _selection_mask(self, n: int, k: int,
+                        out: np.ndarray = None) -> np.ndarray:
         """Acceptance mask of sequential selection sampling over exactly n
-        NextDouble draws: accept i when draw_i < (k - taken_i) / (n - i).
+        NextDouble draws: accept i when draw_i < (k - taken_i) / (n - i),
+        written into `out` (bool[n], contiguous) where one is given.
 
         The walk is inherently sequential (taken_i depends on every
-        earlier accept), so it runs in the native layer
-        (lgt_selection_mask — the exact IEEE ops of the reference loop);
-        the Python walk is the no-toolchain fallback.
+        earlier accept), so draws and walk run as ONE pass in the native
+        layer (lgt_mt_selection_mask, continued from this stream's raw
+        state: no array of draws, under a second for 68M rows); without a
+        toolchain the draws are made here and walked by
+        native.selection_walk's Python fallback.
         """
         from .. import native
-        return native.selection_walk(self.next_doubles(n), k)
+        if out is None:
+            out = np.empty(n, dtype=bool)
+        st = self._bg.state["state"]
+        key = np.ascontiguousarray(st["key"], dtype=np.uint32)
+        pos = native.mt_selection_mask(key, int(st["pos"]), n, k, out)
+        if pos is None:
+            out[:] = native.selection_walk(self.next_doubles(n), k)
+        else:
+            self._load(key, pos)
+        return out
 
     def sample(self, n: int, k: int) -> np.ndarray:
         """Sequential selection sampling; reference random.h:55-67.
@@ -188,10 +186,11 @@ class Mt19937Random:
             return np.zeros(0, dtype=np.int32)
         return np.flatnonzero(self._selection_mask(n, k)).astype(np.int32)
 
-    def split_mask(self, n: int, k: int) -> np.ndarray:
-        """Like sample() but returns the boolean acceptance mask over [0, n).
+    def split_mask(self, n: int, k: int, out: np.ndarray = None) -> np.ndarray:
+        """Like sample() but returns the boolean acceptance mask over [0, n)
+        (in `out`, bool[n] and contiguous, where one is given).
 
         Mirrors the in/out-of-bag partition loop of GBDT::Bagging
         (reference src/boosting/gbdt.cpp:118-129).
         """
-        return self._selection_mask(n, k)
+        return self._selection_mask(n, k, out)

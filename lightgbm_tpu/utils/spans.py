@@ -10,8 +10,9 @@ flag check with the profiler off); their keyword arguments become the
 event's stats, so each count travels with its span.  Both land in the one
 `.xplane.pb` of `jax.profiler.trace(dir)`, on one clock;
 `benchmark/phase_table.py <dir>` prints the table (the benchmark keeps its
-own copy of these names, `benchmark/harness/scopes.json` and
-`scopes_ranked.json`; tests/test_spans.py holds their union equal).
+own copy of these names, `benchmark/harness/scopes.json`,
+`scopes_ranked.json` and `scopes_bagged.json`; tests/test_spans.py holds
+their union equal).
 
 Use the constants at the sites, never a string literal: the test greps
 for `lgbm.` names outside this registry.
@@ -53,7 +54,9 @@ DEVICE_SCOPES = (
 # -- host spans (models/gbdt.py segment loop), with their stats ------------
 SEGMENT = "lgbm.segment"              # iter, k: one train_segment / iteration
 HOST_INPUTS = "lgbm.host_inputs"      # plan, bagging, masks and their upload
-ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call
+ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call;
+#                                       a re-sorting one adds carried, taken,
+#                                       the arrangement also window, in_bag
 FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
 #                                       grid_rows, feat_groups, block_matmuls
 #                                       (a row step's feature groups and
@@ -61,13 +64,19 @@ FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swe
 #                                       counters (Objective.trace_counters:
 #                                       lambdarank's pairs_padded, pairs_real,
 #                                       queries, lmax, each what ONE tree
-#                                       costs): _flush_pending
+#                                       costs), and the sampling's:
+#                                       bag_window, bag_in_bag, bag_draws
+#                                       (since the last flush), feat_used,
+#                                       0 where sampling is off:
+#                                       _flush_pending
 FLUSH_PULL = "lgbm.flush_pull"        # the device_get (host waits for device)
 FLUSH_UNPACK = "lgbm.flush_unpack"    # _unpack_tree loop, stump truncation
 EVAL = "lgbm.eval"                    # iter: metrics and early stopping
+BAG_DRAW = "lgbm.bag_draw"            # iter, rows, in_bag: _bagging when it
+#                                       redraws, INSIDE lgbm.host_inputs
 
 HOST_SPANS = (SEGMENT, HOST_INPUTS, ENQUEUE, FLUSH, FLUSH_PULL,
-              FLUSH_UNPACK, EVAL)
+              FLUSH_UNPACK, EVAL, BAG_DRAW)
 
 # `kind` of an lgbm.enqueue span: which executable was called
 ENQUEUE_KINDS = ("scan", "resort", "multi", "dart", "arrange", "general")

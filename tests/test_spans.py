@@ -239,16 +239,57 @@ def test_spans_count_what_was_trained(traced_and_plain):
     assert len(booster._gbdt.models) == ROUNDS
 
 
-def test_bag_arrangement_says_what_moved_together(tmp_path):
+SAMPLING_STATS = ("bag_window", "bag_in_bag", "bag_draws", "feat_used")
+
+
+@pytest.fixture(scope="module")
+def sampled_spans(tmp_path_factory):
+    """Five rounds that bag (a draw every second) and sample features."""
+    trace_dir = str(tmp_path_factory.mktemp("sampled"))
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
-    with jax.profiler.trace(str(tmp_path), profiler_options=options):
-        _train(PATHS["bagged"][0], 1, 0, rounds=3)
-    arranges = [s for name, s in _program_spans(str(tmp_path))
+    extra = {**PATHS["bagged"][0], "bagging_freq": 2,
+             "feature_fraction": 0.5}
+    with jax.profiler.trace(trace_dir, profiler_options=options):
+        _train(extra, 1, 0, rounds=5)
+    return _program_spans(trace_dir)
+
+
+def test_bag_arrangement_says_what_moved_together(sampled_spans):
+    arranges = [s for name, s in sampled_spans
                 if name == spans.ENQUEUE and s["kind"] == "arrange"]
-    assert len(arranges) == 3       # bagging_freq=1: one a round
+    assert len(arranges) == 3       # bagging_freq=2: before rounds 0, 2, 4
     # the [1, N] scores row, the mask, the order, sign and label_weight
     assert all((s["carried"], s["taken"]) == (5, 1) for s in arranges)
+    # the static window and the bag it holds: 8,192 rows, half in the bag
+    assert all((s["window"], s["in_bag"]) == (4096, 4096) for s in arranges)
+
+
+def test_a_draw_has_its_span_and_the_flush_its_account(sampled_spans):
+    """With sampling on: one `lgbm.bag_draw` a redraw, inside
+    `lgbm.host_inputs`, with the round, the rows and the bag's count; every
+    flush says the window, the bag, the draws since the flush before and
+    the features a tree may split on."""
+    draws = [s for name, s in sampled_spans if name == spans.BAG_DRAW]
+    assert [(s["iter"], s["rows"], s["in_bag"]) for s in draws] == [
+        (0, 8192, 4096), (2, 8192, 4096), (4, 8192, 4096)]
+    flushes = [s for name, s in sampled_spans if name == spans.FLUSH]
+    assert flushes and sum(s["trees"] for s in flushes) == 5
+    assert sum(s["bag_draws"] for s in flushes) == len(draws)
+    for s in flushes:
+        assert (s["bag_window"], s["bag_in_bag"], s["feat_used"]) == (
+            4096, 4096, 3), s
+
+
+def test_no_sampling_no_draw_and_an_account_of_zeros(traced_and_plain):
+    found = traced_and_plain[0]
+    assert not [s for name, s in found if name == spans.BAG_DRAW]
+    flushes = [s for name, s in found if name == spans.FLUSH]
+    assert flushes
+    for s in flushes:
+        assert [s[k] for k in SAMPLING_STATS] == [0, 0, 0, 0], s
+    assert all("window" not in s and "in_bag" not in s
+               for name, s in found if name == spans.ENQUEUE)
 
 
 def test_profiler_changes_no_bit(traced_and_plain):
@@ -293,14 +334,22 @@ def _benchmark_names(name):
     ("enqueue_kinds", spans.ENQUEUE_KINDS)])
 def test_benchmark_copy_is_equal(key, ours):
     """The program's lists equal the UNION of the benchmark's scope files
-    (`scopes.json`, accepted and not edited, and `scopes_ranked.json`,
-    what the ranking cell added), and in each file's grouping every scope
-    a cell of that file can show feeds exactly one of its metrics."""
+    (`scopes.json`, accepted and not edited, `scopes_ranked.json`, what the
+    ranking cell added, and `scopes_bagged.json`, what the bagged cell
+    added), and in each file's grouping every scope a cell of that file
+    can show feeds exactly one of its metrics."""
     base = _benchmark_names("scopes.json")
     ranked = _benchmark_names("scopes_ranked.json")
-    assert tuple(base[key]) + tuple(ranked.get(key, ())) == ours
+    bagged = _benchmark_names("scopes_bagged.json")
+    assert (tuple(base[key]) + tuple(ranked.get(key, ()))
+            + tuple(bagged.get(key, ()))) == ours
     if key == "device_scopes":
         grouped = [s for g in base["device_groups"].values() for s in g]
         assert sorted(grouped) == sorted(base[key])
-        grouped = [s for g in ranked["device_groups"].values() for s in g]
-        assert sorted(grouped) == sorted(ours)
+        for added in (ranked, bagged):
+            grouped = [s for g in added["device_groups"].values() for s in g]
+            assert sorted(grouped) == sorted(ours)
+    if key == "host_spans":
+        grouped = {s for g in bagged["host_groups"].values()
+                   for s in g["spans"]}
+        assert grouped <= set(ours) and spans.BAG_DRAW in grouped
