@@ -69,15 +69,17 @@ def _pack_tree(dev_tree):
     """TreeArrays -> (int32 buffer, float buffer): two flat arrays so a
     whole tree ships device->host in two async copies instead of eleven.
     The trailing dummy slots (grow.py TreeArrays) are trimmed here, so the
-    wire layout stays [1 + 4*(L-1) + 3*L + 2 | (L-1) + L + (L-1)]: the
-    int row ends with the tree's two sweep counters (blocks_swept,
-    grid_rows), behind everything _unpack_tree and _dart_layout slice."""
+    wire layout stays [1 + 4*(L-1) + 3*L + 3 | (L-1) + L + (L-1)]: the
+    int row ends with the tree's three block counters (blocks_swept,
+    grid_rows, partition_blocks), behind everything _unpack_tree and
+    _dart_layout slice."""
     ints = jnp.concatenate([
         dev_tree.num_leaves.reshape(1), dev_tree.split_feature[:-1],
         dev_tree.threshold_bin[:-1], dev_tree.left_child[:-1],
         dev_tree.right_child[:-1], dev_tree.leaf_parent[:-1],
         dev_tree.leaf_depth[:-1], dev_tree.leaf_count[:-1],
         dev_tree.blocks_swept.reshape(1), dev_tree.grid_rows.reshape(1),
+        dev_tree.partition_blocks.reshape(1),
     ]).astype(jnp.int32)
     floats = jnp.concatenate([dev_tree.split_gain[:-1],
                               dev_tree.leaf_value[:-1],
@@ -2293,10 +2295,12 @@ class GBDT:
                         ([m.ints for m in pend], [m.floats for m in pend]))
                 for m, ih, fh in zip(pend, ints_all, floats_all):
                     m.ints, m.floats = ih, fh
-            # a packed tree's first int is its leaf count, its last two
-            # what its block-list sweeps cost (ops/grow.py TreeArrays)
-            stats = {"blocks_swept": sum(int(m.ints[-2]) for m in pending),
-                     "grid_rows": sum(int(m.ints[-1]) for m in pending)}
+            # a packed tree's first int is its leaf count, its last three
+            # what its block-list sweeps and partition passes cost
+            # (ops/grow.py TreeArrays)
+            stats = {name: sum(int(m.ints[at]) for m in pending)
+                     for at, name in ((-3, "blocks_swept"), (-2, "grid_rows"),
+                                      (-1, "partition_blocks"))}
             stats["feat_groups"], stats["block_matmuls"] = self._sweep_grid()
             wire = self._exchange_bytes(sum(int(m.ints[0])
                                             for m in pending))
@@ -3381,7 +3385,7 @@ class DART(GBDT):
         leaf_dt = np.uint8 if L <= 256 else np.int32
         if self._bank is None:
             T = max(cfg.num_iterations, k_iters) + 1  # + dummy row
-            li = 1 + 4 * (L - 1) + 3 * L + 2
+            li = 1 + 4 * (L - 1) + 3 * L + 3
             lf = 3 * L - 2
             bi = np.zeros((T, li), np.int32)
             # untouched rows must TERMINATE traversal: child slots -1

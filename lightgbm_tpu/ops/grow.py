@@ -6,6 +6,8 @@ TPU-native redesign of SerialTreeLearner::Train
   - The reference's DataPartition (grouped row-index arrays, re-shuffled at
     every split) becomes a flat per-row `leaf_id [N] int32`, updated with one
     vectorized compare per split — no data movement, shard-local under pjit.
+    In the block-list mode (`ranged`) the compare is a kernel over the row
+    blocks the split leaf occupies, which are kept as state.
   - Per-leaf histogram cache (HistogramPool) becomes a dense
     `hist [L, F, B, 3]` tensor; the parent-minus-smaller-child subtraction
     trick (FeatureHistogram::Subtract, feature_histogram.hpp:97-106) is a
@@ -67,21 +69,29 @@ class TreeArrays(NamedTuple):
     num_leaves: jax.Array       # scalar i32
     # what the tree's block-list sweeps cost, root included and summed
     # over shards (0 in the other sweep modes): occupied row blocks, and
-    # the row steps the kernels' grids ran
+    # the row steps the kernels' grids ran; and the row blocks its split
+    # leaves occupied, which the partition passes visited
     blocks_swept: jax.Array     # scalar i32
     grid_rows: jax.Array        # scalar i32
+    partition_blocks: jax.Array  # scalar i32
 
 
 class GrowState(NamedTuple):
     tree: TreeArrays
-    leaf_id: jax.Array          # [N] i32
+    leaf_id: jax.Array          # [N] i32; in the block-list mode under
+    #                             the row's bag bit (hist_pallas.OOB_BIT)
+    occ: jax.Array              # [L+1, nblocks] bool: the row blocks that
+    #                             hold a row of each leaf, and
+    occ_bag: jax.Array          # the same of in-bag rows (what a sweep
+    #                             lists); block-list mode, else empty
     hist: jax.Array             # [K+1, F, B, 3] (last = dummy slot);
     #                             K = max_leaves (dense) or hist_slots (pool)
     leaf_sum_g: jax.Array       # [L+1] (last = dummy slot)
     leaf_sum_h: jax.Array       # [L+1]
     best_f: jax.Array           # [L+1, 8] float best-split fields
     best_i: jax.Array           # [L+1, 4] i32 best-split fields
-    swept: jax.Array            # [2] i32 (occupied blocks, grid rows) so far
+    swept: jax.Array            # [3] i32 so far: (occupied blocks, grid
+    #                             rows) of the sweeps, blocks partitioned
     # histogram-pool bookkeeping (HistogramPool, reference
     # feature_histogram.hpp:275-398, re-designed as on-device LRU): only
     # carried when hist_slots bounds the pool; zero-size arrays otherwise
@@ -122,6 +132,7 @@ def _empty_tree(max_leaves: int, dtype) -> TreeArrays:
         leaf_count=z_i(L + 1),
         num_leaves=jnp.int32(1),
         blocks_swept=jnp.int32(0), grid_rows=jnp.int32(0),
+        partition_blocks=jnp.int32(0),
     )
 
 
@@ -181,7 +192,9 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
     kernel, f32, max_bin<=256, N % 8192 == 0).
     ranged (pallas): per split, sweep only the row blocks that hold the
     target leaf's rows (leaf_histogram_blocklist) instead of every block
-    (leaf_histogram_masked); bit-identical for the same row order.
+    (leaf_histogram_masked), and partition only those of the split leaf
+    (leaf_partition_blocklist) instead of comparing every row;
+    bit-identical for the same row order.
     psum_axis: mesh axis sharding rows (tree_learner=data).
     hist_slots (>0): bound histogram HBM to hist_slots live [F, B, 3]
     leaf histograms — the reference HistogramPool's role
@@ -308,66 +321,122 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
 
     ranged_on = (ranged and hist_impl == "pallas"
                  and feature_axis is None)
-    no_blocks = jnp.zeros(2, dtype=jnp.int32)   # hist_leaf's count elsewhere
+    no_blocks = jnp.zeros(3, dtype=jnp.int32)   # hist_leaf's count elsewhere
     if hist_impl == "pallas":
-        from .hist_pallas import (PALLAS_ROW_BLOCK, fold_leaf_mask,
-                                  leaf_histogram_blocklist,
-                                  leaf_histogram_masked, make_gh2)
+        from .hist_pallas import (PALLAS_ROW_BLOCK, PART_BLOCKS, fold_bag_bit,
+                                  fold_leaf_mask, leaf_histogram_blocklist,
+                                  leaf_histogram_masked, leaf_of,
+                                  leaf_partition_blocklist, make_gh2,
+                                  part_groups)
         with jax.named_scope(spans.HIST_SWEEP):
             gh2 = make_gh2(grad, hess)
         # TPU runs the compiled kernel; CPU (tests) uses interpret mode
         interpret = jax.default_backend() == "cpu"
     if ranged_on:
         # Block-list sweeps (VERDICT r2 #1): per split, sweep ONLY the
-        # row blocks that contain the target leaf's rows.  The occupancy
-        # scan is one cheap [nblocks, B] reduction + a tiny argsort;
-        # skipped blocks contribute exact +0.0f in the full sweep, so
-        # the result is BIT-identical to it for the same row order.
-        # Pays off when rows are leaf-clustered (the ordered-partition
-        # mode in models/gbdt.py re-sorts rows by the previous tree's
-        # leaves every few trees).  The kernel's grid ends at the leaf's
-        # last occupied block (a run-time bound), so a sweep costs its
-        # own blocks and one compiled kernel serves every leaf size.
+        # row blocks that contain the target leaf's rows; skipped blocks
+        # contribute exact +0.0f in the full sweep, so the result is
+        # BIT-identical to it for the same row order.  Pays off when rows
+        # are leaf-clustered (the ordered-partition mode in models/gbdt.py
+        # re-sorts rows by the previous tree's leaves every few trees).
+        # The kernel's grid ends at the leaf's last occupied block (a
+        # run-time bound), so a sweep costs its own blocks and one
+        # compiled kernel serves every leaf size.
+        # Which blocks a leaf occupies is STATE (GrowState.occ), not a
+        # scan of the ids: the root's row is made once a tree, and the
+        # partition pass of a split, which runs over the split leaf's
+        # own blocks and writes the ids in place, says of each which
+        # child has a row there.  Nothing in `step` touches all N rows.
+        # The ids carry the bag as a high bit (fold_bag_bit, once a
+        # tree): the sweep's target never equals an out-of-bag row.
         # Under tree_learner=data (psum_axis set) everything here is
         # shard-LOCAL — blocks, occupancy, block list, grid, re-sorts —
         # except the histogram reduction the other impls share
         # (hist_psum): each shard's kernel runs to its own count.
         nblocks = n // PALLAS_ROW_BLOCK
 
-        def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
-            with jax.named_scope(spans.BLOCK_LIST):
-                leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-                occ = (leaf_eff == target).reshape(
-                    nblocks, PALLAS_ROW_BLOCK).any(axis=1)
-                n_occ = jnp.sum(occ).astype(jnp.int32)
-                # occupied block ids first, ascending (stable argsort of
-                # the complement keeps file order => full-sweep
-                # association)
-                blist = jnp.argsort(jnp.where(occ, 0, 1).astype(jnp.int32),
-                                    stable=True).astype(jnp.int32)
+        @jax.named_scope(spans.BLOCK_LIST)
+        def block_list(occ):
+            """(occupied block ids first and ascending, their count): the
+            stable argsort of the complement keeps file order => the full
+            sweep's association."""
+            return (jnp.argsort(jnp.where(occ, 0, 1).astype(jnp.int32),
+                                stable=True).astype(jnp.int32),
+                    jnp.sum(occ).astype(jnp.int32))
+
+        def hist_leaf(leaf_id, target, occ, scope=spans.HIST_SWEEP):
+            """occ [nblocks]: the blocks that hold an in-bag row of it."""
+            blist, n_occ = block_list(occ)
             with jax.named_scope(scope):
                 h = leaf_histogram_blocklist(
-                    bins_t, gh2, leaf_eff, target, blist, n_occ,
+                    bins_t, gh2, leaf_id, target, blist, n_occ,
                     max_bin=max_bin, interpret=interpret).astype(dtype)
             # (occupied blocks, row steps the kernel ran): an empty leaf
             # still runs one step
-            return hist_psum(h), jnp.stack([n_occ, jnp.maximum(n_occ, 1)])
-    elif hist_impl == "pallas":
-        def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
-            with jax.named_scope(scope):
-                leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
-                h = leaf_histogram_masked(
-                    bins_t, gh2, leaf_eff, target, max_bin=max_bin,
-                    interpret=interpret).astype(dtype)
-            return hist_psum(h), no_blocks
+            return hist_psum(h), jnp.stack([n_occ, jnp.maximum(n_occ, 1),
+                                            jnp.int32(0)])
+
+        groups = part_groups(nblocks)
+        # row blocks of each group: PART_BLOCKS, the last what is left
+        group_width = jnp.diff(jnp.minimum(
+            jnp.arange(groups + 1) * PART_BLOCKS, nblocks)).astype(jnp.int32)
+
+        def partition(st, bl, right, feature, threshold, keep, wl, wr):
+            """The split's ONE pass over the groups of row blocks the
+            split leaf lies in: the new ids, the table with the children's
+            rows, and the blocks visited."""
+            was = st.occ[bl]
+            with jax.named_scope(spans.BLOCK_LIST):
+                held = jnp.pad(was, (0, groups * PART_BLOCKS - nblocks)) \
+                    .reshape(groups, PART_BLOCKS).any(axis=1)
+            glist, n_held = block_list(held)
+            leaf_id, left, right_ = leaf_partition_blocklist(
+                bins_t, st.leaf_id, glist, n_held, bl, right, feature,
+                threshold, keep, interpret=interpret)
+            with jax.named_scope(spans.BLOCK_LIST):
+                # a block the pass did not visit held no row of the leaf
+                # (the tables take whole rows: no layout to choose)
+                occ = st.occ.at[wl].set(was & left[0]) \
+                            .at[wr].set(was & right_[0])
+                occ_bag = st.occ_bag.at[wl].set(was & left[1]) \
+                                    .at[wr].set(was & right_[1])
+                visited = jnp.sum(jnp.where(held & keep, group_width, 0))
+            return leaf_id, occ, occ_bag, jnp.stack(
+                [0, 0, visited]).astype(jnp.int32)
+
+        with jax.named_scope(spans.BLOCK_LIST):
+            leaf_id0 = fold_bag_bit(bag_mask)
+            occ0 = jnp.zeros((max_leaves + 1, nblocks), dtype=bool)
+            occ_bag0 = occ0.at[0].set(
+                bag_mask.reshape(nblocks, PALLAS_ROW_BLOCK).any(axis=1))
+            occ0 = occ0.at[0].set(True)
     else:
-        def hist_leaf(leaf_id, target, scope=spans.HIST_SWEEP):
-            with jax.named_scope(scope):
-                gv = make_gvals(grad, hess, (leaf_id == target) & bag_mask,
-                                dtype)
-                h = leaf_histogram(bins_t, gv, max_bin=max_bin,
-                                   row_chunk=row_chunk)
-            return hist_psum(h), no_blocks
+        leaf_id0 = jnp.zeros(n, dtype=jnp.int32)
+        occ0 = occ_bag0 = jnp.zeros((max_leaves + 1, 0), dtype=bool)
+        if hist_impl == "pallas":
+            def hist_leaf(leaf_id, target, occ, scope=spans.HIST_SWEEP):
+                with jax.named_scope(scope):
+                    leaf_eff = fold_leaf_mask(leaf_id, bag_mask)
+                    h = leaf_histogram_masked(
+                        bins_t, gh2, leaf_eff, target, max_bin=max_bin,
+                        interpret=interpret).astype(dtype)
+                return hist_psum(h), no_blocks
+        else:
+            def hist_leaf(leaf_id, target, occ, scope=spans.HIST_SWEEP):
+                with jax.named_scope(scope):
+                    gv = make_gvals(grad, hess,
+                                    (leaf_id == target) & bag_mask, dtype)
+                    h = leaf_histogram(bins_t, gv, max_bin=max_bin,
+                                       row_chunk=row_chunk)
+                return hist_psum(h), no_blocks
+
+        def partition(st, bl, right, feature, threshold, keep, wl, wr):
+            """One vectorized compare over every row (replaces
+            DataPartition::Split, data_partition.hpp:84-132)."""
+            go_right = (keep & (st.leaf_id == bl)
+                        & feature_go_right(feature, threshold))
+            return (jnp.where(go_right, right, st.leaf_id), st.occ,
+                    st.occ_bag, no_blocks)
 
     def packed_best(best, depth):
         """The depth gate (no split at max_depth) and the packing."""
@@ -377,8 +446,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         return _pack_best(best, dtype)
 
     # ---- root ----
-    root_hist, root_swept = hist_leaf(jnp.zeros(n, dtype=jnp.int32),
-                                      jnp.int32(0), scope=spans.HIST_ROOT)
+    root_hist, root_swept = hist_leaf(leaf_id0, jnp.int32(0), occ_bag0[0],
+                                      scope=spans.HIST_ROOT)
     # every row lands in exactly one bin of feature 0, so its histogram sums
     # are the root totals (LeafSplits::Init root sumup, leaf_splits.hpp:36-117);
     # in voting mode the hist is local, so all-reduce the three scalars
@@ -415,8 +484,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                    .at[0].set(root_hist)
     state = GrowState(
         tree=tree,
-        leaf_id=jnp.zeros(n, dtype=jnp.int32),
-        hist=hist0,
+        leaf_id=leaf_id0, occ=occ0, occ_bag=occ_bag0, hist=hist0,
         leaf_sum_g=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_g),
         leaf_sum_h=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_h),
         best_f=best_f0, best_i=best_i0, swept=root_swept,
@@ -491,13 +559,6 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 num_leaves=tree.num_leaves + keep.astype(jnp.int32),
             )
 
-        # --- partition: one vectorized compare (replaces DataPartition::Split,
-        # src/treelearner/data_partition.hpp:84-132) ---
-        with jax.named_scope(spans.PARTITION):
-            go_right = (keep & (st.leaf_id == bl)
-                        & feature_go_right(s_feature, s_threshold))
-            leaf_id = jnp.where(go_right, right, st.leaf_id)
-
         # --- histograms: smaller child scanned, larger by subtraction ---
         with jax.named_scope(spans.HIST_POOL):
             left_is_smaller = si[BI_LCNT] <= si[BI_RCNT]
@@ -506,17 +567,28 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                 # parent histogram from its pool slot, or a full recompute
                 # when it was LRU-evicted (the reference recomputes evicted
                 # leaves the same way, feature_histogram.hpp:275-398 +
-                # serial_tree_learner.cpp BeforeFindBestSplit)
+                # serial_tree_learner.cpp BeforeFindBestSplit); it reads
+                # the ids BEFORE the partition pass writes them in place
                 parent_slot = st.leaf_slot[bl]
                 parent_hist, reswept = jax.lax.cond(
                     parent_slot >= 0,
                     lambda: (st.hist[jnp.clip(parent_slot, 0, K - 1)],
                              no_blocks),
-                    lambda: hist_leaf(st.leaf_id, bl))
+                    lambda: hist_leaf(st.leaf_id, bl, st.occ_bag[bl]))
             else:
                 parent_hist, reswept = st.hist[bl], no_blocks
+
+        # --- partition: the split leaf's rows over the threshold take the
+        # new leaf's index; no data movement ---
+        with jax.named_scope(spans.PARTITION):
+            leaf_id, occ, occ_bag, parted = partition(
+                st, bl, right, s_feature, s_threshold, keep, wl, wr)
+
         with jax.named_scope(spans.HIST_SWEEP):
-            small_hist, swept = hist_leaf(leaf_id, small_leaf)
+            # (a step after growth has stopped sweeps nothing: its
+            # histograms would land in the dummy slot)
+            small_hist, swept = hist_leaf(leaf_id, small_leaf,
+                                          occ_bag[small_leaf] & keep)
         with jax.named_scope(spans.HIST_POOL):
             large_hist = parent_hist - small_hist
             left_hist = jnp.where(left_is_smaller, small_hist, large_hist)
@@ -570,18 +642,21 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
             best_f = st.best_f.at[wl].set(lbf).at[wr].set(rbf)
             best_i = st.best_i.at[wl].set(lbi).at[wr].set(rbi)
 
-        return GrowState(tree=new_tree, leaf_id=leaf_id, hist=hist,
+        return GrowState(tree=new_tree, leaf_id=leaf_id, occ=occ,
+                         occ_bag=occ_bag, hist=hist,
                          leaf_sum_g=leaf_sum_g, leaf_sum_h=leaf_sum_h,
                          best_f=best_f, best_i=best_i,
-                         swept=st.swept + reswept + swept,
+                         swept=st.swept + reswept + parted + swept,
                          leaf_slot=leaf_slot, slot_leaf=slot_leaf,
                          slot_used=slot_used), None
 
     final, _ = jax.lax.scan(step, state,
                             jnp.arange(1, max_leaves, dtype=jnp.int32))
     swept = psum(final.swept)
-    return final.tree._replace(blocks_swept=swept[0],
-                               grid_rows=swept[1]), final.leaf_id
+    with jax.named_scope(spans.PARTITION):
+        leaf_id = leaf_of(final.leaf_id) if ranged_on else final.leaf_id
+    return final.tree._replace(blocks_swept=swept[0], grid_rows=swept[1],
+                               partition_blocks=swept[2]), leaf_id
 
 
 @contract.traced_pure

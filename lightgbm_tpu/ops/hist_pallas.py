@@ -42,7 +42,8 @@ ops/histogram.py, for the same reason.  Only the interpreted kernels
 
 Two wrappers, one per sweep mode: leaf_histogram_masked sweeps every row
 block; leaf_histogram_blocklist sweeps the blocks a list names (the
-ordered-partition mode of ops/grow.py).  The gain scan is a separate XLA
+ordered-partition mode of ops/grow.py), where leaf_partition_blocklist,
+at the end of this file, is the split's pass over the leaf ids.  The gain scan is a separate XLA
 pass over the [F, B, 3] tensor (ops/split.find_best_split): 0.05% of a
 tree at 68M x 39 (PERF.md, PR 28's ledger lines).
 
@@ -319,3 +320,159 @@ def leaf_histogram_blocklist(bins_t: jax.Array, gh2: jax.Array,
         name="leaf_histogram_blocklist",
     )(target, blist, bins_t, gh2, leaf_eff)
     return _diag_hist_xla(out, fpad)[:f, :max_bin, :]
+
+
+# ---- the partition pass of the ordered mode (ops/grow.py step) ----
+# In the block-list mode the leaf ids carry the bag: an out-of-bag row's
+# id has OOB_BIT set, folded ONCE a tree (fold_bag_bit), so such a row
+# never equals a sweep's target, still follows its leaf through the
+# splits, and its leaf is the bits below (leaf_of).
+OOB_BIT = 1 << 30
+PART_FEATS = 8    # bin rows a partition step reads: one sublane tile
+# Row blocks a partition step covers.  A step costs 0.33 us for its DMAs'
+# issue and wait whatever it moves and 0.2 us a block for the work on
+# its rows (TPU v5e: PERF.md, PR 34), and a leaf's blocks lie in runs
+# (its rows are spread thin through the ranges of an older tree's
+# leaves), so the pass walks the leaf's GROUPS of PART_BLOCKS blocks.
+PART_BLOCKS = 4
+_LANES = 128
+_RIGHT = 16       # a flag word counts rows that stay below this bit
+
+
+def fold_bag_bit(mask: jax.Array) -> jax.Array:
+    """[N] i32 ids of a tree's root: 0 where mask, OOB_BIT elsewhere."""
+    return jnp.where(mask, jnp.int32(0), jnp.int32(OOB_BIT))
+
+
+def leaf_of(leaf_id: jax.Array) -> jax.Array:
+    """The leaf of every row of ids that carry the bag (fold_bag_bit)."""
+    return leaf_id & jnp.int32(OOB_BIT - 1)
+
+
+def part_groups(nblocks: int, part_blocks: int = PART_BLOCKS) -> int:
+    """Groups of part_blocks row blocks that cover nblocks (the last may
+    run past the array)."""
+    return -(-nblocks // part_blocks)
+
+
+def _partition_kernel(glist_ref, split_ref, bins_ref, ids_ref, out_ref,
+                      flags_ref, row_ref, *, block_rows):
+    """glist_ref and split_ref are the scalar-prefetch operands (SMEM):
+    the list the index maps read, and (split leaf or -1, new leaf - split
+    leaf, feature, threshold).  Everything is [1, rows]: ids and bin row
+    lie along the lanes, as the sweep kernel reads them."""
+    i32 = jnp.int32     # (spelled out: python ints are int64 under x64)
+    rows = ids_ref.shape[0]
+    ids = ids_ref[:].reshape(1, rows)
+    # the feature's row of the tile, by a scalar branch: the store lays
+    # it out the same whichever row it was (as a `lax.switch` result the
+    # later rows cost twice the first: PERF.md, PR 34)
+    which = jax.lax.rem(split_ref[2], i32(PART_FEATS))
+    for k in range(PART_FEATS):
+        @pl.when(which == k)
+        def _(k=k):
+            row_ref[...] = bins_ref[k:k + 1, :].astype(i32)
+    mine = leaf_of(ids) == split_ref[0]
+    go_right = mine & (row_ref[...] > split_ref[3])
+    # the new leaf's index under the row's own bag bit
+    out_ref[:] = jnp.where(go_right, ids + split_ref[1], ids).reshape(rows)
+    # per block: rows that stay, and from bit _RIGHT up rows that go; over
+    # any row, then over in-bag rows.  The counts go where the caller
+    # reads them as they lie: block b of the pass at [b // 128, b % 128]
+    # of a table that stays in VMEM through the grid (a group's blocks
+    # share a row; an output block a step cost a fourth DMA, and its
+    # [group, block, lane] form cost the caller 0.2-0.5 ms a split to
+    # read: PERF.md, PR 34).  What no step wrote is whatever was there.
+    moved = jnp.where(mine, jnp.where(go_right, i32(1 << _RIGHT), i32(1)),
+                      i32(0))
+    part_blocks = rows // block_rows
+    group = glist_ref[pl.program_id(0)]
+    at_row = jax.lax.div(group, i32(_LANES // part_blocks))
+    lane0 = jax.lax.rem(group, i32(_LANES // part_blocks)) * part_blocks
+    lane = jax.lax.broadcasted_iota(i32, (1, _LANES), 1)
+    for k, counts in enumerate((moved, jnp.where(ids < OOB_BIT, moved,
+                                                 i32(0)))):
+        held = flags_ref[k, pl.ds(at_row, 1), :]
+        for j in range(part_blocks):
+            held = jnp.where(lane == lane0 + j, jnp.sum(
+                counts[:, j * block_rows:(j + 1) * block_rows], axis=1,
+                keepdims=True, dtype=i32), held)
+        flags_ref[k, pl.ds(at_row, 1), :] = held
+
+
+@functools.partial(jax.jit, static_argnames=("row_block", "part_blocks",
+                                             "interpret"))
+def leaf_partition_blocklist(bins_t: jax.Array, leaf_id: jax.Array,
+                             group_list: jax.Array, n_active, split_leaf,
+                             new_leaf, feature, threshold, keep, *,
+                             row_block: int = PALLAS_ROW_BLOCK,
+                             part_blocks: int = PART_BLOCKS,
+                             interpret: bool = False):
+    """One split's partition over the groups of part_blocks row blocks
+    group_list[:n_active]: rows of split_leaf whose bin of `feature` is
+    over `threshold` move to new_leaf.  Returns (leaf_id, left, right):
+    the ids, and [2, nblocks] bool each.
+
+    leaf_id [N] i32 carries the bag (fold_bag_bit) and is written IN
+    PLACE: a group the list does not name is neither read nor written.
+    Correct whenever every row of split_leaf lies in a listed group.
+    left[i, b] says of a row block b of a LISTED group whether the left
+    child (the rows that stay) has a row there, any row (i = 0) or an
+    in-bag row (i = 1), right[i, b] the same of the rows that go; of any
+    other block they say nothing (the memory is whatever it was), so the
+    caller masks them with the occupancy the list was made from.
+
+    The grid's extent is n_active, read at run time as
+    leaf_histogram_blocklist's is; a step reads its rows of the ids and
+    the tile of PART_FEATS bin rows that holds `feature` (the index map
+    picks it, the kernel the row).  keep False (the grow scan's steps
+    after growth has stopped) or an empty list runs one step, over
+    group_list[0], that moves no row.  Where part_blocks does not divide
+    the blocks the last group runs past the arrays, as a feature block
+    does (_feat_grid): what it reads there reaches no row and no flag
+    that is returned.
+    """
+    f, n = bins_t.shape
+    rows = part_blocks * row_block
+    assert n % row_block == 0 and row_block % _LANES == 0, (n, row_block)
+    assert _LANES % part_blocks == 0, part_blocks
+    nblocks = n // row_block
+    groups = part_groups(nblocks, part_blocks)
+    table_rows = -(-groups * part_blocks // _LANES)
+    glist = jnp.clip(group_list.astype(jnp.int32), 0, groups - 1)
+    n_active = jnp.asarray(n_active, jnp.int32)
+    keep = jnp.asarray(keep, jnp.bool_) & (n_active > 0)
+    steps = jnp.clip(jnp.where(keep, n_active, 0), 1, groups)
+    split_leaf = jnp.asarray(split_leaf, jnp.int32)
+    split = jnp.stack([
+        jnp.where(keep, split_leaf, -1),     # no leaf: nothing matches
+        jnp.asarray(new_leaf, jnp.int32) - split_leaf,
+        jnp.asarray(feature, jnp.int32), jnp.asarray(threshold, jnp.int32)])
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(steps,),
+        in_specs=[
+            pl.BlockSpec((PART_FEATS, rows),
+                         lambda r, gl, s: (jax.lax.div(
+                             s[2], jnp.int32(PART_FEATS)), gl[r])),
+            pl.BlockSpec((rows,), lambda r, gl, s: (gl[r],)),
+        ],
+        out_specs=[
+            pl.BlockSpec((rows,), lambda r, gl, s: (gl[r],)),
+            pl.BlockSpec((2, table_rows, _LANES), lambda r, gl, s: (0, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((1, rows), jnp.int32)],
+    )
+    ids, counts = pl.pallas_call(
+        functools.partial(_partition_kernel, block_rows=row_block),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32),
+                   jax.ShapeDtypeStruct((2, table_rows, _LANES), jnp.int32)],
+        # operand 3 (after the two prefetched) is the ids: output 0
+        input_output_aliases={3: 0},
+        interpret=interpret,
+        name="leaf_partition_blocklist",
+    )(glist, split, bins_t, leaf_id)
+    counts = counts.reshape(2, table_rows * _LANES)[:, :nblocks]
+    return ids, (counts & ((1 << _RIGHT) - 1)) != 0, (counts >> _RIGHT) != 0

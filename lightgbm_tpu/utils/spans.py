@@ -24,12 +24,12 @@ __jax_free__ = True
 OBJECTIVE = "lgbm.objective"          # grad_fn and the dtype casts
 GROW = "lgbm.grow"                    # the grow_tree_bagged call
 HIST_ROOT = "lgbm.hist_root"          # the root's full sweep and root sums
-BLOCK_LIST = "lgbm.block_list"        # occupancy scan, block list, its argsort
+BLOCK_LIST = "lgbm.block_list"        # occupancy table, block lists' argsorts
 HIST_SWEEP = "lgbm.hist_sweep"        # gh2, the bin-matrix pad, the kernel
 HIST_POOL = "lgbm.hist_pool"          # parent - small, select, pool writes
 HIST_EXCHANGE = "lgbm.hist_exchange"  # collectives of the sharded learners
 GAIN_SCAN = "lgbm.gain_scan"          # best split of a leaf, packed
-PARTITION = "lgbm.partition"          # go-right compare, leaf_id update
+PARTITION = "lgbm.partition"          # the partition kernel, or the compare
 TREE_UPDATE = "lgbm.tree_update"      # arg-max over leaves, TreeArrays writes
 OOB_DESCENT = "lgbm.oob_descent"      # bag compaction's out-of-bag descent
 SCORE_UPDATE = "lgbm.score_update"    # leaf-value gather-add on the scores
@@ -58,7 +58,8 @@ ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call;
 #                                       a re-sorting one adds carried, taken,
 #                                       the arrangement also window, in_bag
 FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
-#                                       grid_rows, feat_groups, block_matmuls
+#                                       grid_rows, partition_blocks,
+#                                       feat_groups, block_matmuls
 #                                       (a row step's feature groups and
 #                                       matmuls), and the objective's own
 #                                       counters (Objective.trace_counters:

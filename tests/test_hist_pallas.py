@@ -7,12 +7,14 @@ import numpy as np
 import pytest
 
 from lightgbm_tpu.ops.histogram import leaf_histogram, make_gvals
-from lightgbm_tpu.ops.hist_pallas import (FEAT_BLOCK_CAP, MM_FEATS,
+from lightgbm_tpu.ops.hist_pallas import (FEAT_BLOCK_CAP, MM_FEATS, OOB_BIT,
                                           PALLAS_ROW_BLOCK, T_MM_NS,
                                           T_STEP_NS, _feat_grid,
-                                          fold_leaf_mask,
+                                          fold_bag_bit, fold_leaf_mask,
                                           leaf_histogram_blocklist,
-                                          leaf_histogram_masked, make_gh2)
+                                          leaf_histogram_masked,
+                                          leaf_of, leaf_partition_blocklist,
+                                          make_gh2, part_groups)
 
 
 def _data(n, f, b, seed=0):
@@ -243,10 +245,10 @@ def test_blocklist_kernel_bit_identical_to_masked(occupied, blist):
     assert bool(occupied) == bool(float(jnp.abs(got).max()) > 0.0)
 
 
-def test_grow_tree_ranged_bit_identical():
-    """ranged=True (block-list sweeps) must grow the IDENTICAL tree to
-    the plain pallas full sweep for the same row order."""
-    from lightgbm_tpu.ops.grow import grow_tree
+def _grown(case, ranged):
+    """(tree, leaf_id) of one tree over four row blocks, the block-list
+    mode on or off, in one of the settings that reach it."""
+    from lightgbm_tpu.ops.grow import grow_tree, grow_tree_bagged
     from lightgbm_tpu.ops.split import SplitParams
     n = 8192 * 4
     f, b = 6, 64
@@ -254,19 +256,115 @@ def test_grow_tree_ranged_bit_identical():
     bins_t = rng.randint(0, b, size=(f, n)).astype(np.uint8)
     grad = (bins_t[0] / b - 0.5 + 0.2 * rng.randn(n)).astype(np.float32)
     hess = np.ones(n, dtype=np.float32)
-    params = SplitParams(20, 1.0, 0.0, 0.0, 0.0)
     bag = rng.rand(n) < 0.9   # bagging must also be exact
-    args = (jnp.asarray(bins_t), jnp.asarray(grad), jnp.asarray(hess),
-            jnp.asarray(bag), jnp.ones(f, dtype=bool))
-    kw = dict(max_leaves=8, max_bin=b, params=params, hist_impl="pallas")
-    t0, l0 = grow_tree(*args, **kw)
-    t1, l1 = grow_tree(*args, ranged=True, **kw)
-    assert int(t0.num_leaves) == int(t1.num_leaves)
+    kw = dict(max_leaves=8, max_bin=b, hist_impl="pallas", ranged=ranged,
+              params=SplitParams(20, 1.0, 0.0, 0.0, 0.0))
+    grow = grow_tree
+    if case == "pool":
+        # two slots for eight leaves: parents are evicted and recomputed
+        kw["hist_slots"] = 2
+    elif case == "window":
+        # in-bag rows first, a window of three blocks that holds them
+        # all and some out-of-bag rows; the fourth block is replayed
+        bag = np.arange(n) < 8192 * 2 + 5000
+        kw["bag_rows"] = 8192 * 3
+        grow = grow_tree_bagged
+    elif case == "shards":
+        from jax.sharding import Mesh
+        from lightgbm_tpu.parallel.mesh import (DATA_AXIS, P,
+                                                _sharded_grow_fn)
+        grow = _sharded_grow_fn(
+            Mesh(np.array(jax.devices()[:2]), (DATA_AXIS,)),
+            dict(kw, psum_axis=DATA_AXIS, num_shards=2),
+            in_specs=(P(None, DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
+                      P(DATA_AXIS), P(None)),
+            leaf_id_spec=P(DATA_AXIS))
+        kw = {}
+    return grow(jnp.asarray(bins_t), jnp.asarray(grad), jnp.asarray(hess),
+                jnp.asarray(bag), jnp.ones(f, dtype=bool), **kw)
+
+
+@pytest.mark.parametrize("case", ["bag", "pool", "shards", "window"])
+def test_grow_tree_ranged_bit_identical(case):
+    """ranged=True (block-list sweeps, the partition pass over the split
+    leaf's own blocks, occupancy as state) must grow the IDENTICAL tree,
+    and return the identical leaf ids, to the plain pallas full sweep
+    with its vectorised compare, for the same row order: under a bag
+    mask, with a histogram pool that evicts parents (the recompute reads
+    the ids before the pass writes them in place), on two shards under
+    psum_axis (occupancy, lists and grids are shard-local), and through
+    grow_tree_bagged's window."""
+    t0, l0 = _grown(case, ranged=False)
+    t1, l1 = _grown(case, ranged=True)
+    assert int(t0.num_leaves) == int(t1.num_leaves) == 8
     np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
-    for fld in ("split_feature", "threshold_bin", "leaf_value",
-                "leaf_count"):
-        np.testing.assert_array_equal(np.asarray(getattr(t0, fld)),
-                                      np.asarray(getattr(t1, fld)))
+    counters = ("blocks_swept", "grid_rows", "partition_blocks")
+    for fld in t0._fields:
+        if fld not in counters:
+            np.testing.assert_array_equal(np.asarray(getattr(t0, fld)),
+                                          np.asarray(getattr(t1, fld)))
+    assert [int(getattr(t0, c)) for c in counters] == [0, 0, 0]
+    assert all(int(getattr(t1, c)) > 0 for c in counters)
+
+
+def _partition_case(case):
+    """(row blocks, part_blocks, listed groups, list handed over, keep)"""
+    return {
+        # groups in any order behind the listed ones; ascending in front
+        "random": (12, 2, [1, 2, 4], [1, 2, 4, 5, 3, 0], True),
+        "one_block_groups": (6, 1, [0, 3, 5], [0, 3, 5, 1, 2, 4], True),
+        "every_group": (8, 4, [0, 1], [0, 1], True),
+        # 7 blocks in groups of 4: the second group runs past the arrays
+        "ragged_last": (7, 4, [0, 1], [0, 1], True),
+        "ragged_alone": (9, 4, [2], [2, 0, 1], True),
+        # an empty list runs one step over its first entry, moving nothing
+        "empty": (8, 2, [], [3, 0, 1, 2], True),
+        # growth has stopped: one step that matches nothing
+        "keep_false": (8, 2, [0, 2], [0, 2, 1, 3], False),
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["random", "one_block_groups",
+                                  "every_group", "ragged_last",
+                                  "ragged_alone", "empty", "keep_false"])
+def test_partition_kernel_against_numpy_recount(case):
+    """leaf_partition_blocklist against a numpy recount: the ids (rows of
+    the split leaf over the threshold take the new leaf under their own
+    bag bit; a group the list does not name keeps every byte, as does
+    every group when keep is False or the list is empty) and, of every
+    block of a listed group, which child has a row there (the rows that
+    stay, the rows that went), any row and in-bag row."""
+    nblocks, pb, listed, glist, keep = _partition_case(case)
+    rb, f, bl, right, feature, thr = 256, 13, 2, 9, 11, 120
+    n = nblocks * rb
+    assert part_groups(nblocks, pb) == len(glist)
+    rng = np.random.RandomState(nblocks * pb)
+    bins = rng.randint(0, 255, size=(f, n)).astype(np.uint8)
+    leaf = rng.randint(0, 5, size=n).astype(np.int32)
+    leaf[:rb] = 1                               # a block without the leaf
+    bag = rng.rand(n) < 0.7
+    bag[rb:2 * rb] = False                      # a block wholly out of bag
+    ids = np.asarray(fold_bag_bit(jnp.asarray(bag))) | leaf
+    assert (np.asarray(leaf_of(jnp.asarray(ids))) == leaf).all()
+    got, left, went = leaf_partition_blocklist(
+        jnp.asarray(bins), jnp.asarray(ids), jnp.asarray(glist, jnp.int32),
+        jnp.int32(len(listed)), bl, right, feature, thr, keep,
+        row_block=rb, part_blocks=pb, interpret=True)
+    got, left, went = np.asarray(got), np.asarray(left), np.asarray(went)
+    assert left.shape == went.shape == (2, nblocks)
+    want = ids.copy()
+    visited = [b for g in (listed if keep else ())
+               for b in range(g * pb, min((g + 1) * pb, nblocks))]
+    for blk in visited:
+        rows = slice(blk * rb, (blk + 1) * rb)
+        mine = leaf[rows] == bl
+        goes = mine & (bins[feature, rows] > thr)
+        stays = mine & ~goes
+        want[rows] = np.where(goes, right | (ids[rows] & OOB_BIT), ids[rows])
+        assert list(left[:, blk]) == [stays.any(), (stays & bag[rows]).any()]
+        assert list(went[:, blk]) == [goes.any(), (goes & bag[rows]).any()]
+    np.testing.assert_array_equal(got, want)
+    assert (got != ids).any() == bool(visited)
 
 
 def test_every_pallas_wrapper_has_a_grower():
@@ -285,6 +383,7 @@ def test_every_pallas_wrapper_has_a_grower():
                       if n.startswith("leaf_histogram"))
     assert wrappers == ["leaf_histogram_blocklist", "leaf_histogram_masked"]
     assert set(wrappers) <= imported, set(wrappers) - imported
+    assert "leaf_partition_blocklist" in imported
 
 
 @pytest.mark.parametrize("source", ["command_line", "config_file",

@@ -311,15 +311,19 @@ def _pallas_call_names():
 
 
 @pytest.mark.parametrize("wrapper", [
-    "leaf_histogram_masked", "leaf_histogram_blocklist"])
+    "leaf_histogram_masked", "leaf_histogram_blocklist",
+    "leaf_partition_blocklist"])
 def test_kernel_is_named_after_its_wrapper(wrapper):
     """A Pallas custom call's device event is named after the innermost
     component of its name stack: without a name= a scope around the call
-    renames it and the benchmark's `%leaf_histogram` reader goes blind."""
+    renames it and the benchmark's `%leaf_histogram` reader goes blind.
+    The partition pass is no sweep: its name keeps it out of that sum."""
     names = _pallas_call_names()
     assert sorted(names) == ["leaf_histogram_blocklist",
-                             "leaf_histogram_masked"], names
+                             "leaf_histogram_masked",
+                             "leaf_partition_blocklist"], names
     assert wrapper in names and callable(getattr(hist_pallas, wrapper))
+    assert sum(n.startswith("leaf_histogram") for n in names) == 2
 
 
 # -- the benchmark's copy --------------------------------------------------

@@ -12,7 +12,11 @@ that many row steps, and one where a shard holds none: a run-time bound,
 no compiled worst case (PERF.md section 6, PR 30).  Beside them stand
 `feat_groups` and `block_matmuls`: what ONE row step costs in feature
 groups (grid steps) and block-diagonal matmuls, static, from F alone
-(ops/hist_pallas.py row_step; PR 32).
+(ops/hist_pallas.py row_step; PR 32).  And `partition_blocks` (PR 34):
+the row blocks the partition passes visited, which at each split are the
+groups of PART_BLOCKS blocks of each shard that hold a row of the split
+leaf (the last group of a shard holds what is left), where the two
+passes over every id visited shards x blocks.
 """
 
 import jax
@@ -21,7 +25,8 @@ import pytest
 from test_spans import _program_spans
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.ops.hist_pallas import PALLAS_ROW_BLOCK, row_step
+from lightgbm_tpu.ops.hist_pallas import (PALLAS_ROW_BLOCK, PART_BLOCKS,
+                                          part_groups, row_step)
 from lightgbm_tpu.utils import spans
 
 LEAVES = 7
@@ -54,19 +59,33 @@ def _train_traced(x, y, extra, trace_dir):
 
 
 def _recount(trees, bins, shards):
-    """(occupied blocks, grid rows) of each shard, summed over the trees'
-    sweeps, the shard-local row order followed through the re-sorts."""
+    """(occupied blocks, grid rows, blocks partitioned) of each shard,
+    summed over the trees' sweeps and splits, the shard-local row order
+    followed through the re-sorts."""
     n = bins.shape[1]
     per = n // shards
+    blocks = per // PALLAS_ROW_BLOCK
     order = np.arange(n)            # position -> file row, shard by shard
     occupied = np.zeros(shards, np.int64)
     grid = np.zeros(shards, np.int64)
+    parted = np.zeros(shards, np.int64)
+    groups = part_groups(blocks)
+    width = np.diff(np.minimum(np.arange(groups + 1) * PART_BLOCKS, blocks))
+
+    def held_blocks(leaf_of_row, target):
+        return (leaf_of_row[order] == target).reshape(
+            shards, blocks, PALLAS_ROW_BLOCK).any(axis=2)
 
     def sweep(leaf_of_row, target):
-        held = (leaf_of_row[order] == target).reshape(
-            shards, per // PALLAS_ROW_BLOCK, PALLAS_ROW_BLOCK).any(axis=2)
+        held = held_blocks(leaf_of_row, target)
         occupied[:] += held.sum(axis=1)
         grid[:] += np.maximum(held.sum(axis=1), 1)
+
+    def partition(leaf_of_row, split):
+        held = np.pad(held_blocks(leaf_of_row, split),
+                      ((0, 0), (0, groups * PART_BLOCKS - blocks)))
+        parted[:] += (held.reshape(shards, groups, PART_BLOCKS).any(axis=2)
+                      * width).sum(axis=1)
 
     for t, tree in enumerate(trees):
         assert tree.num_leaves == LEAVES    # no step past the last split
@@ -77,6 +96,7 @@ def _recount(trees, bins, shards):
             while split >= 0:               # split leaf's index
                 split = tree.left_child[split]
             split, right = ~split, node + 1
+            partition(leaf, split)
             go_right = ((leaf == split) & (bins[tree.split_feature[node]]
                                            > tree.threshold_bin[node]))
             leaf[go_right] = right
@@ -87,7 +107,7 @@ def _recount(trees, bins, shards):
                 part = order[s * per:(s + 1) * per]
                 order[s * per:(s + 1) * per] = part[
                     np.argsort(leaf[part], kind="stable")]
-    return occupied, grid
+    return occupied, grid, parted
 
 
 @pytest.mark.parametrize("shards,blocks", [(1, 6), (4, 3)],
@@ -100,9 +120,14 @@ def test_flush_counts_the_blocks_the_sweeps_ran(shards, blocks, tmp_path):
     gbdt = booster._gbdt
     assert gbdt.hist_ranged and gbdt._row_order is not None
     assert sum(s["trees"] for s in flushes) == ROUNDS == len(gbdt.models)
-    occupied, grid = _recount(gbdt.models, gbdt.train_data.bins, shards)
+    occupied, grid, parted = _recount(gbdt.models, gbdt.train_data.bins,
+                                      shards)
     assert sum(s["blocks_swept"] for s in flushes) == occupied.sum()
     assert sum(s["grid_rows"] for s in flushes) == grid.sum()
+    # the partition passes: the split leaf's groups of blocks, not every
+    # block of every shard at every split
+    assert sum(s["partition_blocks"] for s in flushes) == parted.sum()
+    assert 0 < parted.sum() < ROUNDS * (LEAVES - 1) * shards * blocks
     # what a row step costs: 6 features are one group of 8, two matmuls,
     # the same on every shard (rows are sharded, features are not)
     assert {(s["feat_groups"], s["block_matmuls"]) for s in flushes} \
@@ -139,7 +164,8 @@ def test_counters_read_zero_off_the_block_list(extra, tmp_path):
     booster, flushes = _train_traced(x, y, extra, str(tmp_path))
     assert not booster._gbdt.hist_ranged
     assert sum(s["trees"] for s in flushes) == ROUNDS
-    assert all(s["blocks_swept"] == s["grid_rows"] == 0 for s in flushes)
+    assert all(s["blocks_swept"] == s["grid_rows"] == s["partition_blocks"]
+               == 0 for s in flushes)
     # the masked kernel runs the same feature grid (over every row
     # block); the XLA sweep runs no kernel and counts none
     want = (0, 0) if extra.get("hist_impl") == "xla" else (1, 2)
