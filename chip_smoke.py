@@ -69,56 +69,30 @@ MODEL_ARGS = ["objective=binary", "num_leaves=63", "max_bin=255",
               "is_save_binary_file=false"]
 LN2 = 0.6931471805599453   # log-loss of the all-zero initial score
 
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
-
-
 def check(cond: bool, msg: str) -> None:
     if not cond:
         raise RuntimeError("chip_smoke: " + msg)
 
 
-class CompileMeter:
-    """Backend-compile seconds and persistent-cache hits/misses, from
-    JAX's own monitoring events.  On a cache hit the compile event still
-    fires, timing the deserialization — that is the warm run's
-    'near-zero'."""
-
-    def __init__(self) -> None:
-        import jax.monitoring
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _dur(self, event: str, duration: float, **_kw) -> None:
-        if event == BACKEND_COMPILE_EVENT:
-            self.seconds += duration
-
-    def _event(self, event: str, **_kw) -> None:
-        if event == CACHE_HIT_EVENT:
-            self.hits += 1
-        elif event == CACHE_MISS_EVENT:
-            self.misses += 1
-
-    def snapshot(self):
-        return (self.seconds, self.hits, self.misses)
-
-
 @contextlib.contextmanager
-def phase(name: str, meter: CompileMeter):
-    """Wall and compile accounting of one phase (compile is set-up time,
-    reported apart from the wall it is part of)."""
-    s0, h0, m0 = meter.snapshot()
+def phase(name: str):
+    """Wall and compile accounting of one phase, the compile from the
+    program's own ledger (lightgbm_tpu/utils/compile_cache.py): compile is
+    set-up time, reported apart from the wall it is part of.  On a cache
+    hit `compile_s` is the executable's load, the warm run's 'near-zero'."""
+    from lightgbm_tpu.utils import compile_cache
+    before = compile_cache.totals()
     t0 = time.time()
     yield
-    s1, h1, m1 = meter.snapshot()
+    after = compile_cache.totals()
+    spent = {k: after[k] - before[k] for k in after}
     print("chip_smoke: phase %s %s" % (name, json.dumps(
         {"wall_s": round(time.time() - t0, 2),
-         "compile_s": round(s1 - s0, 2),
-         "cache_hits": h1 - h0, "cache_misses": m1 - m0})), flush=True)
+         "trace_lower_s": round(spent["trace_s"] + spent["lower_s"], 2),
+         "compile_s": round(spent["backend_s"] + spent["retrieval_s"], 2),
+         "executables": spent["executables"],
+         "cache_hits": spent["hits"], "cache_misses": spent["misses"]})),
+          flush=True)
 
 
 class Tee:
@@ -441,23 +415,26 @@ def train_four_chips(data: str, shards: int, trees: int) -> None:
 
 
 def run(chips: int, dev: dict) -> None:
-    meter = CompileMeter()
+    from lightgbm_tpu.utils import compile_cache
+    compile_cache.enable_compilation_cache()    # the ledger, from here on
     t_all = time.time()
-    with phase("data", meter):
+    with phase("data"):
         data = make_data(ROWS)
     if chips > 1:
-        with phase("train_%dchip" % chips, meter):
+        with phase("train_%dchip" % chips):
             train_four_chips(data, chips, trees=8)
     else:
-        with phase("train", meter):
+        with phase("train"):
             model = train_one_chip(data, trees=16)
-        with phase("kernel", meter):
+        with phase("kernel"):
             check_kernel(data)
-        with phase("serve", meter):
+        with phase("serve"):
             serve_leg(model, data)
-    print("chip_smoke: wall %.1f s, of which compile (set-up) %.1f s; "
-          "persistent cache hits=%d misses=%d"
-          % (time.time() - t_all, meter.seconds, meter.hits, meter.misses),
+    print("chip_smoke: wall %.1f s; %s" % (time.time() - t_all,
+                                           compile_cache.startup_line()),
+          flush=True)
+    print("chip_smoke: compile ledger %s" % json.dumps(
+        {k: round(v, 2) for k, v in compile_cache.totals().items()}),
           flush=True)
     print(json.dumps({"ok": True, "device": dev}), flush=True)
 

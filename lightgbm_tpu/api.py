@@ -43,7 +43,7 @@ from .io import dataset as io_dataset
 from .metrics import create_metrics
 from .models.gbdt import GBDT, create_boosting
 from .objectives import create_objective
-from .utils import log
+from .utils import log, spans
 from .utils.device import resolve_device
 from .utils.mt19937 import Mt19937Random
 
@@ -106,12 +106,15 @@ class Dataset:
         self._init_score = init_score
         self._feature_names = list(feature_names) if feature_names else None
         self.free_raw_data = free_raw_data
-        if isinstance(data, str):
-            self._construct_from_file(data)
-        elif _is_sparse(data):
-            self._construct_from_sparse(data)
-        else:
-            self._construct_from_matrix(_as_dense(data))
+        with spans.startup(spans.STARTUP_DATASET) as loaded:
+            if isinstance(data, str):
+                self._construct_from_file(data)
+            elif _is_sparse(data):
+                self._construct_from_sparse(data)
+            else:
+                self._construct_from_matrix(_as_dense(data))
+            loaded["rows"] = self._inner.num_data
+            loaded["features"] = self._inner.num_features
 
     # -- construction --------------------------------------------------
     def _construct_from_file(self, path: str) -> None:

@@ -21,7 +21,7 @@ import numpy as np
 
 from . import config as config_mod
 from .config import Config
-from .utils import log
+from .utils import log, spans
 
 if TYPE_CHECKING:  # annotation-only names; runtime imports stay lazy
     from .io.dataset import Dataset
@@ -148,57 +148,61 @@ class Application:
                     self.boosting_old.load_model_from_string(f.read())
 
         self.objective = create_objective(cfg)
-        start = time.time()
-        # feature-parallel premise (reference
-        # feature_parallel_tree_learner.cpp:45-78): every machine holds
-        # ALL rows — only the bin matrix splits, along features.  Rows
-        # then need no sharding, and metrics are already global on every
-        # rank (a cross-rank sum would double-count).
-        feat_parallel = cfg.tree_learner == "feature"
-        row_rank = 0 if feat_parallel else self.rank
-        row_shards = 1 if feat_parallel else self.num_machines
-        if feat_parallel and self.rank > 0:
-            # every rank loads the full file (num_shards=1), so only
-            # rank 0 may write the .bin cache — concurrent writers would
-            # truncate each other on a shared filesystem.  (Mutated
-            # AFTER the config-fingerprint check, which already ran.)
-            cfg.is_save_binary_file = False
-        self.train_data = load_dataset(cfg.data, cfg, rank=row_rank,
-                                       num_shards=row_shards)
-        if self.boosting_old is not None:
-            self._set_init_scores(self.train_data, cfg.data)
-        reducers = None
-        if self.num_machines > 1 and not feat_parallel:
-            from .parallel.dist import make_metric_reducer
-            reducers = make_metric_reducer()
-
-        self.train_metrics = []
-        for m in create_metrics(cfg):
-            m.init("training", self.train_data.metadata,
-                   self.train_data.num_data)
-            if reducers is not None:
-                m.set_reducer(*reducers)
-            self.train_metrics.append(m)
-
-        self.valid_datas: List[Dataset] = []
-        self.valid_metricss: List[List[Metric]] = []
-        for fname in cfg.valid_data:
-            # multi-host: valid files shard per rank like the train file;
-            # metric reduction makes the reported values global
-            vd = load_dataset(fname, cfg, reference=self.train_data,
-                              rank=row_rank, num_shards=row_shards)
+        # load and binning of every file: the record's seconds are the
+        # log line's
+        loading = spans.startup(spans.STARTUP_DATASET)
+        with loading as loaded:
+            # feature-parallel premise (reference
+            # feature_parallel_tree_learner.cpp:45-78): every machine holds
+            # ALL rows — only the bin matrix splits, along features.  Rows
+            # then need no sharding, and metrics are already global on every
+            # rank (a cross-rank sum would double-count).
+            feat_parallel = cfg.tree_learner == "feature"
+            row_rank = 0 if feat_parallel else self.rank
+            row_shards = 1 if feat_parallel else self.num_machines
+            if feat_parallel and self.rank > 0:
+                # every rank loads the full file (num_shards=1), so only
+                # rank 0 may write the .bin cache — concurrent writers would
+                # truncate each other on a shared filesystem.  (Mutated
+                # AFTER the config-fingerprint check, which already ran.)
+                cfg.is_save_binary_file = False
+            self.train_data = load_dataset(cfg.data, cfg, rank=row_rank,
+                                           num_shards=row_shards)
             if self.boosting_old is not None:
-                self._set_init_scores(vd, fname)
-            ms = []
+                self._set_init_scores(self.train_data, cfg.data)
+            reducers = None
+            if self.num_machines > 1 and not feat_parallel:
+                from .parallel.dist import make_metric_reducer
+                reducers = make_metric_reducer()
+
+            self.train_metrics = []
             for m in create_metrics(cfg):
-                m.init(fname, vd.metadata, vd.num_data)
+                m.init("training", self.train_data.metadata,
+                       self.train_data.num_data)
                 if reducers is not None:
                     m.set_reducer(*reducers)
-                ms.append(m)
-            self.valid_datas.append(vd)
-            self.valid_metricss.append(ms)
-        log.info("Finished loading data, %f seconds used"
-                 % (time.time() - start))
+                self.train_metrics.append(m)
+
+            self.valid_datas: List[Dataset] = []
+            self.valid_metricss: List[List[Metric]] = []
+            for fname in cfg.valid_data:
+                # multi-host: valid files shard per rank like the train file;
+                # metric reduction makes the reported values global
+                vd = load_dataset(fname, cfg, reference=self.train_data,
+                                  rank=row_rank, num_shards=row_shards)
+                if self.boosting_old is not None:
+                    self._set_init_scores(vd, fname)
+                ms = []
+                for m in create_metrics(cfg):
+                    m.init(fname, vd.metadata, vd.num_data)
+                    if reducers is not None:
+                        m.set_reducer(*reducers)
+                    ms.append(m)
+                self.valid_datas.append(vd)
+                self.valid_metricss.append(ms)
+            loaded["rows"] = self.train_data.num_data
+            loaded["features"] = self.train_data.num_features
+        log.info("Finished loading data, %f seconds used" % loading.seconds)
 
         self.objective.init(self.train_data.metadata,
                             self.train_data.num_data)

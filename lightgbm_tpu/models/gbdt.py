@@ -35,7 +35,7 @@ from ..ops.split import SplitParams
 from ..resilience.atomic import read_npz, text_writer, write_npz
 from ..resilience.snapshot import fingerprint_diff, resume_fingerprint
 from ..resilience.faults import faultpoint
-from ..utils import log, spans
+from ..utils import compile_cache, log, spans
 from ..utils.mt19937 import Mt19937Random
 from .tree import Tree
 
@@ -207,20 +207,74 @@ def _batch_iters(body, spec, k):
 # benchmark's trees_per_dispatch.  A host counter, not a guard —
 # analysis/guards.py counts the transfers.
 _DISPATCHES = 0
+_FIRST_CALLS: set = set()    # the (kind, k, shards) that had a first call
 
 
-def _enqueue(kind: str, k: int, shards: int = 1,
-             **stats: int) -> TraceAnnotation:
+class _enqueue:
     """The span around one call of a jitted training executable:
     `kind` names it (spans.ENQUEUE_KINDS), `k` is the boosting
     iterations it covers, `shards` the devices the one program runs on;
     a dispatch that re-sorts the rows adds `carried` and `taken`
     (_resort_counts).  The call returns when the work is enqueued, not
-    when the device is done (unless it compiles first)."""
-    global _DISPATCHES
-    _DISPATCHES += 1
-    return TraceAnnotation(spans.ENQUEUE, kind=kind, k=k, shards=shards,
-                           **stats)
+    when the device is done (unless it compiles first).
+
+    While it is open it is the compile ledger's context
+    (utils/compile_cache.py): an executable traced, lowered, compiled or
+    loaded inside it lands in `compiled`, and the call was a FIRST call.
+    It then leaves a start-up record lgbm.first_call (the call's
+    seconds, the ledger's sums for it, `hit`: the persistent cache had
+    every executable, `again`: this (kind, k, shards) had a first call
+    before, so the same plan compiled anew) and tells the span
+    (`first=1`, `hit`).  Off the first call that is one test a
+    dispatch."""
+
+    __slots__ = ("key", "compiled", "_span", "_open", "_t0")
+
+    def __init__(self, kind: str, k: int, shards: int = 1,
+                 **stats: int) -> None:
+        global _DISPATCHES
+        if not _DISPATCHES:
+            spans.stamp(spans.FIRST_DISPATCH)
+        _DISPATCHES += 1
+        self.key = (kind, k, shards)
+        self.compiled: Optional[list] = None
+        self._span = TraceAnnotation(spans.ENQUEUE, kind=kind, k=k,
+                                     shards=shards, **stats)
+
+    def compiled_inside(self, record: dict) -> list:
+        """The ledger's hand-over of an executable of this call; -> the
+        call, as the record names it."""
+        if self.compiled is None:
+            self.compiled = []
+        self.compiled.append(record)
+        return list(self.key)
+
+    def __enter__(self) -> None:
+        self._span.__enter__()
+        self._open = spans.open_contexts()
+        self._open.append(self)
+        self._t0 = spans.clock()
+
+    def __exit__(self, *exc) -> None:
+        self._open.pop()
+        if self.compiled is not None:
+            self._first_call()
+        self._span.__exit__(*exc)
+
+    def _first_call(self) -> None:
+        seconds = spans.clock() - self._t0
+        sums = {f: sum(r[f] for r in self.compiled)
+                for f in ("trace_s", "lower_s", "backend_s", "retrieval_s")}
+        hit = int(all(r["hit"] for r in self.compiled
+                      if r["hit"] is not None))
+        again = int(self.key in _FIRST_CALLS)
+        _FIRST_CALLS.add(self.key)
+        kind, k, shards = self.key
+        self._span.set_metadata(first=1, hit=hit)
+        spans.record_startup(spans.FIRST_CALL, self._t0, seconds, kind=kind,
+                             k=k, shards=shards,
+                             executables=len(self.compiled), hit=hit,
+                             again=again, **sums)
 
 
 def dispatch_count() -> int:
@@ -843,6 +897,14 @@ class GBDT:
     def __init__(self, config: Config, train_data: Optional[Dataset],
                  objective: Optional[Objective],
                  training_metrics: Sequence[Metric] = ()):
+        rows = 0 if train_data is None else train_data.num_data
+        with spans.startup(spans.STARTUP_BOOSTER, rows=rows):
+            self._build(config, train_data, objective, training_metrics)
+
+    def _build(self, config: Config, train_data: Optional[Dataset],
+               objective: Optional[Objective],
+               training_metrics: Sequence[Metric]) -> None:
+        """All of __init__, inside its start-up span."""
         self.config = config
         self.train_data = train_data
         self.objective = objective
@@ -1080,42 +1142,49 @@ class GBDT:
                     and self._shard_layout is None
                     and (self.grower is None or self.rows_sharded))
         bins = None if streamed else train_data.bins
-        self.scores = self._init_scores(train_data, n)
-        if self._shard_layout is not None:
-            # query-granular layout: file rows scatter into per-shard
-            # blocks; gap rows (like trailing pad rows) stay permanently
-            # out-of-bag and their scores are never read
-            bins = self._shard_layout.place(bins)
-            self.scores = jnp.asarray(
-                self._shard_layout.place(np.asarray(self.scores)))
-        elif self.n_pad != n:
-            # a row-sharded grower pads block by block (shard_bins)
-            if bins is not None and not self.rows_sharded:
-                bins = np.pad(bins, ((0, 0), (0, self.n_pad - n)))
-            self.scores = jnp.pad(self.scores,
-                                  ((0, 0), (0, self.n_pad - n)))
-        if self.grower is not None:
-            if streamed:
-                self.bins_dev = self._put_bins_sharded_streamed(train_data)
-            elif self.rows_sharded:
-                self.bins_dev = self.grower.shard_bins(bins, self.n_pad)
+        # the bin matrix, the scores and what pads them on their way to
+        # the device; the span ends where the host's part ends (the
+        # transfers are asynchronous and nothing waits for them here)
+        with spans.startup(spans.STARTUP_UPLOAD,
+                           shards=self._shards) as uploaded:
+            self.scores = self._init_scores(train_data, n)
+            if self._shard_layout is not None:
+                # query-granular layout: file rows scatter into per-shard
+                # blocks; gap rows (like trailing pad rows) stay permanently
+                # out-of-bag and their scores are never read
+                bins = self._shard_layout.place(bins)
+                self.scores = jnp.asarray(
+                    self._shard_layout.place(np.asarray(self.scores)))
+            elif self.n_pad != n:
+                # a row-sharded grower pads block by block (shard_bins)
+                if bins is not None and not self.rows_sharded:
+                    bins = np.pad(bins, ((0, 0), (0, self.n_pad - n)))
+                self.scores = jnp.pad(self.scores,
+                                      ((0, 0), (0, self.n_pad - n)))
+            if self.grower is not None:
+                if streamed:
+                    self.bins_dev = self._put_bins_sharded_streamed(train_data)
+                elif self.rows_sharded:
+                    self.bins_dev = self.grower.shard_bins(bins, self.n_pad)
+                else:
+                    self.bins_dev = self.grower.shard_bins(bins)
+                if self.rows_sharded and not self._mh:
+                    # single-host: shard scores so the leaf_id gather-add
+                    # stays on-device
+                    self.scores = jax.device_put(
+                        self.scores, self.grower.row_sharding_2d())
+                elif self._mh_fused:
+                    # multi-host fused: scores become a GLOBAL row-sharded
+                    # array once — every later iteration touches them only
+                    # inside the fused dispatch (process p's file rows live
+                    # at global positions [p*n_pad, (p+1)*n_pad))
+                    self.scores = self.grower.shard_rows(
+                        np.asarray(self.scores), self.n_pad)
             else:
-                self.bins_dev = self.grower.shard_bins(bins)
-            if self.rows_sharded and not self._mh:
-                # single-host: shard scores so the leaf_id gather-add
-                # stays on-device
-                self.scores = jax.device_put(
-                    self.scores, self.grower.row_sharding_2d())
-            elif self._mh_fused:
-                # multi-host fused: scores become a GLOBAL row-sharded
-                # array once — every later iteration touches them only
-                # inside the fused dispatch (process p's file rows live
-                # at global positions [p*n_pad, (p+1)*n_pad))
-                self.scores = self.grower.shard_rows(
-                    np.asarray(self.scores), self.n_pad)
-        else:
-            self.bins_dev = (self._put_bins_streamed(train_data)
-                             if streamed else jnp.asarray(bins))
+                self.bins_dev = (self._put_bins_streamed(train_data)
+                                 if streamed else jnp.asarray(bins))
+            uploaded["bytes"] = (int(self.bins_dev.nbytes)
+                                 + int(self.scores.nbytes))
         if objective is not None and self.n_pad != n:
             objective.pad_to(self.n_pad)
 
@@ -2313,6 +2382,9 @@ class GBDT:
             flush_span.set_metadata(**stats)
             with TraceAnnotation(spans.FLUSH_UNPACK):
                 self._unpack_pending()
+        if spans.stamp(spans.FIRST_TREE):
+            # once a process: what the job's start cost (README.md)
+            log.info(compile_cache.startup_line())
         return self._stopped
 
     def _sampling_counters(self) -> dict:
@@ -3276,9 +3348,9 @@ class DART(GBDT):
     continued training keep the host-tree path."""
     name = "dart"
 
-    def __init__(self, config: Config, train_data, objective,
-                 training_metrics=()):
-        super().__init__(config, train_data, objective, training_metrics)
+    def _build(self, config: Config, train_data, objective,
+               training_metrics) -> None:
+        super()._build(config, train_data, objective, training_metrics)
         self.drop_rate = config.drop_rate
         self.drop_rng = Mt19937Random(config.drop_seed)
         self.drop_index: List[int] = []

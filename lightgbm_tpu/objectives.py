@@ -61,9 +61,19 @@ class Objective:
     _counters: dict = {}
 
     def init(self, metadata: Metadata, num_data: int) -> None:
+        """Build the label-derived state, inside its start-up span."""
         self.metadata = metadata
         self.num_data = num_data
         self.n_pad = num_data
+        stats = {"rows": num_data}
+        if metadata.query_boundaries is not None:
+            stats["queries"] = len(metadata.query_boundaries) - 1
+        with spans.startup(spans.STARTUP_OBJECTIVE, **stats):
+            self._init_state(metadata, num_data)
+
+    def _init_state(self, metadata: Metadata, num_data: int) -> None:
+        """What a subclass builds from the labels (host arrays, their
+        upload, its own jits)."""
 
     def pad_to(self, n_pad: int) -> None:
         """Extend label-derived device arrays to a padded row count so
@@ -181,8 +191,7 @@ class RegressionL2(Objective):
     def __init__(self, config: Config):
         pass
 
-    def init(self, metadata: Metadata, num_data: int) -> None:
-        super().init(metadata, num_data)
+    def _init_state(self, metadata: Metadata, num_data: int) -> None:
         self.label = jnp.asarray(metadata.label, dtype=jnp.float32)
         self.weights = (None if metadata.weights is None
                         else jnp.asarray(metadata.weights, dtype=jnp.float32))
@@ -229,8 +238,7 @@ class BinaryLogloss(Objective):
             log.fatal("Sigmoid parameter %f should be greater than zero"
                       % self.sigmoid)
 
-    def init(self, metadata: Metadata, num_data: int) -> None:
-        super().init(metadata, num_data)
+    def _init_state(self, metadata: Metadata, num_data: int) -> None:
         labels01 = metadata.label.astype(np.int32)
         cnt_pos = int((labels01 == 1).sum())
         cnt_neg = num_data - cnt_pos
@@ -299,8 +307,7 @@ class MulticlassSoftmax(Objective):
     def __init__(self, config: Config):
         self.num_class = config.num_class
 
-    def init(self, metadata: Metadata, num_data: int) -> None:
-        super().init(metadata, num_data)
+    def _init_state(self, metadata: Metadata, num_data: int) -> None:
         li = metadata.label.astype(np.int32)
         if li.min() < 0 or li.max() >= self.num_class:
             log.fatal("Label must be in [0, %d)" % self.num_class)
@@ -401,8 +408,7 @@ class LambdarankNDCG(Objective):
             np.float32(2.0) / (np.float32(1.0)
                                + np.exp(np.float32(2.0) * ts * self.sigmoid)))
 
-    def init(self, metadata: Metadata, num_data: int) -> None:
-        super().init(metadata, num_data)
+    def _init_state(self, metadata: Metadata, num_data: int) -> None:
         if metadata.query_boundaries is None:
             log.fatal("Lambdarank tasks require query information")
         self.qb = metadata.query_boundaries

@@ -1,8 +1,9 @@
 """The names a training job shows under in a JAX profiler trace
 (lightgbm_tpu/utils/spans.py): device scopes in every fused step, host
 spans with their counts around the segment loop, named Pallas kernels,
-and the benchmark's copy of the lists.  All on the CPU: what the scopes
-read on the chip is benchmark/phase_table.py's business.
+the benchmark's copy of the lists, the start-up names among them
+(tests/test_startup_spans.py has what those record).  All on the CPU: what
+the scopes read on the chip is benchmark/phase_table.py's business.
 """
 
 import ast
@@ -166,8 +167,9 @@ def test_resort_helper_lowers_under_its_scope(path, scope, monkeypatch):
 def test_no_name_outside_the_registry():
     """Every `lgbm.*` name in the package is in utils/spans.py, and no
     site passes a string literal where a constant belongs."""
-    known = set(spans.DEVICE_SCOPES) | set(spans.HOST_SPANS)
-    assert len(known) == len(spans.DEVICE_SCOPES) + len(spans.HOST_SPANS)
+    kinds = (spans.DEVICE_SCOPES, spans.HOST_SPANS, spans.STARTUP_SPANS)
+    known = set().union(*kinds)
+    assert len(known) == sum(len(k) for k in kinds)
     for path in glob.glob(os.path.join(PACKAGE, "**", "*.py"),
                           recursive=True):
         with open(path) as fh:
@@ -176,7 +178,8 @@ def test_no_name_outside_the_registry():
         assert not strangers, (path, strangers)
         if not path.endswith(os.path.join("utils", "spans.py")):
             literal = re.findall(
-                r"(?:named_scope|TraceAnnotation)\(\s*[\"']", src)
+                r"(?:named_scope|TraceAnnotation|startup|stamp)"
+                r"\(\s*[\"']", src)
             assert not literal, (path, literal)
 
 
@@ -357,3 +360,20 @@ def test_benchmark_copy_is_equal(key, ours):
         grouped = {s for g in bagged["host_groups"].values()
                    for s in g["spans"]}
         assert grouped <= set(ours) and spans.BAG_DRAW in grouped
+
+
+def test_benchmark_copy_of_the_start_up_names_is_equal():
+    """`scopes_startup.json` (what the start-up readers added) holds the
+    third kind, the stamps and the compile ledger's field names as the
+    program has them; each group of its readers names what exists."""
+    from lightgbm_tpu.utils import compile_cache
+    names = _benchmark_names("scopes_startup.json")
+    assert tuple(names["startup_spans"]) == spans.STARTUP_SPANS
+    assert tuple(names["stamps"]) == spans.STAMPS
+    assert tuple(names["ledger_fields"]) == compile_cache.LEDGER_FIELDS
+    assert names["enqueue_context"] == spans.ENQUEUE
+    grouped = [s for g in names["span_groups"].values() for s in g]
+    assert sorted(grouped) == sorted(set(spans.STARTUP_SPANS)
+                                     - {spans.STARTUP_DATASET})
+    for group in names["ledger_groups"].values():
+        assert set(group["fields"]) <= set(compile_cache.LEDGER_FIELDS)
