@@ -214,9 +214,9 @@ class _enqueue:
     """The span around one call of a jitted training executable:
     `kind` names it (spans.ENQUEUE_KINDS), `k` is the boosting
     iterations it covers, `shards` the devices the one program runs on;
-    a dispatch that re-sorts the rows adds `carried` and `taken`
-    (_resort_counts).  The call returns when the work is enqueued, not
-    when the device is done (unless it compiles first).
+    a dispatch that re-sorts the rows adds `carried`, `taken` and
+    `word_rows` (_resort_counts).  The call returns when the work is
+    enqueued, not when the device is done (unless it compiles first).
 
     While it is open it is the compile ledger's context
     (utils/compile_cache.py): an executable traced, lowered, compiled or
@@ -331,31 +331,115 @@ def _make_fused_step(grad_fn, grow_kw, lr, dtype, compact_rows=0,
     return jax.jit(body, donate_argnums=(0, 1))
 
 
+# The one gather of a re-sort moves a matrix of uint32 "word rows", and
+# builds and takes it apart a block of columns at a time, so that what a
+# re-sort holds beside the matrix itself stays small (PERF.md section 6,
+# PR 36).  Both numbers follow from the shapes alone, never from a key:
+# a group is at most _STACK_ROWS word rows (the ranking cell's 59 are one
+# group; a 500-tree DART bank of uint8 leaves is two, neither a copy of
+# the whole bank), a block _BLOCK_COLS columns.
+_STACK_ROWS = 64
+_BLOCK_COLS = 1 << 20
+
+
+def _word_rows(a, n: int) -> int:
+    """The rows an array fills in the stacked uint32 matrix that ONE
+    gather moves in a re-sort, 0 if it follows by a gather of its own.
+    Rows on the last axis; then a 32-bit array with one row a position
+    ([N], or [1, N] like the single-class scores) is one row, bitcast;
+    a narrower integer or bool with R rows a position (the bag's [N]
+    bool, the [F, N] uint8/uint16 bins, [K, N] class-wise masks, DART's
+    [T, N] uint8 leaf bank) is ceil(R x itemsize / 4) rows, 4 / itemsize
+    of its rows to a word (a narrow float would change its value when
+    widened).  64-bit state, narrow floats and 32-bit arrays with more
+    than one row a position ([K, N] class-wise scores) are taken."""
+    if a.shape[-1] != n:
+        return 0
+    rows, size = a.size // n, a.dtype.itemsize
+    if size == 4:
+        return int(rows == 1)
+    if size < 4 and (a.dtype == jnp.bool_
+                     or jnp.issubdtype(a.dtype, jnp.integer)):
+        return -(-rows * size // 4)
+    return 0
+
+
 def _moves_as_word(a, n: int) -> bool:
-    """One row a position, rows on the last axis ([N], or [1, N] like
-    the single-class scores), and a 32-bit word (bitcast) or a narrower
-    integer or bool (widened; a narrow float would change its value):
-    such an array follows a re-sort as one row of the stacked word
-    matrix that ONE gather moves.  Anything else ([F, N] bins, [K, N]
-    class-wise scores and masks, DART's [T, N] leaf bank, 64-bit state)
-    follows the permutation by a gather of its own."""
-    return a.shape[-1] == n and a.size == n and (
-        a.dtype.itemsize == 4 or (a.dtype.itemsize < 4 and (
-            a.dtype == jnp.bool_ or jnp.issubdtype(a.dtype, jnp.integer))))
+    """Whether an array joins the stacked matrix (_word_rows): a gather
+    on this chip costs by the INDEX and by the (8, 128) tiles a column
+    of its operand lies in, hardly by the byte (17.7 ns an index for 5
+    word rows, 27.4 for 15, where the bins alone cost 30.6: PERF.md
+    section 6, PR 36), so every array that can be a few uint32 rows
+    rides the one gather."""
+    return _word_rows(a, n) > 0
 
 
 def _resort_counts(bufs, gstate, row_state):
-    """The `carried` / `taken` stats of a re-sorting dispatch's
-    lgbm.enqueue span: how many per-row arrays move together in the one
-    gather of 32-bit words and how many follow by a gather of their own
-    (_resort_rows makes the same choice from the same shapes).  A gather
-    costs per INDEX, hardly per byte (PERF.md section 6, PR 28), so a
-    leaf that silently falls from the first count to the second costs a
-    second and a half at 68M rows."""
+    """The `carried` / `taken` / `word_rows` stats of a re-sorting
+    dispatch's lgbm.enqueue span: how many per-row arrays move together
+    in the one gather of 32-bit words, how many follow by a gather of
+    their own, and the rows of the stacked matrix (_resort_rows makes
+    the same choice from the same shapes): 6, 0 and 15 for binary at
+    F = 39, 5, 0 and 59 for lambdarank at F = 220.  An array that
+    silently falls from the first count to the second costs a second
+    and a half at 68M rows (PERF.md section 6, PRs 28 and 36)."""
     arrays = list(bufs) + list(row_state(gstate)[0])
     n = arrays[0].shape[-1]
-    carried = sum(_moves_as_word(a, n) for a in arrays)
-    return {"carried": carried, "taken": len(arrays) - carried}
+    rows = [_word_rows(a, n) for a in arrays]
+    carried = sum(r > 0 for r in rows)
+    return {"carried": carried, "taken": len(arrays) - carried,
+            "word_rows": sum(rows)}
+
+
+def _word_parts(a, lo, hi):
+    """(shift, first row, end row) of the rows of the narrow [R, n]
+    array `a` that lie in its word rows [lo, hi): word g holds rows g,
+    g + G, g + 2G, ... (G = all its word rows), one a byte (or half
+    word): contiguous row slices, no [G, 4, n] view whose 4 the chip
+    would pad to a tile."""
+    bits = 8 * a.dtype.itemsize
+    total = -(-a.shape[0] * bits // 32)
+    for j in range(32 // bits):
+        r0, r1 = j * total + lo, min(j * total + hi, a.shape[0])
+        if r0 < r1:
+            yield bits * j, r0, r1
+
+
+def _pack_words(a, lo, hi, start, cols):
+    """Word rows [lo, hi) of the [R, n] array `a`, columns [start,
+    start + cols): uint32 [hi - lo, cols]."""
+    if a.dtype.itemsize == 4:
+        return jax.lax.bitcast_convert_type(
+            jax.lax.dynamic_slice(a, (0, start), (1, cols)), jnp.uint32)
+    out = None
+    for shift, r0, r1 in _word_parts(a, lo, hi):
+        part = jax.lax.dynamic_slice(a, (r0, start), (r1 - r0, cols))
+        if part.dtype != jnp.bool_:     # no sign extension
+            part = jax.lax.bitcast_convert_type(
+                part, jnp.uint8 if a.dtype.itemsize == 1 else jnp.uint16)
+        part = part.astype(jnp.uint32) << shift
+        if r1 - r0 < hi - lo:
+            part = jnp.pad(part, ((0, hi - lo - (r1 - r0)), (0, 0)))
+        out = part if out is None else out | part
+    return out
+
+
+def _unpack_words(words, a, lo, hi):
+    """The inverse of _pack_words: [(first row, rows of `a`'s dtype)]
+    held by the word rows [lo, hi) of `a` given as `words`."""
+    if a.dtype.itemsize == 4:
+        return [(0, jax.lax.bitcast_convert_type(words, a.dtype))]
+    out = []
+    for shift, r0, r1 in _word_parts(a, lo, hi):
+        part = (words[:r1 - r0] >> shift) & ((1 << 8 * a.dtype.itemsize) - 1)
+        if a.dtype == jnp.bool_:
+            part = part != 0
+        else:
+            part = jax.lax.bitcast_convert_type(
+                part.astype(jnp.uint8 if a.dtype.itemsize == 1
+                            else jnp.uint16), a.dtype)
+        out.append((r0, part))
+    return out
 
 
 @contract.traced_pure
@@ -363,18 +447,30 @@ def _resort_rows(keys, bufs, gstate, row_state):
     """Stable re-sort of every per-row buffer (rows on the LAST axis) by
     `keys` (most significant first; ties keep their order).  One stable
     lax.sort of the keys and an iota gives the permutation `rel` (new
-    position j holds old row rel[j]); every array with one row a
-    position (_moves_as_word) is bitcast or widened to a uint32 row of
-    ONE stacked matrix that a single gather moves, so a row's state
-    moves once; the wider arrays follow `rel` by a gather each.  Equal
-    to argsort(stable=True) and a take per array, to the bit, at a fifth
-    of their cost on the chip.  (The same arrays as payload operands of
-    the sort itself run another 0.8 s a re-sort faster at 68M rows and
-    compile a minute longer on a cold start: PERF.md section 6, PR 28.)
+    position j holds old row rel[j]); every array that can be uint32
+    word rows (_word_rows: the 32-bit arrays with one row a position,
+    the narrow integers and bools of any height, the bin matrix among
+    them, four uint8 rows a word) is packed into ONE stacked matrix
+    that a single gather moves, so a row's state moves once; what is
+    left (64-bit state, narrow floats, [K, N] class-wise scores) follows
+    `rel` by a gather each.  Equal to argsort(stable=True) and a take
+    per array, to the bit.  The bin matrix keeps its [F, N] narrow
+    shape between dispatches: the packed words live inside the step.
+    At 68.3M rows and F = 39 the one gather of 15 word rows takes
+    1.87 s on the chip where the bins' own gather took 2.09 s and the
+    five words' 1.21 (PERF.md section 6, PR 36).  (The same arrays as
+    payload operands of the sort itself ran another 0.8 s a re-sort
+    faster and compile a minute longer on a cold start: PR 28.)
+
+    The stack is filled, and the gathered stack taken apart into the
+    outputs, _BLOCK_COLS columns at a time, in place: beside the stack
+    itself (15 words a row at F = 39) the step holds a block's worth of
+    temporaries, not a second and third copy of the bin matrix (PERF.md
+    section 6, PR 36).
 
     Keys shorter than the buffers (bag compaction: the static in-bag
-    window [:m]) sort the window only and keep the out-of-bag tail as a
-    contiguous copy — the tail-stays-in-place invariant that
+    window [:m]) sort the window only and leave the out-of-bag tail
+    where it is — the tail-stays-in-place invariant that
     _bag_arrange_body and grow_tree_bagged rely on (tail rows never
     enter histograms, so their clustering is irrelevant and moving them
     would be pure waste).
@@ -391,28 +487,60 @@ def _resort_rows(keys, bufs, gstate, row_state):
     rel = jax.lax.sort(tuple(keys) + (jnp.arange(m, dtype=jnp.int32),),
                        num_keys=len(keys), is_stable=True)[len(keys)]
 
-    def word(a):
-        a = a.reshape(n)[:m]
-        if a.dtype.itemsize == 4:
-            return jax.lax.bitcast_convert_type(a, jnp.uint32)
-        return a.astype(jnp.uint32)
-
-    def unword(w, a):
-        if a.dtype.itemsize == 4:
-            return jax.lax.bitcast_convert_type(w, a.dtype)
-        return w.astype(a.dtype)
-
-    carried = [i for i, a in enumerate(arrays) if _moves_as_word(a, n)]
-    # the row order is among them at every site, so the stack is never empty
-    words = jnp.take(jnp.stack([word(arrays[i]) for i in carried]), rel,
-                     axis=1)
-    together = {i: unword(w, arrays[i]) for i, w in zip(carried, words)}
-    moved = []
+    # (array, its word rows [lo, hi)) in groups of at most _STACK_ROWS
+    groups, room = [[]], _STACK_ROWS
     for i, a in enumerate(arrays):
-        w = (together[i].reshape(a.shape[:-1] + (m,)) if i in together
-             else jnp.take(a[..., :m], rel, axis=-1))
-        moved.append(w if m == n
-                     else jnp.concatenate([w, a[..., m:]], axis=-1))
+        lo, total = 0, _word_rows(a, n)
+        while lo < total:
+            if not room:
+                groups.append([])
+                room = _STACK_ROWS
+            hi = min(total, lo + room)
+            groups[-1].append((i, lo, hi))
+            room -= hi - lo
+            lo = hi
+    moved = [a.reshape(-1, n) if _moves_as_word(a, n)
+             else jnp.take(a[..., :m], rel, axis=-1) if m == n
+             else jnp.concatenate([jnp.take(a[..., :m], rel, axis=-1),
+                                   a[..., m:]], axis=-1)
+             for a in arrays]
+    cols = min(m, _BLOCK_COLS)
+    blocks = -(-m // cols)
+
+    def start_of(b):        # the last block overlaps the one before it
+        return jnp.minimum(b * cols, m - cols)
+
+    # the row order is among the arrays at every site: never no group
+    for group in groups:
+        rows = sum(hi - lo for _, lo, hi in group)
+
+        def fill(b, stack, group=group):
+            start = start_of(b)
+            return jax.lax.dynamic_update_slice(stack, jnp.concatenate(
+                [_pack_words(moved[i], lo, hi, start, cols)
+                 for i, lo, hi in group]), (0, start))
+
+        stack = jax.lax.fori_loop(0, blocks, fill,
+                                  jnp.zeros((rows, m), jnp.uint32))
+
+        def spread(b, outs, group=group, stack=stack):
+            start = start_of(b)
+            words = jnp.take(stack, jax.lax.dynamic_slice(
+                rel, (start,), (cols,)), axis=1)
+            at = 0
+            for i, lo, hi in group:
+                for row, part in _unpack_words(words[at:at + hi - lo],
+                                               moved[i], lo, hi):
+                    outs[i] = jax.lax.dynamic_update_slice(
+                        outs[i], part, (row, start))
+                at += hi - lo
+            return outs
+
+        for i, out in jax.lax.fori_loop(
+                0, blocks, spread,
+                {i: moved[i] for i, _, _ in group}).items():
+            moved[i] = out
+    moved = [w.reshape(a.shape) for w, a in zip(moved, arrays)]
     if m < n:
         rel = jnp.concatenate([rel, jnp.arange(m, n, dtype=jnp.int32)])
     return moved[:len(bufs)], rebuild(moved[len(bufs):], rel)

@@ -67,7 +67,8 @@ SEGMENT = "lgbm.segment"              # iter, k: one train_segment / iteration
 HOST_INPUTS = "lgbm.host_inputs"      # plan, bagging, masks and their upload
 ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call;
 #                                       a re-sorting one adds carried, taken,
-#                                       the arrangement also window, in_bag
+#                                       word_rows (the stacked matrix's), the
+#                                       arrangement also window, in_bag
 FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
 #                                       grid_rows, partition_blocks,
 #                                       feat_groups, block_matmuls

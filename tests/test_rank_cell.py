@@ -173,9 +173,10 @@ def test_pair_counters_are_the_formula_of_the_lengths(tiny_job):
 def test_a_resort_carries_row_slot_and_the_flush_the_counters(tiny_job,
                                                               tmp_path):
     """A traced period of the tiny job: the re-sorting dispatch moves
-    scores, bag, order and lambdarank's `row_slot` in the one gather of
-    words (`carried` 4) and the bins alone by a gather of their own
-    (`taken` 1); the flush's span carries the objective's counters."""
+    the bins, scores, bag, order and lambdarank's `row_slot` in the one
+    gather of words (`carried` 5, `taken` 0, `word_rows` 4 + a word for
+    every four features); the flush's span carries the objective's
+    counters."""
     from harness import scopes
     cfg, rows = tiny_job
     booster = train_ranked.build_booster(cfg, rows, on_tpu=False)
@@ -192,7 +193,10 @@ def test_a_resort_carries_row_slot_and_the_flush_the_counters(tiny_job,
     resorts = [s.stats for s in host if s.name == spans.ENQUEUE
                and s.stats["kind"] == "resort"]
     assert len(resorts) == 2            # trees 0 and 4
-    assert all((s["carried"], s["taken"]) == (4, 1) for s in resorts)
+    # (the harness's reader leaves a stat of value 0 out)
+    assert all((s["carried"], s.get("taken", 0)) == (5, 0) for s in resorts)
+    features = booster.bins_dev.shape[0]
+    assert all(s["word_rows"] == 4 + -(-features // 4) for s in resorts)
     flushes = [s.stats for s in host if s.name == spans.FLUSH]
     want = booster.objective.trace_counters()
     assert flushes and want["pairs_padded"] > want["pairs_real"] > 0

@@ -1,7 +1,8 @@
 """The re-sort moves a row's state once (models/gbdt.py _resort_rows):
-every array with one row a position is a uint32 row of ONE matrix that a
-single gather moves by the stable sort's permutation; the wider ones
-follow it by a gather each.  Held here to the idiom it replaced,
+every array that can be uint32 word rows (32-bit with one row a position;
+narrow integers and bools of any height, the bin matrix among them) is
+packed into ONE matrix that a single gather moves by the stable sort's
+permutation; the rest follow it by a gather each.  Held here to the idiom it replaced,
 `argsort(stable=True)` and a `take` per array, equal to the bit: the
 helper alone over keys, payload dtypes, the window form and the
 objective hooks, and the whole training step, serial and on four virtual
@@ -65,8 +66,24 @@ PAYLOADS = {
     "f16": lambda rng: rng.randn(N).astype(np.float16),
     "classwise": lambda rng: rng.randn(3, N).astype(np.float32),
     "bins": lambda rng: rng.randint(0, 255, (5, N)).astype(np.uint8),
+    # the arrays that join the stacked matrix since PR 36: narrow
+    # integers and bools with more than one row a position
+    **{"bins_f%d" % f: (lambda rng, f=f: rng.randint(
+        0, 256, (f, N)).astype(np.uint8)) for f in (1, 3, 4, 39, 220)},
+    "bins_u16": lambda rng: rng.randint(0, 65536, (7, N)).astype(np.uint16),
+    "int16_rows": lambda rng: rng.randint(-32768, 32768,
+                                          (3, N)).astype(np.int16),
+    "masks": lambda rng: rng.rand(3, N) > 0.5,
+    # DART's leaf bank, high enough to need a second group of word rows
+    "bank_u8": lambda rng: rng.randint(0, 256, (300, N)).astype(np.uint8),
+    "bank_i32": lambda rng: rng.randint(0, 1000, (6, N)).astype(np.int32),
 }
-WORDS = {"f32", "int32", "bool", "scores_row", "int8"}
+# the rows each payload fills in the stacked matrix; 0 = taken
+WORD_ROWS = {"f32": 1, "int32": 1, "bool": 1, "scores_row": 1, "int8": 1,
+             "f16": 0, "classwise": 0, "bins": 2, "bins_f1": 1,
+             "bins_f3": 1, "bins_f4": 1, "bins_f39": 10, "bins_f220": 55,
+             "bins_u16": 4, "int16_rows": 2, "masks": 1, "bank_u8": 75,
+             "bank_i32": 0}
 
 
 def _same(got, want):
@@ -98,8 +115,28 @@ def test_helper_equals_argsort_and_takes(key, payload, window):
         _same([moved[..., window:], order[window:]],
               [bufs[0][..., window:], bufs[1][window:]])
     counts = gbdt._resort_counts(bufs, gstate, _plain)
-    assert counts == {"carried": 2 + (payload in WORDS),
-                      "taken": payload not in WORDS}
+    rows = WORD_ROWS[payload]
+    assert counts == {"carried": 2 + (rows > 0), "taken": int(rows == 0),
+                      "word_rows": 2 + rows}
+
+
+@pytest.mark.parametrize("window", [N, 3000])
+@pytest.mark.parametrize("payload", ["bins_f39", "bins_u16", "bool",
+                                     "scores_row", "masks", "bank_u8",
+                                     "int16_rows", "classwise"])
+def test_blocks_of_columns_cover_the_window(payload, window, monkeypatch):
+    """The stack is packed and unpacked a block of columns at a time,
+    the last block pulled back to end at the window's end: five blocks
+    of 1,024 columns over 5,000 rows (the last overlaps the fourth),
+    three over a window of 3,000."""
+    monkeypatch.setattr(gbdt, "_BLOCK_COLS", 1024)
+    rng = np.random.RandomState(window + len(payload))
+    keys = _keys("leaves63", rng, window)
+    bufs = [jnp.asarray(PAYLOADS[payload](rng)),
+            jnp.arange(N, dtype=jnp.int32)]
+    gstate = (jnp.asarray(rng.randn(N).astype(np.float32)), None)
+    _same(jax.jit(lambda k, b, g: gbdt._resort_rows(k, b, g, _plain))(
+        keys, bufs, gstate), _oracle_resort_rows(keys, bufs, gstate, _plain))
 
 
 def test_nan_and_negative_zero_payloads_are_moved_not_compared():
@@ -130,7 +167,7 @@ def test_lambdarank_state_remaps_its_positions(window):
     _same(moved[0][new[0]], scores[0][di])
     _same(new[1:5] + new[6:], gstate[1:5] + gstate[6:])
     assert gbdt._resort_counts([scores], gstate, row_state) == {
-        "carried": 2, "taken": 0}
+        "carried": 2, "taken": 0, "word_rows": 2}
 
 
 # -- the whole step --------------------------------------------------------

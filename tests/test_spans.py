@@ -52,7 +52,10 @@ PATHS = {
 }
 
 
-def _data(classes, n=8192, f=6, seed=3):
+FEATURES = 6
+
+
+def _data(classes, n=8192, f=FEATURES, seed=3):
     rng = np.random.RandomState(seed)
     x = rng.randn(n, f).astype(np.float32)
     z = x[:, 0] + 0.5 * x[:, 1] * x[:, 2] + 0.3 * rng.randn(n)
@@ -225,14 +228,16 @@ def test_spans_count_what_was_trained(traced_and_plain):
     assert {s["kind"] for s in by_name[spans.ENQUEUE]} \
         <= set(spans.ENQUEUE_KINDS)
     # a re-sorting dispatch says what moved in the one gather of words
-    # (scores, bag, row order, the binary objective's two arrays) and
-    # what followed the permutation by a gather of its own (the bins);
-    # no other dispatch has the stats
+    # (the bins, scores, bag, row order, the binary objective's two
+    # arrays), what followed the permutation by a gather of its own
+    # (nothing) and the rows of the stacked matrix (five words and the
+    # bins, four feature rows a word); no other dispatch has the stats
     for s in by_name[spans.ENQUEUE]:
         if s["kind"] == "resort":
-            assert (s["carried"], s["taken"]) == (5, 1), s
+            assert (s["carried"], s["taken"]) == (6, 0), s
+            assert s["word_rows"] == 5 + -(-FEATURES // 4), s
         else:
-            assert "carried" not in s and "taken" not in s, s
+            assert not {"carried", "taken", "word_rows"} & set(s), s
     assert sum(s["trees"] for s in by_name[spans.FLUSH]) == ROUNDS
     assert all(s["bytes"] > 0 for s in by_name[spans.FLUSH])
     assert len(by_name[spans.FLUSH_PULL]) == len(by_name[spans.FLUSH])
@@ -262,8 +267,10 @@ def test_bag_arrangement_says_what_moved_together(sampled_spans):
     arranges = [s for name, s in sampled_spans
                 if name == spans.ENQUEUE and s["kind"] == "arrange"]
     assert len(arranges) == 3       # bagging_freq=2: before rounds 0, 2, 4
-    # the [1, N] scores row, the mask, the order, sign and label_weight
-    assert all((s["carried"], s["taken"]) == (5, 1) for s in arranges)
+    # the bins, the [1, N] scores row, the mask, the order, sign and
+    # label_weight, all in the one gather
+    assert all((s["carried"], s["taken"]) == (6, 0) for s in arranges)
+    assert all(s["word_rows"] == 5 + -(-FEATURES // 4) for s in arranges)
     # the static window and the bag it holds: 8,192 rows, half in the bag
     assert all((s["window"], s["in_bag"]) == (4096, 4096) for s in arranges)
 

@@ -14,6 +14,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from test_bins_in_place import F as STEP_FEATURES
 from test_bins_in_place import N as STEP_ROWS
 from test_bins_in_place import (_steps_of_a_training_job,  # noqa: F401
                                 traces_forgotten)
@@ -217,21 +218,45 @@ def _ops_under(text, scope):
 def test_resort_step_moves_row_state_in_one_gather(monkeypatch,
                                                    traces_forgotten):
     """The re-sort of the default ordered path, lowered for the TPU: ONE
-    sort (the key and the iota) and TWO gathers by its permutation, the
-    bin matrix's and that of the stacked words: the five arrays of the
-    binary objective's step with one row a position (scores, bag, row
-    order, sign, label_weight).  A take of one such array costs 1.7 s at
-    68M rows where the gather of all five costs 1.0 (PERF.md section 6,
-    PR 28), so an array that falls off the stack must show here."""
+    sort (the key and the iota) and ONE gather by its permutation, that
+    of the stacked words: the five arrays of the binary objective's step
+    with one row a position (scores, bag, row order, sign, label_weight)
+    AND the bin matrix, four feature rows a word.  A take of one such
+    array costs 1.7 s at 68M rows and the bins' own 2.1 s where the
+    gather of all of them costs little more than the five's 1.2 (PERF.md
+    section 6, PRs 28 and 36), so an array that falls off the stack must
+    show here."""
     make, shapes = _steps_of_a_training_job(monkeypatch)[0]
     jax.clear_caches()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = make().trace(*shapes).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert _ops_under(text, "lgbm.resort") == [
-        ("gather", STEP_ROWS), ("gather", STEP_ROWS), ("sort", 2)]
+        ("gather", STEP_ROWS), ("sort", 2)]
     stacked = re.findall(r"stablehlo\.gather.*tensor<(\d+)x%dxui32>, "
                          % STEP_ROWS, text)
-    assert stacked == ["5"], stacked
+    assert stacked == [str(5 + -(-STEP_FEATURES // 4))], stacked
     # the reader sees the rest of the step too: the block list's argsort
     assert ("sort", 2) in _ops_under(text, "lgbm.block_list")
+
+
+def test_bag_arrangement_moves_row_state_in_one_gather(monkeypatch,
+                                                       traces_forgotten):
+    """The same under `lgbm.bag_arrange`: the arrangement after a redraw
+    sorts the static window on the bag's bit and moves bins, scores,
+    mask, order and the objective's two arrays in ONE gather of the
+    window's rows."""
+    steps = _steps_of_a_training_job(
+        monkeypatch, bagging_fraction=0.5, bagging_freq=1, bag_compact="on")
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    texts = [make().trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+        for make, shapes in steps]
+    (text,) = [t for t in dict.fromkeys(texts) if "lgbm.bag_arrange" in t]
+    (gather, sort) = _ops_under(text, "lgbm.bag_arrange")
+    assert sort == ("sort", 2)
+    assert gather[0] == "gather" and 0 < gather[1] <= STEP_ROWS, gather
+    stacked = re.findall(r"stablehlo\.gather.*tensor<(\d+)x%dxui32>, "
+                         % gather[1], text)
+    assert stacked == [str(5 + -(-STEP_FEATURES // 4))], stacked
