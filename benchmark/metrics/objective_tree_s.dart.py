@@ -1,0 +1,10 @@
+"""`objective_tree_s` of the DART cell: device seconds a tree spent under
+`lgbm.objective`.
+Grouped in harness/scopes_dart.json; nothing where the trace has
+nothing of it to read (harness/scopes_dart.py)."""
+
+from harness import scopes_dart
+
+
+def read(record: dict):
+    return scopes_dart.tree_seconds(record, "objective_tree_s.dart")

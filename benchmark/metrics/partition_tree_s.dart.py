@@ -1,0 +1,11 @@
+"""`partition_tree_s` of the DART cell: device seconds a tree spent under
+`lgbm.partition`, `tree_update`, `oob_descent`, and under `lgbm.grow`
+in no deeper scope.
+Grouped in harness/scopes_dart.json; nothing where the trace has
+nothing of it to read (harness/scopes_dart.py)."""
+
+from harness import scopes_dart
+
+
+def read(record: dict):
+    return scopes_dart.tree_seconds(record, "partition_tree_s.dart")

@@ -179,7 +179,7 @@ CONSUME_KINDS: Mapping[str, str] = {
     "bank_i": "bank", "bank_f": "bank", "leaf_bank": "bank",
     "vbanks": "bank", "t_row": "bank",
     # DART drop/normalize schedule inputs
-    "drop_idx": "dart", "drop_mask": "dart", "lr": "dart", "kf": "dart",
+    "drop_idx": "dart", "drop_count": "dart", "lr": "dart", "kf": "dart",
 }
 
 #: collective primitives (matched as jax.lax.X / lax.X in the AST)

@@ -16,8 +16,9 @@ from __future__ import annotations
 
 __jax_free__ = False  # the boosting driver traces jits
 
+import functools
 from collections import OrderedDict
-from typing import List, Optional, Sequence, Tuple
+from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +31,7 @@ from ..io.dataset import Dataset
 from ..metrics import Metric
 from ..objectives import Objective
 from ..ops.grow import grow_tree, grow_tree_bagged
-from ..ops.predict import predict_leaf_binned
+from ..ops.predict import predict_leaf_binned, replay_leaf_binned_inline
 from ..ops.split import SplitParams
 from ..resilience.atomic import read_npz, text_writer, write_npz
 from ..resilience.snapshot import fingerprint_diff, resume_fingerprint
@@ -162,6 +163,8 @@ _SCAN_MULTI_REORDER = (((0, 0), (1, 1), (2, 6), (4, 5), (6, 7), (7, 4),
                         (8, 8)), (3,), (2, 3), 9)
 _SCAN_DART = (((0, 0), (1, 1), (2, 2), (3, 3), (4, 4), (5, 5), (15, 8)),
               (6, 7, 8, 9, 11, 16), (6, 7), 9)
+_SCAN_DART_REORDER = (_SCAN_DART[0] + ((12, 9), (10, 10), (14, 11), (17, 12)),
+                      _SCAN_DART[1], _SCAN_DART[2], 13)
 
 
 @contract.parity_oracle("K=1 returns the body UNCHANGED — the "
@@ -336,10 +339,43 @@ def _make_fused_step(grad_fn, grow_kw, lr, dtype, compact_rows=0,
 # re-sort holds beside the matrix itself stays small (PERF.md section 6,
 # PR 36).  Both numbers follow from the shapes alone, never from a key:
 # a group is at most _STACK_ROWS word rows (the ranking cell's 59 are one
-# group; a 500-tree DART bank of uint8 leaves is two, neither a copy of
-# the whole bank), a block _BLOCK_COLS columns.
+# group), a block _BLOCK_COLS columns.  DART's [T, N] leaf bank is no such
+# array: a stack of 64 word rows is 17.5 GB at 68.3M rows, more than the
+# chip, so the bank rides as _FilledRows, a tile of 8 word rows at a time
+# and only as far as it is filled.
 _STACK_ROWS = 64
 _BLOCK_COLS = 1 << 20
+_TILE_WORDS = 8     # word rows of one (8, 128) uint32 tile: 32 uint8 rows
+
+
+class _FilledRows(NamedTuple):
+    """DART's leaf bank among a re-sort's buffers (the LAST of them):
+    `rows` [R, N] of uint8 (int32 past 256 leaves) whose rows [0, fill)
+    hold something, `fill` a RUN-TIME count (a traced scalar in a step,
+    an int where the host counts: _resort_counts).  It moves in groups
+    of _bank_group(rows) rows, each one stack of at most _TILE_WORDS word
+    rows through the same `rel`, ceil(fill / group) of them: static
+    shapes, a run-time trip count, in place."""
+    rows: jax.Array
+    fill: Any
+
+
+def _bank_group(rows) -> Tuple[int, int]:
+    """(rows, word rows) of one group of a _FilledRows in a re-sort: one
+    (8, 128) tile of words, 32 uint8 rows (PERF.md section 6, PR 36: a
+    gather costs by the tiles a column lies in, so 8 word rows move for
+    the price of one), or all its rows where it has fewer.  The bank is
+    allocated in whole groups (DART._plan_bank)."""
+    per_word = 4 // rows.dtype.itemsize
+    group = min(rows.shape[0], _TILE_WORDS * per_word)
+    assert rows.shape[0] % group == 0 and group % per_word == 0, rows.shape
+    return group, group // per_word
+
+
+def _split_filled(bufs):
+    """(the plain buffers, the _FilledRows among them)."""
+    return ([b for b in bufs if not isinstance(b, _FilledRows)],
+            [b for b in bufs if isinstance(b, _FilledRows)])
 
 
 def _word_rows(a, n: int) -> int:
@@ -348,9 +384,9 @@ def _word_rows(a, n: int) -> int:
     Rows on the last axis; then a 32-bit array with one row a position
     ([N], or [1, N] like the single-class scores) is one row, bitcast;
     a narrower integer or bool with R rows a position (the bag's [N]
-    bool, the [F, N] uint8/uint16 bins, [K, N] class-wise masks, DART's
-    [T, N] uint8 leaf bank) is ceil(R x itemsize / 4) rows, 4 / itemsize
-    of its rows to a word (a narrow float would change its value when
+    bool, the [F, N] uint8/uint16 bins, [K, N] class-wise masks) is
+    ceil(R x itemsize / 4) rows, 4 / itemsize of its rows to a word (a
+    narrow float would change its value when
     widened).  64-bit state, narrow floats and 32-bit arrays with more
     than one row a position ([K, N] class-wise scores) are taken."""
     if a.shape[-1] != n:
@@ -382,12 +418,18 @@ def _resort_counts(bufs, gstate, row_state):
     the same choice from the same shapes): 6, 0 and 15 for binary at
     F = 39, 5, 0 and 59 for lambdarank at F = 220.  An array that
     silently falls from the first count to the second costs a second
-    and a half at 68M rows (PERF.md section 6, PRs 28 and 36)."""
-    arrays = list(bufs) + list(row_state(gstate)[0])
+    and a half at 68M rows (PERF.md section 6, PRs 28 and 36).  DART's
+    leaf bank (_FilledRows, `fill` an int here) is one more carried
+    array, and adds the word rows of its filled groups."""
+    bufs, banks = _split_filled(bufs)
+    arrays = bufs + list(row_state(gstate)[0])
     n = arrays[0].shape[-1]
     rows = [_word_rows(a, n) for a in arrays]
-    carried = sum(r > 0 for r in rows)
-    return {"carried": carried, "taken": len(arrays) - carried,
+    for bank in banks:
+        group, words = _bank_group(bank.rows)
+        rows.append(-(-int(bank.fill) // group) * words)
+    carried = sum(r > 0 for r in rows) + sum(int(b.fill) == 0 for b in banks)
+    return {"carried": carried, "taken": len(rows) - carried,
             "word_rows": sum(rows)}
 
 
@@ -478,10 +520,13 @@ def _resort_rows(keys, bufs, gstate, row_state):
     `row_state` is the objective's make_row_state_fn: which leaves of
     `gstate` are per-row (they join `bufs`), and how the permuted state
     is rebuilt from them and the full-length `rel` (lambdarank remaps
-    its doc_idx row positions through the inverse).  Returns (permuted
-    bufs, permuted gstate)."""
+    its doc_idx row positions through the inverse).  A _FilledRows (DART's
+    leaf bank), the last of `bufs` where there is one, follows the same
+    `rel` in its own groups (_carry_filled) and comes back as its array.
+    Returns (permuted bufs, permuted gstate)."""
     rows, rebuild = row_state(gstate)
-    arrays = list(bufs) + list(rows)
+    bufs, banks = _split_filled(bufs)
+    arrays = bufs + list(rows)
     n = arrays[0].shape[-1]
     m = keys[0].shape[0]
     rel = jax.lax.sort(tuple(keys) + (jnp.arange(m, dtype=jnp.int32),),
@@ -541,9 +586,75 @@ def _resort_rows(keys, bufs, gstate, row_state):
                 {i: moved[i] for i, _, _ in group}).items():
             moved[i] = out
     moved = [w.reshape(a.shape) for w, a in zip(moved, arrays)]
+    carried = [_carry_filled(b, rel, cols, blocks, start_of) for b in banks]
     if m < n:
         rel = jnp.concatenate([rel, jnp.arange(m, n, dtype=jnp.int32)])
-    return moved[:len(bufs)], rebuild(moved[len(bufs):], rel)
+    return moved[:len(bufs)] + carried, rebuild(moved[len(bufs):], rel)
+
+
+def _carry_filled(bank: _FilledRows, rel, cols, blocks, start_of):
+    """The filled groups of DART's leaf bank through a re-sort's `rel`
+    (the window's [m]; columns past it stay): per group the stack of its
+    word rows is filled, gathered and taken apart into the bank IN PLACE,
+    a block of `cols` columns at a time as _resort_rows does for its own
+    stack, so that a re-sort holds one group's stack beside the bank
+    (8 x 4 B a row) and never a copy of it.  The trip count is
+    ceil(fill / group): what a re-sort moves of the bank follows what the
+    job has banked so far, under one executable."""
+    out, fill = bank
+    group, words = _bank_group(out)
+    bits = 8 * out.dtype.itemsize
+    narrow = {8: jnp.uint8, 16: jnp.uint16, 32: jnp.uint32}[bits]
+    m = rel.shape[0]
+
+    def one_group(g, out):
+        def row_of(j, start):   # byte j of the group's words: these rows
+            return (g * group + j * words).astype(start.dtype)
+
+        def fill_block(b, stack):
+            start = start_of(b)
+            word = None
+            for j in range(32 // bits):
+                part = jax.lax.bitcast_convert_type(jax.lax.dynamic_slice(
+                    out, (row_of(j, start), start), (words, cols)), narrow)
+                part = part.astype(jnp.uint32) << (bits * j)
+                word = part if word is None else word | part
+            return jax.lax.dynamic_update_slice(stack, word, (0, start))
+
+        stack = jax.lax.fori_loop(0, blocks, fill_block,
+                                  jnp.zeros((words, m), jnp.uint32))
+
+        def spread(b, out):
+            start = start_of(b)
+            got = jnp.take(stack, jax.lax.dynamic_slice(
+                rel, (start,), (cols,)), axis=1)
+            for j in range(32 // bits):
+                part = (got >> (bits * j)) & ((1 << bits) - 1) \
+                    if bits < 32 else got
+                out = jax.lax.dynamic_update_slice(
+                    out, jax.lax.bitcast_convert_type(
+                        part.astype(narrow), out.dtype),
+                    (row_of(j, start), start))
+            return out
+
+        return jax.lax.fori_loop(0, blocks, spread, out)
+
+    with jax.named_scope(spans.DART_CARRY):
+        return jax.lax.fori_loop(0, -(-fill // group), one_group, out)
+
+
+@contract.traced_pure
+def _resort_by_leaf(leaf_id, bufs, gstate, row_state, compact_rows):
+    """The re-sort that ends a re-sorting step, the plain one's and
+    DART's alike: every per-row buffer (`bufs`: bins first; DART's leaf
+    bank, a _FilledRows, last) and the objective's state, stably sorted
+    by the tree's leaves.  Padded rows ride along via their tracked
+    leaf_id and stay permanently out-of-bag through the permuted bag
+    mask.  Under bag compaction only the static window re-sorts."""
+    n = bufs[0].shape[1]
+    with jax.named_scope(spans.RESORT):
+        m = compact_rows if 0 < compact_rows < n else n
+        return _resort_rows((leaf_id[:m],), bufs, gstate, row_state)
 
 
 @contract.traced_pure
@@ -589,16 +700,9 @@ def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype, row_state,
                 new_valid.append(vs.at[0].add(leaf_vals[vleaf]))
         with jax.named_scope(spans.PACK_TREE):
             ints, floats = _pack_tree(dev_tree)
-        n = bins.shape[1]
-        with jax.named_scope(spans.RESORT):
-            # stable sort by this tree's leaves; padded rows ride along
-            # via their tracked leaf_id and stay permanently out-of-bag
-            # through the permuted bag mask
-            m = compact_rows if 0 < compact_rows < n else n
-            (bins_new, scores, bag_new, order_new), gstate_new = \
-                _resort_rows((leaf_id[:m],),
-                             [bins, scores, bag, row_order], gstate,
-                             row_state)
+        (bins_new, scores, bag_new, order_new), gstate_new = \
+            _resort_by_leaf(leaf_id, [bins, scores, bag, row_order],
+                            gstate, row_state, compact_rows)
         return (scores, new_valid, ints, floats, bins_new, bag_new,
                 gstate_new, order_new, stopped)
     return step
@@ -632,11 +736,131 @@ def _dart_layout(L):
     return SF0, TB0, LC0, RC0, RC1, LV0, LV1
 
 
+def _bank_row(bank, j):
+    """Row j of a leaf bank as int32 ids in an [N] array of its own.  A
+    look-up of a small table by ids that the same fusion slices out of
+    the tiled [T, N] bank is emitted as one pass over the rows a LEAF
+    (0.22 s a dropped tree at 68.3M rows and 63 leaves, where the row
+    taken out first costs 3.5 ms: PERF.md section 6, PR 37)."""
+    return jax.lax.optimization_barrier(bank[j]).astype(jnp.int32)
+
+
+def _dart_replayed_ids(bank_i, j, bins, L):
+    """Leaf ids of tree j over the rows as they lie, from its splits in
+    the tree bank: what the leaf bank would hold had it room for j."""
+    SF0, TB0, LC0, RC0, RC1, LV0, LV1 = _dart_layout(L)
+    with jax.named_scope(spans.DART_REPLAY):
+        row = bank_i[j]     # + the dummy slot the replay skips
+        return replay_leaf_binned_inline(
+            jnp.pad(row[SF0:TB0], (0, 1)), jnp.pad(row[TB0:LC0], (0, 1)),
+            jnp.pad(row[LC0:RC0], (0, 1), constant_values=-1), row[0], bins)
+
+
 @contract.traced_pure
-@contract.fused_body(extras=("bank", "dart"),
+def _dart_drop_trees(scores, bank_f, bank_i, leaf_bank, bins, drop_idx,
+                     drops, L, replay_slots):
+    """DART's drop phase (dart.hpp:86-110) over the device bank: for each
+    of the first `drops` trees of `drop_idx`, shrinkage(-1) persisted in
+    bank_f and the train-score add.  A tree inside the leaf bank (all
+    but its last row) adds by its cached ids; one past it by a replay of
+    its splits, whose ids are kept for the normalise in one of
+    `replay_slots` [N] buffers while there is one left.  ONE program
+    whether the bank holds every tree or not: the same adds in the same
+    order, so a bounded bank changes no bit of a job
+    (tests/test_dart_bank.py).  -> (scores, bank_f, kept)."""
+    SF0, TB0, LC0, RC0, RC1, LV0, LV1 = _dart_layout(L)
+    bank_cap = leaf_bank.shape[0] - 1
+
+    def drop_body(i, carry):
+        sc, bf, kept, outside = carry
+        j = drop_idx[i]
+        v1 = -bf[j, LV0:LV1]
+        add = v1.astype(jnp.float32)
+
+        def banked(sc, kept, outside):
+            return (sc.at[0].add(add[_bank_row(leaf_bank, j)]),
+                    kept, outside)
+
+        def replayed(sc, kept, outside):
+            leaf = _dart_replayed_ids(bank_i, j, bins, L)
+            kept = tuple(jnp.where(outside == s,
+                                   leaf.astype(leaf_bank.dtype), k)
+                         for s, k in enumerate(kept))
+            return sc.at[0].add(add[leaf]), kept, outside + 1
+
+        sc, kept, outside = jax.lax.cond(j < bank_cap, banked, replayed,
+                                         sc, kept, outside)
+        return sc, bf.at[j, LV0:LV1].set(v1), kept, outside
+
+    with jax.named_scope(spans.DART_DROP):
+        kept = tuple(jnp.zeros(bins.shape[1], leaf_bank.dtype)
+                     for _ in range(replay_slots))
+        scores, bank_f, kept, _ = jax.lax.fori_loop(
+            0, drops, drop_body, (scores, bank_f, kept, jnp.int32(0)))
+    return scores, bank_f, kept
+
+
+@contract.traced_pure
+def _dart_normalize_trees(scores, vss, bank_f, bank_i, leaf_bank, vbanks,
+                          bins, drop_idx, drops, lr, kf, kept, L):
+    """DART's normalise (dart.hpp:114-129) over the device bank: per
+    dropped tree shrinkage(rate) + VALID add, then shrinkage(-k) + TRAIN
+    add, both persisted in bank_f.  `kept`: the ids the drop phase
+    replayed, in its order; a replayed tree past them is replayed again.
+    -> (scores, vss, bank_f)."""
+    SF0, TB0, LC0, RC0, RC1, LV0, LV1 = _dart_layout(L)
+    bank_cap = leaf_bank.shape[0] - 1
+
+    def norm_body(i, carry):
+        sc, vss, bf, outside = carry
+        j = drop_idx[i]
+        v2 = bf[j, LV0:LV1] * lr
+        vss = tuple(
+            vs.at[0].add(v2.astype(jnp.float32)[_bank_row(vb, j)])
+            for vs, vb in zip(vss, vbanks))
+        v3 = v2 * (-kf)
+        add = v3.astype(jnp.float32)
+
+        def banked(sc, outside):
+            return (sc.at[0].add(add[_bank_row(leaf_bank, j)]),
+                    outside)
+
+        def kept_ids(sc):
+            ids = kept[0]
+            for s in range(1, len(kept)):
+                ids = jnp.where(outside == s, kept[s], ids)
+            return sc.at[0].add(add[ids.astype(jnp.int32)])
+
+        def again(sc):
+            return sc.at[0].add(add[_dart_replayed_ids(bank_i, j, bins, L)])
+
+        def replayed(sc, outside):
+            sc = (jax.lax.cond(outside < len(kept), kept_ids, again, sc)
+                  if kept else again(sc))
+            return sc, outside + 1
+
+        sc, outside = jax.lax.cond(j < bank_cap, banked, replayed, sc,
+                                   outside)
+        return sc, vss, bf.at[j, LV0:LV1].set(v3), outside
+
+    with jax.named_scope(spans.DART_NORMALIZE):
+        scores, vss, bank_f, _ = jax.lax.fori_loop(
+            0, drops, norm_body, (scores, tuple(vss), bank_f, jnp.int32(0)))
+    return scores, vss, bank_f
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_bank_row(bank, t, ids):
+    """One row of DART's leaf bank, in place (a restore's rebuild)."""
+    return bank.at[t].set(ids.astype(bank.dtype))
+
+
+@contract.traced_pure
+@contract.fused_body(extras=("bank", "dart", "order"),
                      collectives=("all_gather", "axis_index", "psum",
                                   "psum_scatter"))
 def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
+                          replay_slots=0, row_state=None,
                           compact_rows=0, k_iters=1):
     """Fused DART iteration over a DEVICE-RESIDENT tree bank (VERDICT r3
     weak #5: DART previously paid ~6 host dispatches + a blocking tree
@@ -661,43 +885,45 @@ def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
     host/reference tree->Shrinkage sequence, so long drop histories
     cannot drift the saved model.
 
-    The drop list pads to a FIXED cap with lax.cond-skipped slots, so
+    The drop list has the bank's length and a RUN-TIME count
+    (`drop_count`: the trip count of the drop and normalise loops), so
     one executable serves every drop count (a shape-per-count design
-    measured 3 mid-loop recompiles per bench run).  The device `stopped`
-    flag gates every phase, so deferred host flushes truncate at the
-    exact reference stop point.
+    measured 3 mid-loop recompiles per bench run; a cap of 8 with
+    power-of-two buckets past it compiled again wherever a late
+    iteration dropped 9).  The device `stopped` flag zeroes the count,
+    so deferred host flushes truncate at the exact reference stop point.
 
     Leaf assignments are CACHED per tree (leaf_bank / per-valid-set
     vbanks) at training time: tree structure never changes after
     training, so the drop/normalize adds gather a [L] value table by the
     cached ids instead of re-descending every row per dropped tree —
     the descent's per-level [N] gathers measured ~6x the gather-only
-    cost on TPU (r3 memory: gathers dominate; reformulate)."""
+    cost on TPU (r3 memory: gathers dominate; reformulate).  The leaf
+    bank holds the first rows - 1 trees only (its last row is the one
+    dead and unbanked steps write to; DART._plan_bank sizes it from the
+    device's memory): a dropped tree past it gets its ids by a replay of
+    its splits, which bank_i holds for every tree (replay_leaf_binned:
+    a pass over a bin row a split), kept for the normalise in one of
+    `replay_slots` [N] buffers where there is one left, else replayed
+    again there.
+
+    `row_state` (the objective's make_row_state_fn) makes it the
+    RE-SORTING step: after the normalise every per-row buffer, the
+    leaf bank's filled groups among them, is stably sorted by the new
+    tree's leaves (_resort_by_leaf, the plain re-sort step's tail)."""
     L = max_leaves
     SF0, TB0, LC0, RC0, RC1, LV0, LV1 = _dart_layout(L)
+    reorder = row_state is not None
 
     def step(scores, valid_scores, bank_i, bank_f, leaf_bank, vbanks,
-             drop_idx, drop_mask, lr, kf, bag_mask, fmask, bins,
-             valid_bins, gstate, stopped, t_row):
+             drop_idx, drop_count, lr, kf, bag_mask, fmask, bins,
+             valid_bins, gstate, stopped, t_row, *row_order):
         live = jnp.logical_not(stopped)
-
-        def drop_body(carry, xs):
-            sc, bf = carry
-            j, m = xs
-
-            def do(sc, bf):
-                v1 = -bf[j, LV0:LV1]
-                leaf = leaf_bank[j].astype(jnp.int32)
-                sc = sc.at[0].add(v1.astype(jnp.float32)[leaf])
-                return sc, bf.at[j, LV0:LV1].set(v1)
-
-            sc, bf = jax.lax.cond(m & live, do, lambda sc, bf: (sc, bf),
-                                  sc, bf)
-            return (sc, bf), None
-
-        with jax.named_scope(spans.DART_BANK):
-            (scores, bank_f), _ = jax.lax.scan(
-                drop_body, (scores, bank_f), (drop_idx, drop_mask))
+        drops = jnp.where(live, drop_count, 0)
+        bank_cap = leaf_bank.shape[0] - 1
+        scores, bank_f, kept = _dart_drop_trees(
+            scores, bank_f, bank_i, leaf_bank, bins, drop_idx, drops, L,
+            replay_slots)
 
         bag = _unpack_bag(bag_mask, bins.shape[1])
         with jax.named_scope(spans.OBJECTIVE):
@@ -736,41 +962,34 @@ def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
                 dev_tree.leaf_value[:-1] * lr)
             bank_i = bank_i.at[wrow].set(ints)
             bank_f = bank_f.at[wrow].set(bank_row_f)
-            leaf_bank = leaf_bank.at[wrow].set(
-                leaf_id.astype(leaf_bank.dtype))
+            # a tree past the leaf bank's last real row is not banked
+            leaf_bank = leaf_bank.at[
+                jnp.where(live, jnp.minimum(t_row, bank_cap),
+                          bank_cap)].set(leaf_id.astype(leaf_bank.dtype))
 
-        def norm_body(carry, xs):
-            sc, vss, bf = carry
-            j, m = xs
-
-            def do(sc, vss, bf):
-                v2 = bf[j, LV0:LV1] * lr
-                new_vss = []
-                for vs, vb in zip(vss, new_vbanks):
-                    vleaf = vb[j].astype(jnp.int32)
-                    new_vss.append(
-                        vs.at[0].add(v2.astype(jnp.float32)[vleaf]))
-                v3 = v2 * (-kf)
-                leaf = leaf_bank[j].astype(jnp.int32)
-                sc = sc.at[0].add(v3.astype(jnp.float32)[leaf])
-                return sc, tuple(new_vss), bf.at[j, LV0:LV1].set(v3)
-
-            sc, vss, bf = jax.lax.cond(
-                m & live, do, lambda sc, vss, bf: (sc, vss, bf),
-                sc, vss, bf)
-            return (sc, vss, bf), None
-
-        with jax.named_scope(spans.DART_BANK):
-            (scores, vss, bank_f), _ = jax.lax.scan(
-                norm_body, (scores, tuple(new_valid), bank_f),
-                (drop_idx, drop_mask))
+        scores, vss, bank_f = _dart_normalize_trees(
+            scores, tuple(new_valid), bank_f, bank_i, leaf_bank,
+            new_vbanks, bins, drop_idx, drops, lr, kf, kept, L)
         # ints/floats (the AS-TRAINED packed tree, before any later drop
         # mutation) also return to the host: materialization needs the
         # pristine values for the f64 factor replay, with no bank pull
-        return (scores, list(vss), bank_i, bank_f, leaf_bank,
-                list(new_vbanks), ints, floats, stopped)
-    return jax.jit(_batch_iters(step, _SCAN_DART, k_iters),
-                   donate_argnums=(0, 1, 2, 3, 4, 5))
+        out = (scores, list(vss), bank_i, bank_f, leaf_bank,
+               list(new_vbanks), ints, floats, stopped)
+        if not reorder:
+            return out
+        filled = _FilledRows(leaf_bank, jnp.minimum(t_row + 1, bank_cap))
+        (bins_new, scores, bag_new, order_new, leaf_bank), gstate_new = \
+            _resort_by_leaf(leaf_id, [bins, scores, bag, row_order[0],
+                                      filled], gstate, row_state,
+                            compact_rows)
+        return ((scores,) + out[1:4] + (leaf_bank,) + out[5:]
+                + (bins_new, bag_new, gstate_new, order_new))
+    # gstate is NOT donated (the first re-sort aliases the objective's
+    # own arrays); the re-sorting step replaces bag, bins and the order
+    return jax.jit(_batch_iters(step, _SCAN_DART_REORDER if reorder
+                                else _SCAN_DART, k_iters),
+                   donate_argnums=(0, 1, 2, 3, 4, 5)
+                   + ((10, 12, 17) if reorder else ()))
 
 
 @contract.traced_pure
@@ -985,11 +1204,13 @@ def _bag_arrange_body(row_state, multi):
     def arrange(bins, scores, mask, gstate, order, *bank):
         with jax.named_scope(spans.BAG_ARRANGE):
             key = mask.any(axis=0) if multi else mask
-            # DART's leaf bank [T, N] is per-row on its last axis too
-            (bins, scores, mask, order, *bank), gstate = _resort_rows(
+            # DART's leaf bank is per-row on its last axis too: `bank`
+            # is (its rows, how many are filled)
+            filled = [_FilledRows(*bank)] if bank else []
+            (bins, scores, mask, order, *moved), gstate = _resort_rows(
                 (jnp.logical_not(key),),
-                [bins, scores, mask, order, *bank], gstate, row_state)
-        return (bins, scores, mask, gstate, order, *bank)
+                [bins, scores, mask, order, *filled], gstate, row_state)
+        return (bins, scores, mask, gstate, order, *moved)
     return arrange
 
 
@@ -1026,8 +1247,14 @@ class GBDT:
                  objective: Optional[Objective],
                  training_metrics: Sequence[Metric] = ()):
         rows = 0 if train_data is None else train_data.num_data
-        with spans.startup(spans.STARTUP_BOOSTER, rows=rows):
+        with spans.startup(spans.STARTUP_BOOSTER, rows=rows) as stats:
             self._build(config, train_data, objective, training_metrics)
+            stats.update(self._startup_stats())
+
+    def _startup_stats(self) -> dict:
+        """What the booster's start-up span says beyond its rows (DART:
+        the leaf bank's bound)."""
+        return {}
 
     def _build(self, config: Config, train_data: Optional[Dataset],
                objective: Optional[Objective],
@@ -1982,8 +2209,8 @@ class GBDT:
                         - self._trees_since_reorder)
             # reorder_every == 1: every iteration re-sorts — the segment
             # scans the reorder body uniformly, no cap needed
-        # (DART needs no extra cap: _ensure_bank_capacity grows the
-        # bank to fit any k before the dispatch)
+        # (DART needs no extra cap: its tree rows grow to fit any k
+        # before the dispatch, and a tree past its leaf bank is replayed)
         return max(k, 1)
 
     @contract.rank_uniform
@@ -2164,9 +2391,9 @@ class GBDT:
         self._arrange_for_bag()
         self._bag_arranged = True
 
-    def _dart_bank_rows(self):
+    def _dart_bank_rows(self) -> Optional[_FilledRows]:
         """Per-row DART bank buffers the arrangement must carry (base
-        GBDT has none; DART returns its leaf bank)."""
+        GBDT has none; DART returns its leaf bank and its fill)."""
         return None
 
     def _set_dart_bank_rows(self, arr) -> None:
@@ -2216,12 +2443,13 @@ class GBDT:
 
         fn = _get_fused_step(key, make)
         args = (self.bins_dev, self.scores, mask, gstate, order)
+        moved = args[:3] + args[4:]
         if bank is not None:
-            args += (bank,)
+            args += (bank.rows, jnp.int32(bank.fill))
+            moved += (bank,)
         with _enqueue("arrange", 0, self._shards,
                       window=self._bag_window, in_bag=self._bag_in_bag,
-                      **_resort_counts(args[:3] + args[4:], gstate,
-                                       row_state)):
+                      **_resort_counts(moved, gstate, row_state)):
             out = fn(*args)
         self.bins_dev, self.scores, mask_new, gstate_new, order_new = \
             out[:5]
@@ -2507,6 +2735,7 @@ class GBDT:
                 # what one tree's gradients cost (lambdarank's pair pass)
                 stats.update(self.objective.trace_counters())
             stats.update(self._sampling_counters())
+            stats.update(self._dart_counters())
             flush_span.set_metadata(**stats)
             with TraceAnnotation(spans.FLUSH_UNPACK):
                 self._unpack_pending()
@@ -2530,6 +2759,13 @@ class GBDT:
                              if frac < 1.0 else 0)}
         self._bag_draws = 0
         return out
+
+    def _dart_counters(self) -> dict:
+        """lgbm.flush's account of dropout boosting: the trees dropped
+        and, of them, replayed since the last flush, the trees banked
+        and the bank's bound; 0 where the job is no DART job."""
+        return {"dart_drops": 0, "dart_replayed": 0, "dart_bank_rows": 0,
+                "dart_bank_cap": 0}
 
     def _unpack_pending(self) -> None:
         """_flush_pending's host half: pulled buffers -> host Trees,
@@ -3472,9 +3708,15 @@ class DART(GBDT):
     in-dispatch, and host trees materialize from the async-copied
     as-trained rows plus an exact f64 replay of each tree's drop-factor
     history — no per-iteration host round trips and no drift from
-    device-dtype compounding.  Multiclass, custom gradients and
-    continued training keep the host-tree path."""
+    device-dtype compounding.  With the ordered partition on it re-sorts
+    on the plain step's cadence, the leaf bank riding the re-sort.
+    Multiclass, custom gradients and continued training keep the
+    host-tree path."""
     name = "dart"
+
+    # [N] buffers a step keeps replayed leaf ids in between its drop and
+    # its normalise (a byte a row each; counted in the bank's budget)
+    _REPLAY_SLOTS = 8
 
     def _build(self, config: Config, train_data, objective,
                training_metrics) -> None:
@@ -3482,6 +3724,7 @@ class DART(GBDT):
         self.drop_rate = config.drop_rate
         self.drop_rng = Mt19937Random(config.drop_seed)
         self.drop_index: List[int] = []
+        self._drop_history: List[List[int]] = []
         self._bank = None           # [bank_ints [T+1, Li], bank_floats]
         self._bank_count = 0
         self._bank_disabled = False
@@ -3491,10 +3734,15 @@ class DART(GBDT):
         # applied (in its own dtype) to the bank row
         self._bank_hist = {}
         self._bank_lv0 = {}         # row -> as-trained f64 leaf values
+        self._dart_drops = 0        # since the last flush: lgbm.flush
+        self._dart_replayed = 0
         # the banked path defers flushes like the fused GBDT paths; the
         # host-tree fallback needs trees (and the drop surgery) per
         # iteration
         self._flush_every = 16 if self._can_fuse_dart() else 1
+        # (rows of the leaf bank, the last the dummy; replay slots)
+        self._bank_plan = (self._plan_bank() if train_data is not None
+                           and self._can_fuse_dart() else (0, 0))
 
     @contract.rank_uniform
     def _can_fuse_dart(self) -> bool:
@@ -3517,17 +3765,54 @@ class DART(GBDT):
         return (self._can_fuse_dart()
                 and (self._bank is not None or not self._models))
 
+    def _reorder_enabled(self) -> bool:
+        # the banked step re-sorts as the plain fused step does
+        return (self.hist_ranged
+                and getattr(self.objective, "row_permutable", False)
+                and self._segment_fusible())
+
     def _train_segment_fused(self, k: int) -> None:
         self._run_fused_dart(k)
 
-    def _dart_bank_rows(self):
-        """The leaf bank [T, n_pad] is per-row state: the in-bag-first
-        arrangement must carry it (drop/normalize gathers read it by
-        row position)."""
-        return self._bank[2] if self._bank is not None else None
+    def _bank_cap(self) -> int:
+        """Trees the leaf bank can hold (its last row is the dummy)."""
+        return max(self._bank_plan[0] - 1, 0)
+
+    def _bank_fill(self) -> int:
+        """Trees the leaf bank holds: those trained, up to its bound."""
+        return min(self._bank_count, self._bank_cap())
+
+    def _dart_bank_rows(self) -> Optional[_FilledRows]:
+        """The leaf bank is per-row state: the in-bag-first arrangement
+        must carry it (drop/normalize gathers read it by row position),
+        as far as it is filled."""
+        if self._bank is None:
+            return None
+        return _FilledRows(self._bank[2], self._bank_fill())
 
     def _set_dart_bank_rows(self, arr) -> None:
         self._bank[2] = arr
+
+    def _startup_stats(self) -> dict:
+        return {"bank_cap": self._bank_cap(),
+                "bank_bytes": (self._bank_plan[0] * self.n_pad
+                               * self._leaf_dtype().itemsize)}
+
+    def _dart_counters(self) -> dict:
+        out = {"dart_drops": self._dart_drops,
+               "dart_replayed": self._dart_replayed,
+               "dart_bank_rows": (self._bank_fill()
+                                  if self._bank is not None else 0),
+               "dart_bank_cap": self._bank_cap()}
+        self._dart_drops = self._dart_replayed = 0
+        return out
+
+    def drop_history(self) -> List[List[int]]:
+        """The trees each iteration so far dropped, in iteration order
+        (the lottery over upstream's drop_seed stream; one forced where
+        it dropped none), as bag_mask() is for bags: for whoever checks
+        the job against upstream's stream."""
+        return [list(d) for d in self._drop_history]
 
     def _score_for_gradients(self):
         self._dropping_trees()
@@ -3565,24 +3850,88 @@ class DART(GBDT):
         verbatim by both paths so the mt19937 stream stays golden-pinned.
         Pure host state (drop_rng position + `it`), so a K-iteration
         segment precomputes all K lotteries before the dispatch."""
-        self.drop_index = []
-        if self.drop_rate > 1e-15:
-            if it > 0:
-                draws = self.drop_rng.next_doubles(it)
-                self.drop_index = [i for i in range(it)
-                                   if draws[i] < self.drop_rate]
-        if not self.drop_index and it > 0:
-            self.drop_index = list(self.drop_rng.sample(it, 1))
-        self.shrinkage_rate = 1.0 / (1.0 + len(self.drop_index))
+        with TraceAnnotation(spans.DART_DRAW, iter=it) as span:
+            self.drop_index = []
+            if self.drop_rate > 1e-15:
+                if it > 0:
+                    draws = self.drop_rng.next_doubles(it)
+                    self.drop_index = [i for i in range(it)
+                                       if draws[i] < self.drop_rate]
+            if not self.drop_index and it > 0:
+                self.drop_index = list(self.drop_rng.sample(it, 1))
+            self.shrinkage_rate = 1.0 / (1.0 + len(self.drop_index))
+            span.set_metadata(k=len(self.drop_index))
+        del self._drop_history[it:]     # a restored job draws again
+        self._drop_history.append(list(self.drop_index))
+
+    def _leaf_dtype(self):
+        return np.dtype(np.uint8 if max(self.config.num_leaves, 2) <= 256
+                        else np.int32)
+
+    def _plan_bank(self) -> Tuple[int, int]:
+        """(rows of the leaf bank, replay slots), from the device's
+        memory limit and the shapes alone, once, at start-up.
+
+        A banked tree is a leaf id a row ([N] uint8: 68 MB at 68.3M
+        rows), so the bank of a 500-tree job would be 34 GB.  It holds
+        what fits beside the job's own state: the bin matrix and some
+        32 B a row of scores, order, bag, gradients and the objective's
+        arrays, and what the largest step holds while it runs, the
+        re-sort's stack of word rows padded to whole tiles and its sort
+        (PERF.md section 7: 4.88 GiB at 68.3M x 39, 76.7 B a row), with
+        a sixteenth of the limit left over.  Whole groups of a re-sort's
+        carry (_bank_group: 32 uint8 rows), the last row the one dead
+        and unbanked steps write to; no more than the job's
+        num_iterations need.  Where some tree will lie outside, the
+        step's replay slots are counted too.  A bank that cannot hold
+        one tree stops the job here, with the numbers."""
+        from ..utils.device import memory_limit_bytes
+        dt = self._leaf_dtype()
+        per_word = 4 // dt.itemsize
+        group = _TILE_WORDS * per_word
+        need = max(self.config.num_iterations, 1) + 1    # + the dummy row
+        whole = -(-need // per_word) * per_word if need < group \
+            else -(-need // group) * group
+        limit = memory_limit_bytes()
+        if limit is None:
+            log.info("DART: leaf bank of %d trees (no memory limit known)"
+                     % (whole - 1))
+            return whole, 0
+        n, f = self.n_pad, int(self.bins_dev.shape[0])
+        words = _word_rows(self.bins_dev, n) + 5
+        live = n * (f * self.bins_dev.dtype.itemsize + 32)
+        step = n * (4 * -(-words // _TILE_WORDS) * _TILE_WORDS + 16)
+        room = limit - limit // 16 - live - step
+        fit = room // (n * dt.itemsize)
+        slots = 0
+        if fit < whole:
+            slots = self._REPLAY_SLOTS
+            fit -= slots
+            whole = fit // group * group if fit >= group \
+                else fit // per_word * per_word
+        told = ("DART: leaf bank of %d trees, %.2f GB (%d rows x %d B; the "
+                "device lets a process hold %.2f GB, the job's rows %.2f, "
+                "its largest step %.2f); a dropped tree past it is "
+                "replayed" % (max(whole - 1, 0), whole * n * dt.itemsize
+                              / 1e9, n, dt.itemsize, limit / 1e9,
+                              live / 1e9, step / 1e9))
+        if whole < 2:
+            log.fatal(told + ": no room for one banked tree beside the "
+                      "rows; train fewer rows a device")
+        log.info(told)
+        return whole, slots
 
     def _ensure_bank_capacity(self, k_iters: int) -> None:
-        """Bank rows for the next k_iters trees (+ the dummy row dead
+        """Tree rows for the next k_iters trees (+ the dummy row dead
         steps write to); initializes on first use, doubles past
-        config.num_iterations (api num_boost_round, bench loops)."""
+        config.num_iterations (api num_boost_round, bench loops).  The
+        LEAF bank ([rows, N], _plan_bank) is made once, on the device,
+        and never grows: no copy of it is ever held beside it, and a
+        tree past it is replayed where it is dropped."""
         cfg = self.config
         L = max(cfg.num_leaves, 2)
         SF0, TB0, LC0, RC0, RC1, LV0, LV1 = _dart_layout(L)
-        leaf_dt = np.uint8 if L <= 256 else np.int32
+        leaf_dt = self._leaf_dtype()
         if self._bank is None:
             T = max(cfg.num_iterations, k_iters) + 1  # + dummy row
             li = 1 + 4 * (L - 1) + 3 * L + 3
@@ -3594,12 +3943,14 @@ class DART(GBDT):
             bi[:, LC0:RC1] = -1
             self._bank = [jnp.asarray(bi),
                           jnp.zeros((T, lf), dtype=self.dtype),
-                          jnp.zeros((T, self.n_pad), dtype=leaf_dt),
+                          jnp.zeros((self._bank_plan[0], self.n_pad),
+                                    dtype=leaf_dt),
                           [jnp.zeros((T, int(vb.shape[1])), dtype=leaf_dt)
                            for vb in self.valid_bins_dev]]
             self._bank_count = 0
         while self._bank_count + k_iters > self._bank[0].shape[0] - 1:
-            # double the bank, keeping new rows traversal-safe.  The OLD
+            # double the small banks (a tree's packed rows, the valid
+            # sets' ids), keeping new rows traversal-safe.  The OLD
             # dummy row becomes a real row — reset it too: dead
             # (post-stop) steps may have written a garbage tree there,
             # which would otherwise materialize as a phantom model entry
@@ -3616,7 +3967,7 @@ class DART(GBDT):
                 jnp.concatenate([self._bank[0][:-1],
                                  jnp.asarray(safe), jnp.asarray(pad_i)]),
                 dbl(self._bank[1].at[T - 1].set(0.0)),
-                dbl(self._bank[2]),
+                self._bank[2],
                 [dbl(vb) for vb in self._bank[3]]]
 
     def _run_fused_dart(self, k_iters: int = 1) -> None:
@@ -3648,58 +3999,74 @@ class DART(GBDT):
                 if j == 0:
                     self._ensure_bag_arranged()
                 fmasks.append(self._feature_mask(0))
+        reorder = self._reorder_now()
         compact = self._bag_compact_rows() if self._bag_arranged else 0
-        # fixed cap -> ONE executable for every drop count <= 8 (padded
-        # slots are lax.cond-skipped); pow2 buckets beyond are the rare
-        # escape for high drop rates.  A segment pads every iteration to
-        # its max bucket so the whole segment shares one executable.
-        dp = 8
-        while dp < max(len(d) for d in drops):
-            dp *= 2
-        drop_idx = np.zeros((k_iters, dp), np.int32)
-        drop_mask = np.zeros((k_iters, dp), bool)
+        slots = self._bank_plan[1]
+        # the drop list is as long as the tree rows and its count a
+        # run-time scalar: ONE executable a (re-sorting or not, K),
+        # whatever an iteration drops
+        drop_idx = np.zeros((k_iters, self._bank[0].shape[0]), np.int32)
         for j, d in enumerate(drops):
             drop_idx[j, :len(d)] = d
-            drop_mask[j, :len(d)] = True
+        drop_count = np.asarray([len(d) for d in drops], np.int32)
+        self._dart_drops += int(drop_count.sum())
+        self._dart_replayed += sum(i >= self._bank_cap()
+                                   for d in drops for i in d)
         key = ("dart", self.objective.fused_key(), self.dtype,
                self.hist_impl, self.max_bin, L, cfg.max_depth,
                self.params, len(self.valid_bins_dev), self.hist_slots,
-               self.hist_ranged, dp, compact, k_iters)
+               self.hist_ranged, slots, reorder, compact, k_iters)
+        row_state = self.objective.make_row_state_fn()
 
         def make():
             grow_kw = self._grow_kw()
             return _make_fused_step_dart(self.objective.make_grad_fn(),
-                                         grow_kw, self.dtype, L, compact,
-                                         k_iters)
+                                         grow_kw, self.dtype, L, slots,
+                                         row_state if reorder else None,
+                                         compact, k_iters)
 
         fn = _get_fused_step(key, make)
+        gstate = self._gstate_for_fused()
         with TraceAnnotation(spans.HOST_INPUTS):
-            if k_iters == 1:
-                dev_in = (jnp.asarray(drop_idx[0]),
-                          jnp.asarray(drop_mask[0]),
-                          jnp.asarray(rates[0], dtype=self.dtype),
-                          jnp.asarray(kfs[0], dtype=self.dtype))
-                t_row = jnp.int32(self._bank_count)
-            else:
-                dev_in = (jnp.asarray(drop_idx), jnp.asarray(drop_mask),
-                          jnp.asarray(np.asarray(rates, dtype=np.float64)
-                                      .astype(self.dtype)),
-                          jnp.asarray(np.asarray(kfs, dtype=np.float64)
-                                      .astype(self.dtype)))
-                t_row = jnp.arange(self._bank_count,
-                                   self._bank_count + k_iters,
-                                   dtype=jnp.int32)
+            one = k_iters == 1
+            rates_dev = np.asarray(rates, np.float64).astype(self.dtype)
+            kfs_dev = np.asarray(kfs, np.float64).astype(self.dtype)
+            t_row = np.arange(self._bank_count, self._bank_count + k_iters,
+                              dtype=np.int32)
+            fmask = np.stack(fmasks)
+            bag_dev = self._bag_mask_dev_fused(0)
+            if reorder and bag_dev.dtype == jnp.uint8:
+                # ONE bag signature for the re-sorting step (_run_fused)
+                bag_dev = _unpack_bag_jit(bag_dev, self.n_pad)
             args = (self.scores, list(self.valid_scores), self._bank[0],
                     self._bank[1], self._bank[2], list(self._bank[3]),
-                    dev_in[0], dev_in[1], dev_in[2], dev_in[3],
-                    self._bag_mask_dev_fused(0),
-                    jnp.asarray(fmasks[0] if k_iters == 1
-                                else np.stack(fmasks)),
+                    *(jnp.asarray(a[0] if one else a) for a in
+                      (drop_idx, drop_count, rates_dev, kfs_dev)),
+                    bag_dev, jnp.asarray(fmask[0] if one else fmask),
                     self.bins_dev, tuple(self.valid_bins_dev),
-                    self._gstate_for_fused(), self._dev_stopped, t_row)
-        with _enqueue("dart", k_iters, self._shards):
-            (self.scores, valid, bi, bf, lb, vbs, ints, floats,
-             self._dev_stopped) = fn(*args)
+                    gstate, self._dev_stopped,
+                    jnp.asarray(t_row[0] if one else t_row))
+        if reorder:
+            order = (self._row_order if self._row_order is not None
+                     else self._identity_order_dev())
+            fill = min(self._bank_count + k_iters, self._bank_cap())
+            with _enqueue("resort", k_iters, self._shards,
+                          **_resort_counts(
+                              [self.bins_dev, self.scores, bag_dev, order,
+                               _FilledRows(self._bank[2], fill)], gstate,
+                              row_state)):
+                (self.scores, valid, bi, bf, lb, vbs, ints, floats,
+                 self._dev_stopped, self.bins_dev, bag_new, gstate_new,
+                 self._row_order) = fn(*args, order)
+            self._bag_dev_packed[0] = bag_new
+            self._gstate_override = gstate_new
+            self._inv_order = None
+            self._trees_since_reorder = 0
+        else:
+            with _enqueue("dart", k_iters, self._shards):
+                (self.scores, valid, bi, bf, lb, vbs, ints, floats,
+                 self._dev_stopped) = fn(*args)
+            self._trees_since_reorder += k_iters
         self._bank = [bi, bf, lb, list(vbs)]
         self.valid_scores = list(valid)
         # raw floats + each iteration's 1/(1+k) shrinkage applied on the
@@ -3804,6 +4171,10 @@ class DART(GBDT):
                 dtype=np.float64).reshape(-1, 4),
             "dart_bank_lv0_rows": np.asarray(
                 sorted(self._bank_lv0), dtype=np.int64),
+            "dart_drop_iters": np.int64(len(self._drop_history)),
+            "dart_drop_history": np.asarray(
+                [(it, t) for it, d in enumerate(self._drop_history)
+                 for t in d], dtype=np.int64).reshape(-1, 2),
         }
         if self._bank_lv0:
             out["dart_bank_lv0"] = np.stack(
@@ -3826,16 +4197,21 @@ class DART(GBDT):
         bank_f = jnp.asarray(np.asarray(z["dart_bank_f"]),
                              dtype=self.dtype)
         self._bank_count = int(z["dart_bank_count"])
+        if "dart_drop_history" in z:
+            self._drop_history = [[] for _ in range(int(z["dart_drop_iters"]))]
+            for it, t in np.asarray(z["dart_drop_history"]).reshape(-1, 2):
+                self._drop_history[int(it)].append(int(t))
         # leaf-assignment banks are NOT checkpointed ([T, N] would dwarf
         # the snapshot); rebuild them with one traversal per restored
         # tree — structure is immutable, so this reproduces the training-
-        # time leaf ids exactly.  Rows collect in HOST buffers and upload
-        # once (per-tree .at[t].set on the device bank would copy the
-        # whole [T, N] array per tree: O(T^2 N) traffic).
+        # time leaf ids exactly, in the restored row order.  The leaf
+        # bank is made on the device at its planned size and filled a row
+        # at a time IN PLACE (donated), as far as it reaches: no [T, N]
+        # buffer on the host, no second copy on the device.  The valid
+        # sets' rows collect in host buffers and upload once.
         T = int(bank_i.shape[0])
-        L = max(self.config.num_leaves, 2)
-        leaf_dt = np.uint8 if L <= 256 else np.int32
-        lb = np.zeros((T, self.n_pad), dtype=leaf_dt)
+        leaf_dt = self._leaf_dtype()
+        lb = jnp.zeros((self._bank_plan[0], self.n_pad), dtype=leaf_dt)
         vbs = [np.zeros((T, int(vb.shape[1])), dtype=leaf_dt)
                for vb in self.valid_bins_dev]
         for t, tree in enumerate(self._models[:self._bank_count]):
@@ -3843,12 +4219,13 @@ class DART(GBDT):
             tb = jnp.asarray(tree.threshold_bin)
             lc = jnp.asarray(tree.left_child)
             rc = jnp.asarray(tree.right_child)
-            lb[t] = np.asarray(predict_leaf_binned(
-                sf, tb, lc, rc, self.bins_dev)).astype(leaf_dt)
+            if t < lb.shape[0] - 1:
+                lb = _set_bank_row(lb, t, predict_leaf_binned(
+                    sf, tb, lc, rc, self.bins_dev))
             for i, vbins in enumerate(self.valid_bins_dev):
                 vbs[i][t] = np.asarray(predict_leaf_binned(
                     sf, tb, lc, rc, vbins)).astype(leaf_dt)
-        self._bank = [bank_i, bank_f, jnp.asarray(lb),
+        self._bank = [bank_i, bank_f, lb,
                       [jnp.asarray(vb) for vb in vbs]]
         self._bank_disabled = False
         self._bank_dirty = False      # restored trees hold final values

@@ -60,7 +60,6 @@ def predict_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
     return ~node
 
 
-@jax.jit
 def replay_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
                        left_child: jax.Array, num_leaves: jax.Array,
                        bins_t: jax.Array) -> jax.Array:
@@ -91,6 +90,13 @@ def replay_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
         return jnp.where(go_right, k + 1, leaf)
     return jax.lax.fori_loop(0, nodes - 1, split,
                              jnp.zeros(bins_t.shape[1], dtype=jnp.int32))
+
+
+# The body itself, for a caller that is traced twice under two scopes (DART's
+# drop and normalise): a jitted function's trace is kept, and with it the
+# scope names of whoever traced it first.
+replay_leaf_binned_inline = replay_leaf_binned
+replay_leaf_binned = jax.jit(replay_leaf_binned)
 
 
 @jax.jit

@@ -202,10 +202,15 @@ def startup_line() -> str:
     and the ledger's totals (README.md, "Profiling a training job")."""
     spent: Dict[str, float] = {}
     upload_bytes = 0
+    bank = ""
     for r in spans.startup_records():
         spent[r["name"]] = spent.get(r["name"], 0.0) + r["dur"]
         if r["name"] == spans.STARTUP_UPLOAD:
             upload_bytes += r["stats"].get("bytes", 0)
+        if r["stats"].get("bank_cap"):      # a DART job: its bank's bound
+            bank = ("; DART leaf bank %d trees, %.2f GB"
+                    % (r["stats"]["bank_cap"],
+                       r["stats"]["bank_bytes"] / 1e9))
     at = spans.stamps()
     t = totals()
     return ("start-up: first dispatch at %s s (objective %s, booster %s of "
@@ -219,7 +224,7 @@ def startup_line() -> str:
                t["executables"], _s(spent.get(spans.FIRST_CALL, 0.0)),
                _s(t["trace_s"]), _s(t["lower_s"]),
                _s(t["backend_s"] + t["retrieval_s"]), t["hits"],
-               t["misses"], _s(at.get(spans.FIRST_TREE))))
+               t["misses"], _s(at.get(spans.FIRST_TREE))) + bank)
 
 
 def enable_compilation_cache() -> None:

@@ -68,3 +68,14 @@ def resolve_device(device_type: str) -> str:
                   % (device_type, platform, devs[0].device_kind,
                      len(devs)))
     return platform
+
+
+def memory_limit_bytes():
+    """What the first device lets one process hold (`bytes_limit` of its
+    `memory_stats()`), or None where the backend does not say (the CPU).
+    What holds per-row state many times over sizes itself by it (DART's
+    leaf bank, models/gbdt.py)."""
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) if limit else None

@@ -11,8 +11,9 @@ event's stats, so each count travels with its span.  Both land in the one
 `.xplane.pb` of `jax.profiler.trace(dir)`, on one clock;
 `benchmark/phase_table.py <dir>` prints the table (the benchmark keeps its
 own copy of these names, `benchmark/harness/scopes.json`,
-`scopes_ranked.json`, `scopes_bagged.json` and `scopes_startup.json`;
-tests/test_spans.py holds their union equal).  START-UP SPANS cover what
+`scopes_ranked.json`, `scopes_bagged.json`, `scopes_dart.json` and
+`scopes_startup.json`; tests/test_spans.py holds their union equal).
+START-UP SPANS cover what
 a job does before its steady state, where no profiler runs: `startup()`
 opens the same `TraceAnnotation` AND keeps a record in this process
 (name, parent, process age at the start, duration, stats), which
@@ -48,19 +49,30 @@ VALID_UPDATE = "lgbm.valid_update"    # descent and add on each valid set
 PACK_TREE = "lgbm.pack_tree"          # _pack_tree
 RESORT = "lgbm.resort"                # ordered-partition row re-sort
 BAG_ARRANGE = "lgbm.bag_arrange"      # in-bag-first arrangement
-DART_BANK = "lgbm.dart_bank"          # DART drop, normalise, bank write
+DART_BANK = "lgbm.dart_bank"          # DART's append of a new tree's rows
 # lambdarank's gradients (objectives.py), nested INSIDE lgbm.objective: a
 # reader that takes the last component sees them apart, and what stays
 # under lgbm.objective alone is the casts and the loop's plumbing
 RANK_GATHER = "lgbm.rank_gather"      # score[doc_idx], the two row_slot gathers
 RANK_SORT = "lgbm.rank_sort"          # the two argsorts, the discount look-up
 RANK_PAIRS = "lgbm.rank_pairs"        # everything [QB, L, L] and its sums
+# DART's score surgery (models/gbdt.py _make_fused_step_dart): lgbm.dart_bank
+# above is the append alone; lgbm.dart_replay lies INSIDE the drop or the
+# normalise and lgbm.dart_carry INSIDE lgbm.resort or lgbm.bag_arrange (a
+# reader takes the last component)
+DART_DROP = "lgbm.dart_drop"          # the dropped trees taken off the scores
+DART_NORMALIZE = "lgbm.dart_normalize"  # and put back, shrunk by k / (1 + k)
+DART_REPLAY = "lgbm.dart_replay"      # a dropped tree outside the leaf bank:
+#                                       its leaf ids by replay_leaf_binned
+DART_CARRY = "lgbm.dart_carry"        # the leaf bank's filled groups in a
+#                                       re-sort or an arrangement
 
 DEVICE_SCOPES = (
     OBJECTIVE, GROW, HIST_ROOT, BLOCK_LIST, HIST_SWEEP, HIST_POOL,
     HIST_EXCHANGE, GAIN_SCAN, PARTITION, TREE_UPDATE, OOB_DESCENT,
     SCORE_UPDATE, VALID_UPDATE, PACK_TREE, RESORT, BAG_ARRANGE, DART_BANK,
-    RANK_GATHER, RANK_SORT, RANK_PAIRS)
+    RANK_GATHER, RANK_SORT, RANK_PAIRS, DART_DROP, DART_NORMALIZE,
+    DART_REPLAY, DART_CARRY)
 
 # -- host spans (models/gbdt.py segment loop), with their stats ------------
 SEGMENT = "lgbm.segment"              # iter, k: one train_segment / iteration
@@ -80,16 +92,24 @@ FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swe
 #                                       costs), and the sampling's:
 #                                       bag_window, bag_in_bag, bag_draws
 #                                       (since the last flush), feat_used,
-#                                       0 where sampling is off:
+#                                       0 where sampling is off, and
+#                                       DART's: dart_drops (trees dropped,
+#                                       summed over the flushed iterations),
+#                                       dart_replayed (those of them that lay
+#                                       outside the leaf bank), dart_bank_rows
+#                                       (trees banked), dart_bank_cap, 0 where
+#                                       the job is no DART job:
 #                                       _flush_pending
 FLUSH_PULL = "lgbm.flush_pull"        # the device_get (host waits for device)
 FLUSH_UNPACK = "lgbm.flush_unpack"    # _unpack_tree loop, stump truncation
 EVAL = "lgbm.eval"                    # iter: metrics and early stopping
 BAG_DRAW = "lgbm.bag_draw"            # iter, rows, in_bag: _bagging when it
 #                                       redraws, INSIDE lgbm.host_inputs
+DART_DRAW = "lgbm.dart_draw"          # iter, k: DART's lottery over upstream's
+#                                       stream, INSIDE lgbm.host_inputs
 
 HOST_SPANS = (SEGMENT, HOST_INPUTS, ENQUEUE, FLUSH, FLUSH_PULL,
-              FLUSH_UNPACK, EVAL, BAG_DRAW)
+              FLUSH_UNPACK, EVAL, BAG_DRAW, DART_DRAW)
 
 # `kind` of an lgbm.enqueue span: which executable was called
 ENQUEUE_KINDS = ("scan", "resort", "multi", "dart", "arrange", "general")
@@ -100,6 +120,9 @@ STARTUP_DATASET = "lgbm.startup_dataset"      # rows, features: the CLI's and
 STARTUP_OBJECTIVE = "lgbm.startup_objective"  # rows, lambdarank's queries:
 #                                               Objective.init
 STARTUP_BOOSTER = "lgbm.startup_booster"      # rows: GBDT.__init__, DART's
+#                                               (which adds bank_cap, the
+#                                               trees its leaf bank holds,
+#                                               and bank_bytes)
 STARTUP_UPLOAD = "lgbm.startup_upload"        # bytes, shards: INSIDE the
 #                                               booster's, the bin matrix,
 #                                               scores and per-row state on

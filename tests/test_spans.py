@@ -39,7 +39,15 @@ PATHS = {
     "multiclass": ({"objective": "multiclass", "num_class": 3}, 3, False,
                    set()),
     "dart": ({"boosting_type": "dart", "drop_rate": 0.5}, 1, False,
-             {spans.DART_BANK}),
+             {spans.DART_BANK, spans.DART_DROP, spans.DART_NORMALIZE,
+              spans.DART_REPLAY}),
+    # the banked step on the ordered path: the bank rides the re-sort
+    "dart_reorder": ({"boosting_type": "dart", "drop_rate": 0.5,
+                      "hist_impl": "pallas", "hist_reorder_every": 2}, 1,
+                     False,
+                     {spans.BLOCK_LIST, spans.RESORT, spans.DART_BANK,
+                      spans.DART_DROP, spans.DART_NORMALIZE,
+                      spans.DART_REPLAY, spans.DART_CARRY}),
     "sharded": ({"tree_learner": "data", "num_shards": 4}, 1, False,
                 {spans.HIST_EXCHANGE}),
     "bagged": ({"bagging_fraction": 0.5, "bagging_freq": 1,
@@ -139,8 +147,30 @@ def test_rank_scopes_nest_inside_the_objective(monkeypatch):
     assert "sort" not in ops(spans.RANK_PAIRS) | ops(spans.RANK_GATHER)
 
 
+def test_dart_scopes_nest_where_a_reader_expects_them(monkeypatch):
+    """A replayed drop lowers under `lgbm.dart_replay` INSIDE the drop or
+    the normalise, the bank's groups under `lgbm.dart_carry` INSIDE
+    `lgbm.resort`: a reader that takes the last `lgbm.*` component sees
+    them apart, and the append alone is left under `lgbm.dart_bank`."""
+    text = _lowered_texts("dart_reorder", monkeypatch, compiled=True)
+    names = {n for n in re.findall(r'op_name="([^"]*)"', text)
+             if n.startswith("jit(")}
+
+    def outer(scope):
+        return {tuple(SCOPE_RE.findall(n.split(scope)[0])) for n in names
+                if "/%s/" % scope in n}
+    assert outer(spans.DART_REPLAY) == {(spans.DART_DROP,),
+                                        (spans.DART_NORMALIZE,)}
+    assert outer(spans.DART_CARRY) == {(spans.RESORT,)}
+    assert outer(spans.DART_BANK) == {()}
+    ops = {n.rsplit("/", 1)[-1] for n in names
+           if "/%s/" % spans.DART_CARRY in n}
+    assert {"gather", "dynamic_update_slice"} <= ops, ops
+
+
 @pytest.mark.parametrize("path,scope", [("reorder", spans.RESORT),
-                                        ("bagged", spans.BAG_ARRANGE)])
+                                        ("bagged", spans.BAG_ARRANGE),
+                                        ("dart_reorder", spans.RESORT)])
 def test_resort_helper_lowers_under_its_scope(path, scope, monkeypatch):
     """Every operation `_resort_rows` makes (the sort, the gather of the
     stacked words, the wider arrays' gathers, the window's copies, the
@@ -349,24 +379,35 @@ def _benchmark_names(name):
 def test_benchmark_copy_is_equal(key, ours):
     """The program's lists equal the UNION of the benchmark's scope files
     (`scopes.json`, accepted and not edited, `scopes_ranked.json`, what the
-    ranking cell added, and `scopes_bagged.json`, what the bagged cell
-    added), and in each file's grouping every scope a cell of that file
-    can show feeds exactly one of its metrics."""
+    ranking cell added, `scopes_bagged.json`, what the bagged cell added,
+    and `scopes_dart.json`, what the DART cell added), and in each file's
+    grouping every scope a cell of that file can show feeds exactly one of
+    its metrics: all the program's in the newest file, all but what a
+    later file added in an older one."""
     base = _benchmark_names("scopes.json")
     ranked = _benchmark_names("scopes_ranked.json")
     bagged = _benchmark_names("scopes_bagged.json")
+    dart = _benchmark_names("scopes_dart.json")
     assert (tuple(base[key]) + tuple(ranked.get(key, ()))
-            + tuple(bagged.get(key, ()))) == ours
+            + tuple(bagged.get(key, ())) + tuple(dart.get(key, ()))) == ours
     if key == "device_scopes":
         grouped = [s for g in base["device_groups"].values() for s in g]
         assert sorted(grouped) == sorted(base[key])
         for added in (ranked, bagged):
             grouped = [s for g in added["device_groups"].values() for s in g]
-            assert sorted(grouped) == sorted(ours)
+            assert sorted(grouped) == sorted(set(ours) - set(dart[key]))
+        grouped = [s for g in dart["device_groups"].values() for s in g]
+        assert sorted(grouped) == sorted(ours)
+        # a part is read on its own AND inside its group
+        for part in dart["device_parts"].values():
+            assert set(part) <= set(grouped)
     if key == "host_spans":
         grouped = {s for g in bagged["host_groups"].values()
                    for s in g["spans"]}
         assert grouped <= set(ours) and spans.BAG_DRAW in grouped
+        grouped = {s for g in dart["host_groups"].values()
+                   for s in g["spans"]}
+        assert grouped <= set(ours) and spans.DART_DRAW in grouped
 
 
 def test_benchmark_copy_of_the_start_up_names_is_equal():
