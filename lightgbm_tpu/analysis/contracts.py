@@ -175,6 +175,8 @@ CONSUME_KINDS: Mapping[str, str] = {
     "gstate": "gstate",
     "stopped": "stopped",
     "row_order": "order",
+    # the last trees' packed rows, which order a re-sort's leaves inside
+    "prev_trees": "order",
     # DART device-bank inputs
     "bank_i": "bank", "bank_f": "bank", "leaf_bank": "bank",
     "vbanks": "bank", "t_row": "bank",

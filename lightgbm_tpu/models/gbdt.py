@@ -70,17 +70,17 @@ def _pack_tree(dev_tree):
     """TreeArrays -> (int32 buffer, float buffer): two flat arrays so a
     whole tree ships device->host in two async copies instead of eleven.
     The trailing dummy slots (grow.py TreeArrays) are trimmed here, so the
-    wire layout stays [1 + 4*(L-1) + 3*L + 3 | (L-1) + L + (L-1)]: the
-    int row ends with the tree's three block counters (blocks_swept,
-    grid_rows, partition_blocks), behind everything _unpack_tree and
-    _dart_layout slice."""
+    wire layout stays [1 + 4*(L-1) + 3*L + 4 | (L-1) + L + (L-1)]: the
+    int row ends with the tree's four sweep counters (blocks_swept,
+    grid_rows, partition_blocks, rows_swept), behind everything
+    _unpack_tree and _dart_layout slice."""
     ints = jnp.concatenate([
         dev_tree.num_leaves.reshape(1), dev_tree.split_feature[:-1],
         dev_tree.threshold_bin[:-1], dev_tree.left_child[:-1],
         dev_tree.right_child[:-1], dev_tree.leaf_parent[:-1],
         dev_tree.leaf_depth[:-1], dev_tree.leaf_count[:-1],
         dev_tree.blocks_swept.reshape(1), dev_tree.grid_rows.reshape(1),
-        dev_tree.partition_blocks.reshape(1),
+        dev_tree.partition_blocks.reshape(1), dev_tree.rows_swept.reshape(1),
     ]).astype(jnp.int32)
     floats = jnp.concatenate([dev_tree.split_gain[:-1],
                               dev_tree.leaf_value[:-1],
@@ -156,8 +156,8 @@ def _permute_packed_bag(packed: jax.Array, row_order: jax.Array):
 # closed over via the outer args.
 
 _SCAN_PLAIN = (((0, 0), (1, 1), (7, 4)), (3,), (2, 3), 5)
-_SCAN_REORDER = (((0, 0), (1, 1), (2, 5), (4, 4), (6, 6), (7, 7), (8, 8)),
-                 (3,), (2, 3), 9)
+_SCAN_REORDER = (((0, 0), (1, 1), (2, 5), (4, 4), (6, 6), (7, 7), (8, 8),
+                  (9, 9)), (3,), (2, 3), 10)
 _SCAN_MULTI = (((0, 0), (1, 1), (7, 4)), (3,), (2, 3), 5)
 _SCAN_MULTI_REORDER = (((0, 0), (1, 1), (2, 6), (4, 5), (6, 7), (7, 4),
                         (8, 8)), (3,), (2, 3), 9)
@@ -643,27 +643,69 @@ def _carry_filled(bank: _FilledRows, rel, cols, blocks, start_of):
         return jax.lax.fori_loop(0, -(-fill // group), one_group, out)
 
 
+def _packed_leaf_ids(row, bins, L):
+    """Leaf ids of the packed tree `row` (_pack_tree's int row) over the
+    rows of `bins` as they lie, by a replay of its splits: one pass over a
+    bin row a split.  A row of zeros (no tree) gives every row leaf 0."""
+    SF0, TB0, LC0, RC0 = _dart_layout(L)[:4]
+    # + the dummy slot the replay skips
+    return replay_leaf_binned_inline(
+        jnp.pad(row[SF0:TB0], (0, 1)), jnp.pad(row[TB0:LC0], (0, 1)),
+        jnp.pad(row[LC0:RC0], (0, 1), constant_values=-1), row[0], bins,
+        jnp.uint8 if L <= 256 else jnp.int32)
+
+
+# the trees grown before a re-sorting step's own whose leaves order the rows
+# inside each of its leaves (_resort_by_leaf)
+_RESORT_PREV = 2
+
+
+def _leaf_key(leaf_ids, max_leaves: int):
+    """ONE uint32 sort key a row from leaf ids, the most significant first,
+    b bits each (b = the bits of max_leaves - 1, so every leaf fits); ids
+    that would not fit in 32 bits are left out."""
+    b = (max_leaves - 1).bit_length()
+    key = None
+    for ids in leaf_ids[:32 // b]:
+        ids = ids.astype(jnp.uint32)
+        key = ids if key is None else (key << b) | ids
+    return key
+
+
 @contract.traced_pure
-def _resort_by_leaf(leaf_id, bufs, gstate, row_state, compact_rows):
+def _resort_by_leaf(leaf_id, prev_trees, bufs, gstate, row_state,
+                    compact_rows, max_leaves: int):
     """The re-sort that ends a re-sorting step, the plain one's and
     DART's alike: every per-row buffer (`bufs`: bins first; DART's leaf
     bank, a _FilledRows, last) and the objective's state, stably sorted
-    by the tree's leaves.  Padded rows ride along via their tracked
-    leaf_id and stay permanently out-of-bag through the permuted bag
-    mask.  Under bag compaction only the static window re-sorts."""
+    by the tree's leaves and, inside a leaf, by the leaves of the trees
+    grown just before it (`prev_trees`: their packed int rows, the latest
+    first), whose ids a replay of their splits gives (_packed_leaf_ids).
+    A later tree splits on the features the last ones split on, so it
+    cuts these runs far less often than the order an older re-sort left
+    inside a leaf (PERF.md section 6).  Ties keep that order.
+    Padded rows ride along via their tracked leaf_id and stay
+    permanently out-of-bag through the permuted bag mask.  Under bag
+    compaction only the static window re-sorts."""
     n = bufs[0].shape[1]
     with jax.named_scope(spans.RESORT):
         m = compact_rows if 0 < compact_rows < n else n
-        return _resort_rows((leaf_id[:m],), bufs, gstate, row_state)
+        bins = bufs[0][:, :m]
+        key = _leaf_key([leaf_id[:m]] + [_packed_leaf_ids(r, bins, max_leaves)
+                                          for r in prev_trees], max_leaves)
+        return _resort_rows((key,), bufs, gstate, row_state)
 
 
 @contract.traced_pure
 def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype, row_state,
                              compact_rows=0):
     """The fused step PLUS the ordered-partition row re-sort: after the
-    tree lands, rows are stably re-sorted by its leaf assignment so later
-    trees' leaves stay block-clustered and the block-list sweeps
-    (ops/grow.py ranged mode) touch few blocks.  Everything per-row
+    tree lands, rows are stably re-sorted by its leaf assignment, and by
+    the last trees' inside a leaf (`prev_trees`: their packed int rows,
+    the latest first; the step returns them shifted by its own, which is
+    what the next iteration of a scan takes), so later trees' leaves stay
+    block-clustered and the block-list sweeps (ops/grow.py ranged mode)
+    touch few blocks (_resort_by_leaf).  Everything per-row
     (bins, scores, bag mask, objective state, the composed row order)
     comes back permuted in the SAME dispatch (_resort_rows); valid sets
     and tree output are row-order-free.
@@ -676,7 +718,7 @@ def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype, row_state,
     keeps its positions (tail rows never enter histograms, so their
     clustering is irrelevant)."""
     def step(scores, valid_scores, bag_mask, fmask, bins, valid_bins,
-             gstate, row_order, stopped):
+             gstate, row_order, stopped, prev_trees):
         bag = _unpack_bag(bag_mask, bins.shape[1])
         with jax.named_scope(spans.OBJECTIVE):
             grad, hess = grad_fn(scores[0], gstate)
@@ -701,10 +743,12 @@ def _fused_step_body_reorder(grad_fn, grow_kw, lr, dtype, row_state,
         with jax.named_scope(spans.PACK_TREE):
             ints, floats = _pack_tree(dev_tree)
         (bins_new, scores, bag_new, order_new), gstate_new = \
-            _resort_by_leaf(leaf_id, [bins, scores, bag, row_order],
-                            gstate, row_state, compact_rows)
+            _resort_by_leaf(leaf_id, prev_trees,
+                            [bins, scores, bag, row_order], gstate,
+                            row_state, compact_rows, grow_kw["max_leaves"])
         return (scores, new_valid, ints, floats, bins_new, bag_new,
-                gstate_new, order_new, stopped)
+                gstate_new, order_new, stopped,
+                (ints,) + tuple(prev_trees[:-1]))
     return step
 
 
@@ -721,6 +765,11 @@ def _make_fused_step_reorder(grad_fn, grow_kw, lr, dtype, row_state,
                                                  compact_rows),
                         _SCAN_REORDER, k_iters)
     return jax.jit(body, donate_argnums=(0, 1, 2, 4, 7))
+
+
+def _packed_ints(L):
+    """The length of _pack_tree's int row at L leaves."""
+    return 1 + 4 * (L - 1) + 3 * L + 4
 
 
 def _dart_layout(L):
@@ -748,12 +797,8 @@ def _bank_row(bank, j):
 def _dart_replayed_ids(bank_i, j, bins, L):
     """Leaf ids of tree j over the rows as they lie, from its splits in
     the tree bank: what the leaf bank would hold had it room for j."""
-    SF0, TB0, LC0, RC0, RC1, LV0, LV1 = _dart_layout(L)
     with jax.named_scope(spans.DART_REPLAY):
-        row = bank_i[j]     # + the dummy slot the replay skips
-        return replay_leaf_binned_inline(
-            jnp.pad(row[SF0:TB0], (0, 1)), jnp.pad(row[TB0:LC0], (0, 1)),
-            jnp.pad(row[LC0:RC0], (0, 1), constant_values=-1), row[0], bins)
+        return _packed_leaf_ids(bank_i[j], bins, L)
 
 
 @contract.traced_pure
@@ -978,10 +1023,14 @@ def _make_fused_step_dart(grad_fn, grow_kw, dtype, max_leaves,
         if not reorder:
             return out
         filled = _FilledRows(leaf_bank, jnp.minimum(t_row + 1, bank_cap))
+        # the trees before this one are the tree bank's rows before its
+        # (zeros, no tree, before the first)
+        prev_trees = [jnp.where(t_row >= j, bank_i[jnp.maximum(t_row - j, 0)],
+                                0) for j in range(1, _RESORT_PREV + 1)]
         (bins_new, scores, bag_new, order_new, leaf_bank), gstate_new = \
-            _resort_by_leaf(leaf_id, [bins, scores, bag, row_order[0],
-                                      filled], gstate, row_state,
-                            compact_rows)
+            _resort_by_leaf(leaf_id, prev_trees,
+                            [bins, scores, bag, row_order[0], filled],
+                            gstate, row_state, compact_rows, L)
         return ((scores,) + out[1:4] + (leaf_bank,) + out[5:]
                 + (bins_new, bag_new, gstate_new, order_new))
     # gstate is NOT donated (the first re-sort aliases the objective's
@@ -1177,9 +1226,9 @@ def _make_fused_step_sharded(grad_fn, grow_kw, lr, dtype, mesh,
     vrep = [rep] * n_valid
     common_in = (row2, vrep, row, rep, row2, tuple(vrep), gstate_specs)
     if reorder:
-        in_specs = common_in + (row, rep)
+        in_specs = common_in + (row, rep, rep)
         out_specs = (row2, vrep, rep, rep, row2, row, gstate_specs,
-                     row, rep)
+                     row, rep, rep)
         donate = (0, 1, 2, 4, 7)
     else:
         in_specs = common_in + (rep,)
@@ -1484,6 +1533,9 @@ class GBDT:
         self._inv_order = None        # cached device inverse of the above
         self._gstate_override = None
         self._trees_since_reorder = self._unsorted_interval()
+        # the packed int rows (device) of the trees the fused step grew
+        # last, the latest first: the re-sort's key (_prev_trees)
+        self._prev_ints = ()
 
         # out-of-core ingest (ingest/ShardedDataset): feed the device
         # one shard window at a time — the full [F, N] matrix never
@@ -2533,15 +2585,16 @@ class GBDT:
                 bag_mask_dev = _unpack_bag_jit(bag_mask_dev, self.n_pad)
             order = (self._row_order if self._row_order is not None
                      else self._identity_order_dev())
+            prev_trees = self._prev_trees()
             with _enqueue("resort", k_iters, self._shards,
                           **_resort_counts(
                               [self.bins_dev, self.scores, bag_mask_dev,
                                order], gstate, row_state)):
                 (scores, valid, ints, floats, bins_new, bag_new,
-                 gstate_new, order_new, self._dev_stopped) = fn(
+                 gstate_new, order_new, self._dev_stopped, _) = fn(
                     self.scores, list(self.valid_scores), bag_mask_dev,
                     fmask_dev, self.bins_dev, tuple(self.valid_bins_dev),
-                    gstate, order, self._dev_stopped)
+                    gstate, order, self._dev_stopped, prev_trees)
             self.bins_dev = bins_new
             self._bag_dev_packed[0] = bag_new
             self._gstate_override = gstate_new
@@ -2557,13 +2610,30 @@ class GBDT:
             self._trees_since_reorder += k_iters
         self.scores = scores
         self.valid_scores = list(valid)
-        if k_iters == 1:
-            return [_PendingTree(ints, floats, lr, gated=True)]
         # stacked [K, ...] rows stay unmaterialized device slices; the
         # deferred flush stacks every pending tree and pulls them in one
         # device_get
-        return [_PendingTree(ints[j], floats[j], lr, gated=True)
-                for j in range(k_iters)]
+        pend = ([_PendingTree(ints, floats, lr, gated=True)] if k_iters == 1
+                else [_PendingTree(ints[j], floats[j], lr, gated=True)
+                      for j in range(k_iters)])
+        self._prev_ints = (tuple(m.ints for m in pend[::-1])
+                           + self._prev_ints)[:_RESORT_PREV]
+        return pend
+
+    def _prev_row(self, row) -> jax.Array:
+        """A packed int row as the steps' own int rows lie: replicated
+        over the mesh under the sharded step, on the device otherwise
+        (one argument type, one trace)."""
+        return (self.grower.replicate(row) if self._fused_sharded
+                else jnp.asarray(row, jnp.int32))
+
+    def _prev_trees(self) -> tuple:
+        """The re-sorting step's `prev_trees`: the device int rows of the
+        _RESORT_PREV trees the fused step grew last, the latest first, a
+        row of zeros (no tree) for each it has not grown."""
+        zero = self._prev_row(np.zeros(
+            _packed_ints(max(self.config.num_leaves, 2)), np.int32))
+        return (self._prev_ints + (zero,) * _RESORT_PREV)[:_RESORT_PREV]
 
     @contract.parity_oracle("the general per-tree path: one grow "
                             "dispatch per tree — the oracle every fused "
@@ -2720,12 +2790,13 @@ class GBDT:
                         ([m.ints for m in pend], [m.floats for m in pend]))
                 for m, ih, fh in zip(pend, ints_all, floats_all):
                     m.ints, m.floats = ih, fh
-            # a packed tree's first int is its leaf count, its last three
+            # a packed tree's first int is its leaf count, its last four
             # what its block-list sweeps and partition passes cost
             # (ops/grow.py TreeArrays)
             stats = {name: sum(int(m.ints[at]) for m in pending)
-                     for at, name in ((-3, "blocks_swept"), (-2, "grid_rows"),
-                                      (-1, "partition_blocks"))}
+                     for at, name in ((-4, "blocks_swept"), (-3, "grid_rows"),
+                                      (-2, "partition_blocks"),
+                                      (-1, "rows_swept"))}
             stats["feat_groups"], stats["block_matmuls"] = self._sweep_grid()
             wire = self._exchange_bytes(sum(int(m.ints[0])
                                             for m in pending))
@@ -3485,6 +3556,12 @@ class GBDT:
                 if self._mh_fused else np.asarray(self._row_order))
             arrays["trees_since_reorder"] = np.int64(
                 self._trees_since_reorder)
+        if self._prev_ints:
+            # the next re-sort orders each leaf's rows by these trees'
+            arrays["prev_trees"] = np.stack([
+                np.asarray(self.grower.replicated_to_local(r)
+                           if self._mh_fused else r)
+                for r in self._prev_ints])
         # per-valid-set keys: metric counts can differ between valid sets,
         # so one rectangular [sets, metrics] array would be ragged
         for i in range(len(self.best_iter)):
@@ -3595,6 +3672,8 @@ class GBDT:
             z_scores = z_base
             bag_restored = False
         self._inv_order = None
+        self._prev_ints = (tuple(self._prev_row(r) for r in z["prev_trees"])
+                           if "prev_trees" in z else ())
         if self._mh_fused:
             self.scores = self.grower.shard_rows(z_scores, self.n_pad)
         else:
@@ -3877,11 +3956,15 @@ class DART(GBDT):
         what fits beside the job's own state: the bin matrix and some
         32 B a row of scores, order, bag, gradients and the objective's
         arrays, and what the largest step holds while it runs, the
-        re-sort's stack of word rows padded to whole tiles and its sort
-        (PERF.md section 7: 4.88 GiB at 68.3M x 39, 76.7 B a row), with
-        a sixteenth of the limit left over.  Whole groups of a re-sort's
-        carry (_bank_group: 32 uint8 rows), the last row the one dead
-        and unbanked steps write to; no more than the job's
+        re-sort's stack of word rows padded to whole tiles and its sort,
+        80 B a row at 68.3M x 39, with a sixteenth of the limit left
+        over.  The re-sort's key adds the earlier trees' replayed ids: its
+        step's temporaries read 5.73 GiB for a described v5e (90.0 B a
+        row; PERF.md section 6), which the sixteenth holds (0.64 of its
+        0.98 GiB), and a DART job with a bank of 63 trees peaked at
+        9.41 GB of the chip's 16.91 (PERF.md section 6).  Whole groups of
+        a re-sort's carry (_bank_group: 32 uint8 rows), the last row the
+        one dead and unbanked steps write to; no more than the job's
         num_iterations need.  Where some tree will lie outside, the
         step's replay slots are counted too.  A bank that cannot hold
         one tree stops the job here, with the numbers."""
@@ -3934,7 +4017,7 @@ class DART(GBDT):
         leaf_dt = self._leaf_dtype()
         if self._bank is None:
             T = max(cfg.num_iterations, k_iters) + 1  # + dummy row
-            li = 1 + 4 * (L - 1) + 3 * L + 3
+            li = _packed_ints(L)
             lf = 3 * L - 2
             bi = np.zeros((T, li), np.int32)
             # untouched rows must TERMINATE traversal: child slots -1

@@ -69,11 +69,14 @@ class TreeArrays(NamedTuple):
     num_leaves: jax.Array       # scalar i32
     # what the tree's block-list sweeps cost, root included and summed
     # over shards (0 in the other sweep modes): occupied row blocks, and
-    # the row steps the kernels' grids ran; and the row blocks its split
-    # leaves occupied, which the partition passes visited
+    # the row steps the kernels' grids ran; the row blocks its split
+    # leaves occupied, which the partition passes visited; and the in-bag
+    # rows of the leaves the sweeps targeted, which would fill
+    # rows_swept / PALLAS_ROW_BLOCK blocks
     blocks_swept: jax.Array     # scalar i32
     grid_rows: jax.Array        # scalar i32
     partition_blocks: jax.Array  # scalar i32
+    rows_swept: jax.Array       # scalar i32
 
 
 class GrowState(NamedTuple):
@@ -90,8 +93,9 @@ class GrowState(NamedTuple):
     leaf_sum_h: jax.Array       # [L+1]
     best_f: jax.Array           # [L+1, 8] float best-split fields
     best_i: jax.Array           # [L+1, 4] i32 best-split fields
-    swept: jax.Array            # [3] i32 so far: (occupied blocks, grid
-    #                             rows) of the sweeps, blocks partitioned
+    swept: jax.Array            # [4] i32 so far: (occupied blocks, grid
+    #                             rows) of the sweeps, blocks partitioned,
+    #                             rows the sweeps targeted
     # histogram-pool bookkeeping (HistogramPool, reference
     # feature_histogram.hpp:275-398, re-designed as on-device LRU): only
     # carried when hist_slots bounds the pool; zero-size arrays otherwise
@@ -132,7 +136,7 @@ def _empty_tree(max_leaves: int, dtype) -> TreeArrays:
         leaf_count=z_i(L + 1),
         num_leaves=jnp.int32(1),
         blocks_swept=jnp.int32(0), grid_rows=jnp.int32(0),
-        partition_blocks=jnp.int32(0),
+        partition_blocks=jnp.int32(0), rows_swept=jnp.int32(0),
     )
 
 
@@ -321,7 +325,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
 
     ranged_on = (ranged and hist_impl == "pallas"
                  and feature_axis is None)
-    no_blocks = jnp.zeros(3, dtype=jnp.int32)   # hist_leaf's count elsewhere
+    no_blocks = jnp.zeros(4, dtype=jnp.int32)   # hist_leaf's count elsewhere
     if hist_impl == "pallas":
         from .hist_pallas import (PALLAS_ROW_BLOCK, PART_BLOCKS, fold_bag_bit,
                                   fold_leaf_mask, leaf_histogram_blocklist,
@@ -372,9 +376,9 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                     bins_t, gh2, leaf_id, target, blist, n_occ,
                     max_bin=max_bin, interpret=interpret).astype(dtype)
             # (occupied blocks, row steps the kernel ran): an empty leaf
-            # still runs one step
+            # still runs one step; the rows are the caller's to add
             return hist_psum(h), jnp.stack([n_occ, jnp.maximum(n_occ, 1),
-                                            jnp.int32(0)])
+                                            jnp.int32(0), jnp.int32(0)])
 
         groups = part_groups(nblocks)
         # row blocks of each group: PART_BLOCKS, the last what is left
@@ -402,7 +406,7 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                                     .at[wr].set(was & right_[1])
                 visited = jnp.sum(jnp.where(held & keep, group_width, 0))
             return leaf_id, occ, occ_bag, jnp.stack(
-                [0, 0, visited]).astype(jnp.int32)
+                [0, 0, visited, 0]).astype(jnp.int32)
 
         with jax.named_scope(spans.BLOCK_LIST):
             leaf_id0 = fold_bag_bit(bag_mask)
@@ -461,6 +465,12 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                                       psum(root_c))
         root_cnt = jnp.round(root_c).astype(jnp.int32)
 
+    def targeted(rows):
+        """The swept counter of a sweep over a leaf of `rows` in-bag rows
+        (a leaf count of the tree: global already, so never summed over
+        shards); 0 off the block list, as the blocks are."""
+        return no_blocks.at[3].set(rows) if ranged_on else no_blocks
+
     tree = _empty_tree(max_leaves, dtype)
     tree = tree._replace(leaf_count=tree.leaf_count.at[0].set(root_cnt))
     best_f0, best_i0 = _empty_best_packed(max_leaves, dtype)
@@ -487,7 +497,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
         leaf_id=leaf_id0, occ=occ0, occ_bag=occ_bag0, hist=hist0,
         leaf_sum_g=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_g),
         leaf_sum_h=jnp.zeros(max_leaves + 1, dtype=dtype).at[0].set(root_h),
-        best_f=best_f0, best_i=best_i0, swept=root_swept,
+        best_f=best_f0, best_i=best_i0,
+        swept=root_swept + targeted(root_cnt),
         leaf_slot=leaf_slot0, slot_leaf=slot_leaf0, slot_used=slot_used0,
     )
 
@@ -575,6 +586,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
                     lambda: (st.hist[jnp.clip(parent_slot, 0, K - 1)],
                              no_blocks),
                     lambda: hist_leaf(st.leaf_id, bl, st.occ_bag[bl]))
+                reswept = reswept + targeted(jnp.where(
+                    parent_slot >= 0, 0, tree.leaf_count[bl]))
             else:
                 parent_hist, reswept = st.hist[bl], no_blocks
 
@@ -589,6 +602,8 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
             # histograms would land in the dummy slot)
             small_hist, swept = hist_leaf(leaf_id, small_leaf,
                                           occ_bag[small_leaf] & keep)
+            swept = swept + targeted(jnp.where(
+                keep, jnp.minimum(si[BI_LCNT], si[BI_RCNT]), 0))
         with jax.named_scope(spans.HIST_POOL):
             large_hist = parent_hist - small_hist
             left_hist = jnp.where(left_is_smaller, small_hist, large_hist)
@@ -652,11 +667,12 @@ def grow_tree(bins_t: jax.Array, grad: jax.Array, hess: jax.Array,
 
     final, _ = jax.lax.scan(step, state,
                             jnp.arange(1, max_leaves, dtype=jnp.int32))
-    swept = psum(final.swept)
+    swept = psum(final.swept[:3])
     with jax.named_scope(spans.PARTITION):
         leaf_id = leaf_of(final.leaf_id) if ranged_on else final.leaf_id
     return final.tree._replace(blocks_swept=swept[0], grid_rows=swept[1],
-                               partition_blocks=swept[2]), leaf_id
+                               partition_blocks=swept[2],
+                               rows_swept=final.swept[3]), leaf_id
 
 
 @contract.traced_pure

@@ -62,7 +62,7 @@ def predict_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
 
 def replay_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
                        left_child: jax.Array, num_leaves: jax.Array,
-                       bins_t: jax.Array) -> jax.Array:
+                       bins_t: jax.Array, dtype=jnp.int32) -> jax.Array:
     """predict_leaf_binned's answer for a tree the grow scan made, by
     replaying its splits in the order they were made: step k read ONE bin
     row and sent the rows of the leaf it split that lie over the threshold
@@ -73,8 +73,8 @@ def replay_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
     For rows in BULK: a pass streams one bin row and the ids (5 bytes a
     row read, 4 written) where the descent gathers a byte per row and
     LEVEL at 25 ns an index (PERF.md section 6, PR 33: 13.6M out-of-bag rows
-    took 2.50 s a tree by descent, 0.049 s by replay).  Returns [N] i32 leaf
-    ids."""
+    took 2.50 s a tree by descent, 0.049 s by replay).  Returns [N] leaf
+    ids of `dtype` (a byte a row where the leaves fit in one)."""
     nodes = split_feature.shape[0]
 
     def go_left(_, at):
@@ -87,9 +87,9 @@ def replay_leaf_binned(split_feature: jax.Array, threshold_bin: jax.Array,
                                            keepdims=False)
         go_right = ((k < num_leaves - 1) & (leaf == source[k])
                     & (row.astype(jnp.int32) > threshold_bin[k]))
-        return jnp.where(go_right, k + 1, leaf)
+        return jnp.where(go_right, (k + 1).astype(dtype), leaf)
     return jax.lax.fori_loop(0, nodes - 1, split,
-                             jnp.zeros(bins_t.shape[1], dtype=jnp.int32))
+                             jnp.zeros(bins_t.shape[1], dtype=dtype))
 
 
 # The body itself, for a caller that is traced twice under two scopes (DART's

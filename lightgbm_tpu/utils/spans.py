@@ -83,6 +83,8 @@ ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call;
 #                                       arrangement also window, in_bag
 FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
 #                                       grid_rows, partition_blocks,
+#                                       rows_swept (the in-bag rows of
+#                                       the leaves those sweeps targeted),
 #                                       feat_groups, block_matmuls
 #                                       (a row step's feature groups and
 #                                       matmuls), and the objective's own

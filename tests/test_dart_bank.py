@@ -265,15 +265,19 @@ def test_a_restored_job_rebuilds_the_bank_within_its_bound(tmp_path, capped):
     _forget_steps()
 
 
-def test_the_start_up_line_and_record_say_the_banks_bound(capped):
+def test_the_start_up_line_and_record_say_the_banks_bound(capped,
+                                                         monkeypatch):
     """B is in the booster's start-up record and in the job's one start-up
-    info line, before the allocator has had a say."""
+    info line, before the allocator has had a say.  (The process-wide
+    records are emptied first: they keep the first spans.STARTUP_CAP, and
+    an xdist worker may have built that many boosters before this test.)"""
     from lightgbm_tpu.utils import compile_cache
+    monkeypatch.setattr(spans, "_records", [])
+    monkeypatch.setattr(spans, "_dropped", 0)
     x, y = _data()
     capped(3, N_PAD, 5)
-    before = len(spans.startup_records())
     lgb.Booster({**COMMON, "num_iterations": ROUNDS}, lgb.Dataset(x, label=y))
-    record = [r for r in spans.startup_records()[before:]
+    record = [r for r in spans.startup_records()
               if r["name"] == spans.STARTUP_BOOSTER][-1]
     assert record["stats"]["bank_cap"] == 3
     assert record["stats"]["bank_bytes"] == 4 * N_PAD

@@ -298,12 +298,13 @@ def test_grow_tree_ranged_bit_identical(case):
     t1, l1 = _grown(case, ranged=True)
     assert int(t0.num_leaves) == int(t1.num_leaves) == 8
     np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
-    counters = ("blocks_swept", "grid_rows", "partition_blocks")
+    counters = ("blocks_swept", "grid_rows", "partition_blocks",
+                "rows_swept")
     for fld in t0._fields:
         if fld not in counters:
             np.testing.assert_array_equal(np.asarray(getattr(t0, fld)),
                                           np.asarray(getattr(t1, fld)))
-    assert [int(getattr(t0, c)) for c in counters] == [0, 0, 0]
+    assert [int(getattr(t0, c)) for c in counters] == [0, 0, 0, 0]
     assert all(int(getattr(t1, c)) > 0 for c in counters)
 
 

@@ -270,6 +270,13 @@ def test_spans_count_what_was_trained(traced_and_plain):
             assert not {"carried", "taken", "word_rows"} & set(s), s
     assert sum(s["trees"] for s in by_name[spans.FLUSH]) == ROUNDS
     assert all(s["bytes"] > 0 for s in by_name[spans.FLUSH])
+    # the ordered path's sweeps: a tree's root holds every one of the 8,192
+    # rows, which lie in one row block, and each split's smaller child
+    # fewer; seven sweeps a tree, one block each
+    for s in by_name[spans.FLUSH]:
+        assert (s["trees"] * 8192 < s["rows_swept"]
+                <= s["blocks_swept"] * 8192), s
+        assert s["blocks_swept"] == 7 * s["trees"], s
     assert len(by_name[spans.FLUSH_PULL]) == len(by_name[spans.FLUSH])
     assert [s["iter"] for s in by_name[spans.SEGMENT]] == sorted(
         s["iter"] for s in by_name[spans.SEGMENT])

@@ -16,9 +16,11 @@ import jax.numpy as jnp
 import pytest
 from test_bins_in_place import F as STEP_FEATURES
 from test_bins_in_place import N as STEP_ROWS
+from test_bins_in_place import PARAMS as STEP_PARAMS
 from test_bins_in_place import (_steps_of_a_training_job,  # noqa: F401
                                 traces_forgotten)
 
+from lightgbm_tpu.models import gbdt
 from lightgbm_tpu.ops import hist_pallas as hp
 
 S = jax.ShapeDtypeStruct
@@ -225,14 +227,18 @@ def test_resort_step_moves_row_state_in_one_gather(monkeypatch,
     array costs 1.7 s at 68M rows and the bins' own 2.1 s where the
     gather of all of them costs little more than the five's 1.2 (PERF.md
     section 6, PRs 28 and 36), so an array that falls off the stack must
-    show here."""
+    show here.  The key's replays of the trees before this one look up
+    their own node tables (a gather of the padded node count each, the
+    leaf count), never a row."""
     make, shapes = _steps_of_a_training_job(monkeypatch)[0]
     jax.clear_caches()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     text = make().trace(*shapes).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert _ops_under(text, "lgbm.resort") == [
-        ("gather", STEP_ROWS), ("sort", 2)]
+    nodes = STEP_PARAMS["num_leaves"]
+    assert _ops_under(text, "lgbm.resort") == (
+        [("gather", nodes)] * gbdt._RESORT_PREV
+        + [("gather", STEP_ROWS), ("sort", 2)])
     stacked = re.findall(r"stablehlo\.gather.*tensor<(\d+)x%dxui32>, "
                          % STEP_ROWS, text)
     assert stacked == [str(5 + -(-STEP_FEATURES // 4))], stacked
