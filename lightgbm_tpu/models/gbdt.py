@@ -672,6 +672,28 @@ def _leaf_key(leaf_ids, max_leaves: int):
     return key
 
 
+# key words a class-wise re-sort sorts beside its iota: a lax.sort compiles
+# in time that grows with its operands (PERF.md section 7, row 9)
+_CLASS_KEY_WORDS = 2
+
+
+def _class_key(leaf_k, m: int, max_leaves: int):
+    """The class-wise step's re-sort key: the K classes' leaf ids over the
+    first `m` rows, class 0 most significant, packed by _leaf_key into at
+    most _CLASS_KEY_WORDS uint32 words, 32 // b classes a word.  Sorted
+    with an iota, it orders the rows exactly as the K ids as K keys would
+    while K x b <= 64 (each id fits its b bits); classes past the words
+    are left out, and their rows keep the order they had."""
+    b = (max_leaves - 1).bit_length()
+    per = 32 // b
+    classes = min(leaf_k.shape[0], per * _CLASS_KEY_WORDS)
+    with jax.named_scope(spans.CLASS_KEY):
+        return tuple(_leaf_key([leaf_k[k, :m] for k in
+                                range(lo, min(lo + per, classes))],
+                               max_leaves)
+                     for lo in range(0, classes, per))
+
+
 @contract.traced_pure
 def _resort_by_leaf(leaf_id, prev_trees, bufs, gstate, row_state,
                     compact_rows, max_leaves: int):
@@ -1111,15 +1133,15 @@ def _fused_step_multi_body(grad_fn, grow_kw, lr, dtype, reorder,
             ints_k, floats_k = ys
             return scores, list(vss), ints_k, floats_k, stopped
         ints_k, floats_k, leaf_k = ys                   # leaf_k [K, N]
-        # stable lexicographic sort, class 0 primary: the K leaf
-        # assignments are the sort's K keys.  Under bag compaction only
-        # the static union window re-sorts; the OOB tail keeps its
-        # positions (it never enters histograms)
+        # stable lexicographic sort, class 0 primary, by the K leaf
+        # assignments packed into at most two key words (_class_key).
+        # Under bag compaction only the static union window re-sorts;
+        # the OOB tail keeps its positions (it never enters histograms)
         with jax.named_scope(spans.RESORT):
             n = bins.shape[1]
             m = compact_rows if 0 < compact_rows < n else n
             (bins_new, scores, bag_new, order_new), gstate_new = \
-                _resort_rows(tuple(leaf_k[k, :m] for k in range(num_class)),
+                _resort_rows(_class_key(leaf_k, m, grow_kw["max_leaves"]),
                              [bins, scores, bag_masks, row_order[0]],
                              gstate, row_state)
         return (scores, list(vss), ints_k, floats_k, stopped,
@@ -2065,10 +2087,14 @@ class GBDT:
                       self._bag_masks_stacked_dev(), fmasks_dev,
                       self.bins_dev, tuple(self.valid_bins_dev), gstate,
                       self._dev_stopped)
+            stats = {"classes": self.num_class}
             if reorder:
                 common += (self._row_order if self._row_order is not None
                            else self._identity_order_dev(),)
-        with _enqueue("multi", k_iters, self._shards):
+                stats.update(_resort_counts(
+                    [common[4], common[0], common[2], common[8]], gstate,
+                    row_state))
+        with _enqueue("multi", k_iters, self._shards, **stats):
             out = fn(*common)
         if reorder:
             (scores, valid, ints_k, floats_k, self._dev_stopped,
@@ -2807,6 +2833,7 @@ class GBDT:
                 stats.update(self.objective.trace_counters())
             stats.update(self._sampling_counters())
             stats.update(self._dart_counters())
+            stats.update(self._class_counters())
             flush_span.set_metadata(**stats)
             with TraceAnnotation(spans.FLUSH_UNPACK):
                 self._unpack_pending()
@@ -2837,6 +2864,23 @@ class GBDT:
         and the bank's bound; 0 where the job is no DART job."""
         return {"dart_drops": 0, "dart_replayed": 0, "dart_bank_rows": 0,
                 "dart_bank_cap": 0}
+
+    def _class_counters(self) -> dict:
+        """lgbm.flush's account of a class-wise job's sweeps: the classes,
+        and the most and the fewest row blocks that one class's pending
+        trees swept (class = the tree's index mod the classes; a packed
+        tree's ints[-4] is its blocks_swept); 0 in a one-class job.  Read
+        before the trees are unpacked."""
+        k = self.num_class
+        if k <= 1:
+            return {"classes": 0, "class_blocks_max": 0,
+                    "class_blocks_min": 0}
+        blocks = [0] * k
+        for idx, m in enumerate(self._models):
+            if isinstance(m, _PendingTree):
+                blocks[idx % k] += int(m.ints[-4])
+        return {"classes": k, "class_blocks_max": max(blocks),
+                "class_blocks_min": min(blocks)}
 
     def _unpack_pending(self) -> None:
         """_flush_pending's host half: pulled buffers -> host Trees,

@@ -299,7 +299,7 @@ class MulticlassSoftmax(Objective):
     # (gbdt._make_fused_step_multi): one dispatch grows all K
     # per-iteration trees via a class-wise lax.scan
     jax_traceable = True
-    # onehot [K, N] / weights [N] both permute on their last axis, so
+    # the label row [N] / weights [N] both permute on their last axis, so
     # the shared-joint-order multiclass reorder may carry them
     row_permutable = True
     row_shardable = True
@@ -307,18 +307,28 @@ class MulticlassSoftmax(Objective):
     def __init__(self, config: Config):
         self.num_class = config.num_class
 
+    def _label_dtype(self):
+        """The narrowest unsigned type that holds every class and the
+        padded rows' mark, num_class: the row rides a re-sort's one
+        gather of words as a single word row (models/gbdt.py _word_rows),
+        where a [K, N] float one-hot took a gather of its own."""
+        for dt in (np.uint8, np.uint16):
+            if self.num_class <= np.iinfo(dt).max:
+                return dt
+        return np.int32
+
     def _init_state(self, metadata: Metadata, num_data: int) -> None:
         li = metadata.label.astype(np.int32)
         if li.min() < 0 or li.max() >= self.num_class:
             log.fatal("Label must be in [0, %d)" % self.num_class)
-        self.onehot = jnp.asarray(
-            np.eye(self.num_class, dtype=np.float32)[li].T)  # [K, N]
+        self.label = jnp.asarray(li.astype(self._label_dtype()))   # [N]
         self.weights = (None if metadata.weights is None
                         else jnp.asarray(metadata.weights, dtype=jnp.float32))
 
     def pad_to(self, n_pad: int) -> None:
         super().pad_to(n_pad)
-        self.onehot = self._pad(self.onehot, n_pad)
+        # a padded row is of no class: its one-hot is all zeros
+        self.label = self._pad(self.label, n_pad, value=self.num_class)
         self.weights = self._pad(self.weights, n_pad)
 
     def get_gradients(self, score):
@@ -329,7 +339,7 @@ class MulticlassSoftmax(Objective):
         return ("multiclass", self.num_class, self.weights is not None)
 
     def grad_state(self):
-        return (self.onehot, self.weights)
+        return (self.label, self.weights)
 
     @staticmethod
     @contract.traced_pure
@@ -342,9 +352,11 @@ class MulticlassSoftmax(Objective):
             Common::Softmax rec[] with score_t p = (float)rec[k]
             (multiclass_objective.hpp:35-53, common.h:353-367) — under
             default x64-disabled JAX the cast is a no-op and everything
-            stays f32."""
-            onehot, weights = state
+            stays f32.  The one-hot is built here from the label row."""
+            label, weights = state
             score = score.astype(jnp.float32)
+            onehot = (label[None, :].astype(jnp.int32) == jnp.arange(
+                score.shape[0], dtype=jnp.int32)[:, None]).astype(jnp.float32)
             # graftlint: disable=GL003 -- reference parity REQUIRES the
             # f64 softmax (double rec[] in common.h:353-367); with x64
             # off the astype is a no-op and the math stays f32

@@ -11,8 +11,9 @@ event's stats, so each count travels with its span.  Both land in the one
 `.xplane.pb` of `jax.profiler.trace(dir)`, on one clock;
 `benchmark/phase_table.py <dir>` prints the table (the benchmark keeps its
 own copy of these names, `benchmark/harness/scopes.json`,
-`scopes_ranked.json`, `scopes_bagged.json`, `scopes_dart.json` and
-`scopes_startup.json`; tests/test_spans.py holds their union equal).
+`scopes_ranked.json`, `scopes_bagged.json`, `scopes_dart.json`,
+`scopes_multi.json` and `scopes_startup.json`; tests/test_spans.py holds
+their union equal).
 START-UP SPANS cover what
 a job does before its steady state, where no profiler runs: `startup()`
 opens the same `TraceAnnotation` AND keeps a record in this process
@@ -66,13 +67,16 @@ DART_REPLAY = "lgbm.dart_replay"      # a dropped tree outside the leaf bank:
 #                                       its leaf ids by replay_leaf_binned
 DART_CARRY = "lgbm.dart_carry"        # the leaf bank's filled groups in a
 #                                       re-sort or an arrangement
+# the class-wise step's re-sort key (models/gbdt.py _class_key), INSIDE
+# lgbm.resort: the K classes' leaf ids packed into at most two words
+CLASS_KEY = "lgbm.class_key"
 
 DEVICE_SCOPES = (
     OBJECTIVE, GROW, HIST_ROOT, BLOCK_LIST, HIST_SWEEP, HIST_POOL,
     HIST_EXCHANGE, GAIN_SCAN, PARTITION, TREE_UPDATE, OOB_DESCENT,
     SCORE_UPDATE, VALID_UPDATE, PACK_TREE, RESORT, BAG_ARRANGE, DART_BANK,
     RANK_GATHER, RANK_SORT, RANK_PAIRS, DART_DROP, DART_NORMALIZE,
-    DART_REPLAY, DART_CARRY)
+    DART_REPLAY, DART_CARRY, CLASS_KEY)
 
 # -- host spans (models/gbdt.py segment loop), with their stats ------------
 SEGMENT = "lgbm.segment"              # iter, k: one train_segment / iteration
@@ -80,7 +84,8 @@ HOST_INPUTS = "lgbm.host_inputs"      # plan, bagging, masks and their upload
 ENQUEUE = "lgbm.enqueue"              # kind, k: the jitted executable's call;
 #                                       a re-sorting one adds carried, taken,
 #                                       word_rows (the stacked matrix's), the
-#                                       arrangement also window, in_bag
+#                                       arrangement also window, in_bag; a
+#                                       class-wise one (kind multi) classes
 FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swept,
 #                                       grid_rows, partition_blocks,
 #                                       rows_swept (the in-bag rows of
@@ -100,7 +105,12 @@ FLUSH = "lgbm.flush"                  # trees, bytes, exchange_bytes, blocks_swe
 #                                       dart_replayed (those of them that lay
 #                                       outside the leaf bank), dart_bank_rows
 #                                       (trees banked), dart_bank_cap, 0 where
-#                                       the job is no DART job:
+#                                       the job is no DART job, and the
+#                                       classes': classes, class_blocks_max,
+#                                       class_blocks_min (the most and the
+#                                       fewest blocks_swept of one class's
+#                                       flushed trees, class = tree index
+#                                       mod classes), 0 in a one-class job:
 #                                       _flush_pending
 FLUSH_PULL = "lgbm.flush_pull"        # the device_get (host waits for device)
 FLUSH_UNPACK = "lgbm.flush_unpack"    # _unpack_tree loop, stump truncation

@@ -38,6 +38,11 @@ PATHS = {
                 {spans.BLOCK_LIST, spans.RESORT}),
     "multiclass": ({"objective": "multiclass", "num_class": 3}, 3, False,
                    set()),
+    # the class-wise step on the ordered path: one joint key, two words
+    "multiclass_reorder": ({"objective": "multiclass", "num_class": 3,
+                            "hist_impl": "pallas", "hist_reorder_every": 2},
+                           3, False,
+                           {spans.BLOCK_LIST, spans.RESORT, spans.CLASS_KEY}),
     "dart": ({"boosting_type": "dart", "drop_rate": 0.5}, 1, False,
              {spans.DART_BANK, spans.DART_DROP, spans.DART_NORMALIZE,
               spans.DART_REPLAY}),
@@ -168,9 +173,23 @@ def test_dart_scopes_nest_where_a_reader_expects_them(monkeypatch):
     assert {"gather", "dynamic_update_slice"} <= ops, ops
 
 
+def test_class_key_nests_inside_the_resort(monkeypatch):
+    """The class-wise step's key lowers under `lgbm.class_key` INSIDE
+    `lgbm.resort`, and the sort it feeds takes at most three operands."""
+    text = _lowered_texts("multiclass_reorder", monkeypatch, compiled=True)
+    names = {n for n in re.findall(r'op_name="([^"]*)"', text)
+             if "/%s/" % spans.CLASS_KEY in n}
+    assert names
+    assert {tuple(SCOPE_RE.findall(n.split(spans.CLASS_KEY)[0]))
+            for n in names} == {(spans.RESORT,)}
+    sorts = re.findall(r"= \(([^)]*)\) sort\(", text)
+    assert sorts and all(len(s.split(",")) <= 3 for s in sorts), sorts
+
+
 @pytest.mark.parametrize("path,scope", [("reorder", spans.RESORT),
                                         ("bagged", spans.BAG_ARRANGE),
-                                        ("dart_reorder", spans.RESORT)])
+                                        ("dart_reorder", spans.RESORT),
+                                        ("multiclass_reorder", spans.RESORT)])
 def test_resort_helper_lowers_under_its_scope(path, scope, monkeypatch):
     """Every operation `_resort_rows` makes (the sort, the gather of the
     stacked words, the wider arrays' gathers, the window's copies, the
@@ -387,7 +406,8 @@ def test_benchmark_copy_is_equal(key, ours):
     """The program's lists equal the UNION of the benchmark's scope files
     (`scopes.json`, accepted and not edited, `scopes_ranked.json`, what the
     ranking cell added, `scopes_bagged.json`, what the bagged cell added,
-    and `scopes_dart.json`, what the DART cell added), and in each file's
+    `scopes_dart.json`, what the DART cell added, and `scopes_multi.json`,
+    what the class-wise cell added), and in each file's
     grouping every scope a cell of that file can show feeds exactly one of
     its metrics: all the program's in the newest file, all but what a
     later file added in an older one."""
@@ -395,19 +415,23 @@ def test_benchmark_copy_is_equal(key, ours):
     ranked = _benchmark_names("scopes_ranked.json")
     bagged = _benchmark_names("scopes_bagged.json")
     dart = _benchmark_names("scopes_dart.json")
+    multi = _benchmark_names("scopes_multi.json")
     assert (tuple(base[key]) + tuple(ranked.get(key, ()))
-            + tuple(bagged.get(key, ())) + tuple(dart.get(key, ()))) == ours
+            + tuple(bagged.get(key, ())) + tuple(dart.get(key, ()))
+            + tuple(multi.get(key, ()))) == ours
     if key == "device_scopes":
         grouped = [s for g in base["device_groups"].values() for s in g]
         assert sorted(grouped) == sorted(base[key])
         for added in (ranked, bagged):
             grouped = [s for g in added["device_groups"].values() for s in g]
-            assert sorted(grouped) == sorted(set(ours) - set(dart[key]))
-        grouped = [s for g in dart["device_groups"].values() for s in g]
-        assert sorted(grouped) == sorted(ours)
-        # a part is read on its own AND inside its group
-        for part in dart["device_parts"].values():
-            assert set(part) <= set(grouped)
+            assert sorted(grouped) == sorted(set(ours) - set(dart[key])
+                                             - set(multi[key]))
+        for added, later in ((dart, multi[key]), (multi, ())):
+            grouped = [s for g in added["device_groups"].values() for s in g]
+            assert sorted(grouped) == sorted(set(ours) - set(later))
+            # a part is read on its own AND inside its group
+            for part in added["device_parts"].values():
+                assert set(part) <= set(grouped)
     if key == "host_spans":
         grouped = {s for g in bagged["host_groups"].values()
                    for s in g["spans"]}
