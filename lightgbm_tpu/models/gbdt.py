@@ -660,13 +660,14 @@ def _packed_leaf_ids(row, bins, L):
 _RESORT_PREV = 2
 
 
-def _leaf_key(leaf_ids, max_leaves: int):
+def _leaf_key(leaf_ids, max_leaves: int, bits: int = 32):
     """ONE uint32 sort key a row from leaf ids, the most significant first,
     b bits each (b = the bits of max_leaves - 1, so every leaf fits); ids
-    that would not fit in 32 bits are left out."""
+    that would not fit in the key's low `bits` bits are left out.  None
+    where no id is given."""
     b = (max_leaves - 1).bit_length()
     key = None
-    for ids in leaf_ids[:32 // b]:
+    for ids in leaf_ids[:bits // b]:
         ids = ids.astype(jnp.uint32)
         key = ids if key is None else (key << b) | ids
     return key
@@ -1262,52 +1263,70 @@ def _make_fused_step_sharded(grad_fn, grow_kw, lr, dtype, mesh,
 
 
 @contract.traced_pure
-def _bag_arrange_body(row_state, multi):
-    """In-bag-first stable arrangement of every per-row device buffer —
-    the bag-compaction boundary step, ONE dispatch per re-bagging.  The
-    arrangement is a plain row permutation (in-bag rows first, relative
-    order preserved), so it composes with the ordered-partition
-    machinery: the permuted `order` rides the same composed row order
-    that metrics inversion, checkpointing and the general-path restore
-    already understand.  Multiclass sorts by the UNION of the per-class
-    masks (the static window bounds the union; each class still masks
-    its own rows inside it)."""
-    def arrange(bins, scores, mask, gstate, order, *bank):
+def _bag_arrange_body(row_state, multi, max_leaves):
+    """The bag-compaction boundary step, ONE dispatch per re-bagging: a
+    stable arrangement of every per-row device buffer, in-bag rows first,
+    and on both sides of that line in leaf order.  The sort key is ONE
+    uint32 a row: the out-of-bag bit at the top and below it, packed by
+    _leaf_key into 31 bits, the leaf ids of the trees grown last (`prev`:
+    their packed int rows, the latest first, replayed over every row by
+    _packed_leaf_ids, as a re-sort replays them).  So the rows that enter
+    the bag at a redraw land in their leaves' runs instead of at the
+    window's end in the tail's old order, and the window leaves the
+    arrangement as a re-sort would leave it (PERF.md section 6).
+    Rows of zeros (no tree yet) put every row in leaf 0: the plain
+    in-bag-first partition, to the bit.  The arrangement is a plain row
+    permutation, so it composes with the ordered-partition machinery: the
+    permuted `order` rides the same composed row order that metrics
+    inversion, checkpointing and the general-path restore already
+    understand.  Multiclass passes no trees and sorts by the UNION of the
+    per-class masks alone (the static window bounds the union; each class
+    still masks its own rows inside it)."""
+    def arrange(bins, scores, mask, gstate, order, prev, *bank):
         with jax.named_scope(spans.BAG_ARRANGE):
-            key = mask.any(axis=0) if multi else mask
+            out_of_bag = jnp.logical_not(mask.any(axis=0) if multi
+                                         else mask)
+            key = _leaf_key([_packed_leaf_ids(r, bins, max_leaves)
+                             for r in prev], max_leaves, bits=31)
+            key = (out_of_bag if key is None
+                   else (out_of_bag.astype(jnp.uint32) << 31) | key)
             # DART's leaf bank is per-row on its last axis too: `bank`
             # is (its rows, how many are filled)
             filled = [_FilledRows(*bank)] if bank else []
             (bins, scores, mask, order, *moved), gstate = _resort_rows(
-                (jnp.logical_not(key),),
-                [bins, scores, mask, order, *filled], gstate, row_state)
+                (key,), [bins, scores, mask, order, *filled], gstate,
+                row_state)
         return (bins, scores, mask, gstate, order, *moved)
     return arrange
 
 
-def _make_bag_arrange(row_state, multi, with_bank):
+def _make_bag_arrange(row_state, multi, with_bank, max_leaves):
     # gstate is NOT donated (first arrangement aliases the objective's
-    # own arrays); everything else is replaced by its permuted successor
-    donate = (0, 1, 2, 4) + ((5,) if with_bank else ())
-    return jax.jit(_bag_arrange_body(row_state, multi),
+    # own arrays), nor are the trees of the key; everything else is
+    # replaced by its permuted successor
+    donate = (0, 1, 2, 4) + ((6,) if with_bank else ())
+    return jax.jit(_bag_arrange_body(row_state, multi, max_leaves),
                    donate_argnums=donate)
 
 
-def _make_bag_arrange_sharded(row_state, multi, mesh, gstate_specs):
+def _make_bag_arrange_sharded(row_state, multi, mesh, gstate_specs,
+                              max_leaves):
     """The arrangement under shard_map: each shard sorts ITS OWN rows
-    in-bag-first (rel is computed from the shard-local mask), so shard
-    membership never changes and the grow step's psum invariants hold —
-    every in-bag row lands in exactly one shard's static window."""
+    in-bag-first (rel is computed from the shard-local mask and the
+    replicated trees' shard-local replay), so shard membership never
+    changes and the grow step's psum invariants hold — every in-bag row
+    lands in exactly one shard's static window."""
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS, shard_map
 
-    body = _bag_arrange_body(row_state, multi)
+    body = _bag_arrange_body(row_state, multi, max_leaves)
     row = P(DATA_AXIS)
     row2 = P(None, DATA_AXIS)
     mspec = row2 if multi else row
     specs = (row2, row2, mspec, gstate_specs, row)
-    fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs)
+    fn = shard_map(body, mesh=mesh, in_specs=specs + (P(),),
+                   out_specs=specs)
     return jax.jit(fn, donate_argnums=(0, 1, 2, 4))
 
 
@@ -1556,7 +1575,8 @@ class GBDT:
         self._gstate_override = None
         self._trees_since_reorder = self._unsorted_interval()
         # the packed int rows (device) of the trees the fused step grew
-        # last, the latest first: the re-sort's key (_prev_trees)
+        # last, the latest first: the re-sort's and the bag arrangement's
+        # key (_prev_trees)
         self._prev_ints = ()
 
         # out-of-core ingest (ingest/ShardedDataset): feed the device
@@ -2492,11 +2512,20 @@ class GBDT:
 
     def _arrange_for_bag(self) -> None:
         """One device dispatch per re-bagging: stable-sort every per-row
-        buffer in-bag-first so the fused step's static window holds every
-        in-bag row.  The result is 'just another row order', so metrics,
-        checkpoints and the general-path restore reuse the existing
-        ordered-partition machinery unchanged."""
+        buffer in-bag-first, and inside the bag and out of it by the
+        leaves of the trees grown last (_bag_arrange_body), so the fused
+        step's static window holds every in-bag row in leaf order.  The
+        result is 'just another row order', so metrics, checkpoints and
+        the general-path restore reuse the existing ordered-partition
+        machinery unchanged.  The class-wise path keys on the union bag
+        alone: its rows follow the joint class key, which no packed tree
+        row holds."""
         multi = self.num_class > 1
+        L = max(self.config.num_leaves, 2)
+        prev = () if multi else self._prev_trees()
+        # the earlier trees whose leaves the key holds (zeros add none)
+        keyed = min(len(self._prev_ints), len(prev),
+                    31 // (L - 1).bit_length())
         if multi:
             mask = self._bag_masks_stacked_dev()
         else:
@@ -2508,7 +2537,7 @@ class GBDT:
                  else self._identity_order_dev())
         bank = self._dart_bank_rows()
         row_state = self.objective.make_row_state_fn()
-        key = ("bag_arrange", multi, bank is not None,
+        key = ("bag_arrange", multi, bank is not None, L,
                self.objective.fused_key(), self.dtype,
                id(self.grower.mesh) if self._fused_sharded else None)
 
@@ -2516,17 +2545,18 @@ class GBDT:
             if self._fused_sharded:
                 return _make_bag_arrange_sharded(
                     row_state, multi, self.grower.mesh,
-                    self._fused_gspecs(gstate))
-            return _make_bag_arrange(row_state, multi, bank is not None)
+                    self._fused_gspecs(gstate), L)
+            return _make_bag_arrange(row_state, multi, bank is not None, L)
 
         fn = _get_fused_step(key, make)
-        args = (self.bins_dev, self.scores, mask, gstate, order)
-        moved = args[:3] + args[4:]
+        args = (self.bins_dev, self.scores, mask, gstate, order, prev)
+        moved = args[:3] + args[4:5]
         if bank is not None:
             args += (bank.rows, jnp.int32(bank.fill))
             moved += (bank,)
         with _enqueue("arrange", 0, self._shards,
                       window=self._bag_window, in_bag=self._bag_in_bag,
+                      keyed=keyed,
                       **_resort_counts(moved, gstate, row_state)):
             out = fn(*args)
         self.bins_dev, self.scores, mask_new, gstate_new, order_new = \
@@ -2654,9 +2684,10 @@ class GBDT:
                 else jnp.asarray(row, jnp.int32))
 
     def _prev_trees(self) -> tuple:
-        """The re-sorting step's `prev_trees`: the device int rows of the
-        _RESORT_PREV trees the fused step grew last, the latest first, a
-        row of zeros (no tree) for each it has not grown."""
+        """The re-sorting step's `prev_trees`, and the bag arrangement's:
+        the device int rows of the _RESORT_PREV trees the fused step grew
+        last, the latest first, a row of zeros (no tree) for each it has
+        not grown."""
         zero = self._prev_row(np.zeros(
             _packed_ints(max(self.config.num_leaves, 2)), np.int32))
         return (self._prev_ints + (zero,) * _RESORT_PREV)[:_RESORT_PREV]
@@ -4198,13 +4229,15 @@ class DART(GBDT):
         self.valid_scores = list(valid)
         # raw floats + each iteration's 1/(1+k) shrinkage applied on the
         # host in f64, like every other fused path
-        if k_iters == 1:
-            self._models.append(_PendingTree(ints, floats, rates[0],
-                                             gated=True))
-        else:
-            self._models.extend(
-                _PendingTree(ints[j], floats[j], rates[j], gated=True)
-                for j in range(k_iters))
+        pend = ([_PendingTree(ints, floats, rates[0], gated=True)]
+                if k_iters == 1
+                else [_PendingTree(ints[j], floats[j], rates[j], gated=True)
+                      for j in range(k_iters)])
+        self._models.extend(pend)
+        # the trees a bag arrangement keys on (_prev_trees); the step's
+        # own re-sort takes them from the bank
+        self._prev_ints = (tuple(m.ints for m in pend[::-1])
+                           + self._prev_ints)[:_RESORT_PREV]
         self._bank_count += k_iters
         self._bank_dirty = True
 

@@ -329,6 +329,9 @@ def test_bag_arrangement_says_what_moved_together(sampled_spans):
     assert all(s["word_rows"] == 5 + -(-FEATURES // 4) for s in arranges)
     # the static window and the bag it holds: 8,192 rows, half in the bag
     assert all((s["window"], s["in_bag"]) == (4096, 4096) for s in arranges)
+    # the earlier trees whose leaves order the rows: none before the
+    # first tree, then the gbdt._RESORT_PREV grown last
+    assert [s["keyed"] for s in arranges] == [0] + [gbdt._RESORT_PREV] * 2
 
 
 def test_a_draw_has_its_span_and_the_flush_its_account(sampled_spans):

@@ -249,9 +249,10 @@ def test_resort_step_moves_row_state_in_one_gather(monkeypatch,
 def test_bag_arrangement_moves_row_state_in_one_gather(monkeypatch,
                                                        traces_forgotten):
     """The same under `lgbm.bag_arrange`: the arrangement after a redraw
-    sorts the static window on the bag's bit and moves bins, scores,
-    mask, order and the objective's two arrays in ONE gather of the
-    window's rows."""
+    sorts every row on one key, the bag's bit over the replayed leaves of
+    the trees grown last (each replay a gather of the padded node count,
+    never a row), and moves bins, scores, mask, order and the objective's
+    two arrays in ONE gather of the rows."""
     steps = _steps_of_a_training_job(
         monkeypatch, bagging_fraction=0.5, bagging_freq=1, bag_compact="on")
     jax.clear_caches()
@@ -260,7 +261,9 @@ def test_bag_arrangement_moves_row_state_in_one_gather(monkeypatch,
         lowering_platforms=("tpu",)).as_text(debug_info=True)
         for make, shapes in steps]
     (text,) = [t for t in dict.fromkeys(texts) if "lgbm.bag_arrange" in t]
-    (gather, sort) = _ops_under(text, "lgbm.bag_arrange")
+    (*replays, gather, sort) = _ops_under(text, "lgbm.bag_arrange")
+    assert replays == [("gather", STEP_PARAMS["num_leaves"])] * \
+        gbdt._RESORT_PREV
     assert sort == ("sort", 2)
     assert gather[0] == "gather" and 0 < gather[1] <= STEP_ROWS, gather
     stacked = re.findall(r"stablehlo\.gather.*tensor<(\d+)x%dxui32>, "
